@@ -18,8 +18,6 @@ import argparse
 import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +29,7 @@ from benchmarks.aidw_model import (
     modeled_tpu_seconds,
     naive_vmem_bytes,
 )
+from repro.compile_cache import use_persistent_compile_cache
 from repro.core.aidw import AIDWParams, aidw_interpolate, brute_r_obs
 from repro.core.grid import build_grid, grid_r_obs
 from repro.core.idw import idw_interpolate
@@ -98,31 +97,26 @@ def fig4_speedups(quick=False):
 def fig5_double_precision(quick=False):
     """Paper Fig. 5: f64 performance.  Measured f64/f32 ratio on CPU; on the
     TPU target f64 has no native unit (the paper's f64 cliff is absolute)."""
+    import time
+
+    p = AIDWParams(k=10, area=1.0)
     m = 2 * K if quick else 8 * K
-    script = f"""
-import numpy as np, jax.numpy as jnp, time, jax
-from repro.core.aidw import AIDWParams, aidw_interpolate
-from repro.data.spatial import uniform_points
-p = AIDWParams(k=10, area=1.0)
-for dt in (np.float32, np.float64):
-    dx, dy, dz = uniform_points({m}, seed=0, dtype=dt)
-    qx, qy, _ = uniform_points({m}, seed=1, dtype=dt)
-    args = list(map(jnp.asarray, (dx, dy, dz, qx, qy)))
-    f = lambda: aidw_interpolate(*args, p, area=1.0)
-    jax.block_until_ready(f())
-    t0 = time.perf_counter(); jax.block_until_ready(f()); t = time.perf_counter() - t0
-    print(f"F64BENCH,{{np.dtype(dt).name}},{{t*1e3:.1f}}")
-"""
-    env = dict(os.environ, JAX_ENABLE_X64="1", PYTHONPATH="src")
-    r = subprocess.run([sys.executable, "-c", script], env=env, capture_output=True, text=True, timeout=1200)
     times = {}
-    for line in r.stdout.splitlines():
-        if line.startswith("F64BENCH"):
-            _, name, ms = line.split(",")
-            times[name] = float(ms)
-            _row("fig5", f"measured_cpu_{name}_{m//K}K", f"{ms}ms")
-    if "float32" in times and "float64" in times:
-        _row("fig5", "measured_f64_over_f32", f"{times['float64']/times['float32']:.2f}x", "CPU (SIMD width halves)")
+    # in-process under enable_x64: a JAX child started from this (JAX-holding)
+    # parent could not reach an accelerator the parent already holds
+    with jax.enable_x64():
+        for dt in (np.float32, np.float64):
+            dx, dy, dz = uniform_points(m, seed=0, dtype=dt)
+            qx, qy, _ = uniform_points(m, seed=1, dtype=dt)
+            args = list(map(jnp.asarray, (dx, dy, dz, qx, qy)))
+            f = lambda: aidw_interpolate(*args, p, area=1.0)
+            jax.block_until_ready(f())
+            t0 = time.perf_counter()
+            jax.block_until_ready(f())
+            name = np.dtype(dt).name
+            times[name] = (time.perf_counter() - t0) * 1e3
+            _row("fig5", f"measured_cpu_{name}_{m//K}K", f"{times[name]:.1f}ms")
+    _row("fig5", "measured_f64_over_f32", f"{times['float64']/times['float32']:.2f}x", "CPU (SIMD width halves)")
     _row("fig5", "paper_f64_speedup", "~8x vs CPU", "GT 730M f64 at 1/24 rate")
     _row("fig5", "tpu_f64", "no native f64", "use Kahan-f32 instead (EXPERIMENTS §Accuracy)")
 
@@ -1030,6 +1024,7 @@ def main() -> None:
                     help="CI sizes: tiny inputs, no json writes (implies --quick)")
     ap.add_argument("--only", default=None, help="comma-separated table names")
     args = ap.parse_args()
+    use_persistent_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     if args.smoke:
         args.quick = True
     grid_json = os.path.join(os.path.dirname(__file__), "results", "grid_knn.json")

@@ -33,26 +33,10 @@ from repro.core.aidw import AIDWParams, adaptive_alpha, _sq_dists
 from repro.core.knn import running_k_best
 
 
-def shard_map_compat(**kw):
-    """Version-portable ``shard_map`` decorator (same policy as the
-    compiler-params shim in ``kernels/_common.py``): newer jax exposes
-    ``jax.shard_map`` with ``check_vma``; 0.4.x ships
-    ``jax.experimental.shard_map.shard_map`` with the equivalent knob named
-    ``check_rep`` and no vma typing."""
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, **kw)
-    from jax.experimental.shard_map import shard_map
-
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return functools.partial(shard_map, **kw)
-
-
 def _pvary(x, axes):
-    """``lax.pvary`` marks a value device-varying for the vma type system;
-    on jax versions without it (no vma typing) it is the identity."""
-    fn = getattr(jax.lax, "pvary", None)
-    return x if fn is None else fn(x, axes)
+    """Mark a fresh (unvarying) carry as device-varying for shard_map's vma
+    typing, so it can join the ring's per-device state."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def _ring_perm(n: int):
@@ -148,7 +132,8 @@ def ring_aidw(
     qc = min(q_chunk, qx.shape[0] // nshards)
     dc = min(d_chunk, dx.shape[0] // nshards)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=(spec, spec),
@@ -229,7 +214,8 @@ def ring_aidw_rotate_queries(
     qc = min(q_chunk, qx.shape[0] // nshards)
     dc = min(d_chunk, dx.shape[0] // nshards)
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=(spec, spec),
@@ -296,7 +282,8 @@ def sharded_queries_aidw(
     qc = min(q_chunk, qx.shape[0] // nshards)
     dc = min(d_chunk, dx.shape[0])
 
-    @shard_map_compat(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(), qspec, qspec),
         out_specs=(qspec, qspec),

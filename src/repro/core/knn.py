@@ -8,43 +8,56 @@
    vectorised k-pass min-extract merge* that folds a tile of candidate
    distances into a running (rows, k) best set.  This is the exact same
    O(k * m) work the paper's insertion sort does in the worst case, but
-   expressed as dense vector ops (min / cumsum / select) that lower both in
+   expressed as dense vector ops (min / iota / select) that lower both in
    XLA and inside Pallas Mosaic kernels (no argmin, duplicate-safe).
 """
 
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
-def running_k_best(best, d2_tile):
+def first_true(mask, axis: int):
+    """Keep only the first True along ``axis``.
+
+    An iota/min-index form rather than ``cumsum(mask) == 1``: Mosaic has no
+    ``cumsum`` lowering, while iota and min-reductions lower in XLA and in
+    Pallas kernels alike.  It only selects, so it is bitwise the cumsum form.
+    """
+    idx = jax.lax.broadcasted_iota(jnp.int32, mask.shape, axis)
+    none = jnp.asarray(mask.shape[axis], jnp.int32)
+    first = jnp.min(jnp.where(mask, idx, none), axis=axis, keepdims=True)
+    return idx == first
+
+
+def running_k_best(best, d2_tile, axis: int = 1):
     """Merge a tile of squared distances into the running k-best set.
 
     Args:
-      best: (rows, k) current k smallest values per row, ascending not
-        required (any order), +inf for empty slots.
-      d2_tile: (rows, t) new candidate values.
+      best: (rows, k) current k smallest values per row (``(k, cols)`` for
+        ``axis=0``), ascending not required, +inf for empty slots.
+      d2_tile: (rows, t) new candidate values (``(t, cols)`` for ``axis=0``).
 
     Returns:
-      (rows, k) the k smallest of ``concat([best, d2_tile], axis=1)`` per row,
-      in ascending order.
+      the k smallest of ``concat([best, d2_tile], axis)`` per row (column),
+      ascending along ``axis``.
 
-    Implementation: k passes; each pass extracts the row-min and masks out
-    exactly one occurrence (first along the row, via a cumsum trick — this is
-    duplicate-safe and avoids argmin, which Mosaic TPU does not lower).
+    Implementation: k passes; each pass extracts the min and masks out
+    exactly one occurrence (the first, via :func:`first_true` — duplicate-
+    safe and free of argmin, which Mosaic TPU does not lower).  The Pallas
+    kernels call it on in-VMEM tiles with ``axis`` their data axis.
     """
-    k = best.shape[1]
-    c = jnp.concatenate([best, d2_tile], axis=1)
+    k = best.shape[axis]
+    c = jnp.concatenate([best, d2_tile], axis=axis)
     inf = jnp.asarray(jnp.inf, c.dtype)
     outs = []
     for _ in range(k):
-        v = jnp.min(c, axis=1, keepdims=True)  # (rows, 1)
+        v = jnp.min(c, axis=axis, keepdims=True)
         outs.append(v)
-        eq = (c == v).astype(jnp.int32)
-        first = (jnp.cumsum(eq, axis=1) == 1) & (eq == 1)  # first occurrence only
-        c = jnp.where(first, inf, c)
-    return jnp.concatenate(outs, axis=1)
+        c = jnp.where(first_true(c == v, axis), inf, c)
+    return jnp.concatenate(outs, axis=axis)
 
 
 def k_smallest(values, k: int):

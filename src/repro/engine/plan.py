@@ -69,9 +69,21 @@ _P2_TILE_ELEMS = 64 * 4096
 
 
 def _auto_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode only where it is the one way to run: on the
+    CPU backend.  On a TPU the kernels are compiled by Mosaic; any other
+    backend has no lowering for them, and interpreting there would hide a
+    device that failed to come up behind a silently slow path."""
     if interpret is not None:
         return bool(interpret)
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas lowering for backend {backend!r}: the kernels compile for "
+        "TPU and interpret on CPU; pass interpret= explicitly to override"
+    )
 
 
 def _round_up(x: int, mult: int) -> int:

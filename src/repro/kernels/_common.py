@@ -16,23 +16,9 @@ Mosaic and in interpret mode, and can be unit-tested directly.
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams, adaptive_alpha
-
-
-def tpu_compiler_params(dimension_semantics):
-    """Version-portable ``compiler_params`` for TPU ``pallas_call``s.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; which
-    name exists depends on the installed jax (0.4.x ships only the old one).
-    Every kernel module builds its dimension-semantics params through this
-    shim so a rename breaks exactly one line, caught by the CI version matrix.
-    """
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(dimension_semantics=tuple(dimension_semantics))
+from repro.core.knn import first_true
 
 
 def sq_dist_tile(qx, qy, dx, dy):
@@ -41,27 +27,6 @@ def sq_dist_tile(qx, qy, dx, dy):
     ddx = qx - dx
     ddy = qy - dy
     return ddx * ddx + ddy * ddy
-
-
-def merge_k_best(best, d2, data_axis: int):
-    """Branch-free k-pass min-extract merge (duplicate-safe, argmin-free).
-
-    best: (bn, k) for data_axis=1, (k, bn) for data_axis=0.
-    d2:   distance tile with data points along ``data_axis``.
-    Returns the k smallest per query, ascending along ``data_axis``.
-    """
-    ax = data_axis
-    k = best.shape[ax]
-    c = jnp.concatenate([best, d2], axis=ax)
-    inf = jnp.asarray(jnp.inf, c.dtype)
-    outs = []
-    for _ in range(k):
-        v = jnp.min(c, axis=ax, keepdims=True)
-        outs.append(v)
-        eq = (c == v).astype(jnp.int32)
-        first = (jnp.cumsum(eq, axis=ax) == 1) & (eq == 1)
-        c = jnp.where(first, inf, c)
-    return jnp.concatenate(outs, axis=ax)
 
 
 def alpha_from_best(best, m_real: int, area: float, params: AIDWParams, data_axis: int):
@@ -97,8 +62,7 @@ def weight_tile(d2, dz, alpha_half, data_axis: int):
     sum_w = jnp.sum(w, axis=ax, keepdims=True)
     sum_wz = jnp.sum(w * dz, axis=ax, keepdims=True)
     tile_min = jnp.min(d2, axis=ax, keepdims=True)
-    eq = (d2 == tile_min).astype(jnp.int32)
-    first = (jnp.cumsum(eq, axis=ax) == 1) & (eq == 1)
+    first = first_true(d2 == tile_min, ax)
     zeros = jnp.zeros_like(w)
     tile_hit_z = jnp.sum(jnp.where(first, dz + zeros, zeros), axis=ax, keepdims=True)
     return sum_w, sum_wz, tile_min, tile_hit_z

@@ -26,15 +26,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams
+from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
-    merge_k_best,
     sq_dist_tile,
-    tpu_compiler_params,
     weight_tile,
 )
 
-_SEMANTICS = tpu_compiler_params(("parallel", "arbitrary", "arbitrary"))
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 
 
 def _fused_kernel(
@@ -53,7 +52,7 @@ def _fused_kernel(
         def _init():
             best[...] = jnp.full(best.shape, jnp.inf, best.dtype)
 
-        best[...] = merge_k_best(best[...], d2, data_axis=1)
+        best[...] = running_k_best(best[...], d2, axis=1)
 
         @pl.when(j == last_j)
         def _finish():

@@ -68,9 +68,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams
 from repro.core.grid import UniformGrid
+from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
-    merge_k_best,
     pow_weight,
     sq_dist_tile,
     weight_tile,
@@ -158,7 +158,23 @@ def _pf_query_map(i, j, _scalar):
 def _pf_clamped_tile_map(i, j, nt):
     # clamp past-need steps to the block's last real tile: Pallas skips the
     # DMA for a revisited block index, the kernel skips the merge
-    return (i, jnp.maximum(jnp.minimum(j, nt[i] - 1), 0))
+    return (i, 0, jnp.maximum(jnp.minimum(j, nt[i] - 1), 0))
+
+
+def _row_tiles(rows):
+    """Per-block rows ``(nb, c)`` as ``(nb, 1, c)`` for ``_row_spec`` tiles.
+
+    Mosaic requires a block's last two dims to be multiples of (8, 128) or
+    the array's own, so a ``(1, block_d)`` tile cannot index an ``(nb, c)``
+    array by row; with a unit middle axis the tile's ``(1, block_d)`` matches
+    it.  The kernel still sees a ``(1, block_d)`` ref (the block axis is
+    squeezed)."""
+    nb, c = rows.shape
+    return rows.reshape(nb, 1, c)
+
+
+def _row_spec(block_d: int, index_map):
+    return pl.BlockSpec((None, 1, block_d), index_map)
 
 
 def _pf_shared_tile_map(i, j, _scalar):
@@ -184,7 +200,7 @@ def _knn_kernel_skip(nt_ref, qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best,
     @pl.when(j < nt_ref[i])
     def _merge():
         d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])
-        best[...] = merge_k_best(best[...], d2, data_axis=1)
+        best[...] = running_k_best(best[...], d2, axis=1)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -220,7 +236,7 @@ def phase1_alpha_from_candidates(
 
     if num_tiles is None:
         q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-        c_spec = pl.BlockSpec((1, block_d), lambda i, j: (i, j))
+        c_spec = _row_spec(block_d, lambda i, j: (i, 0, j))
         o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
         return pl.pallas_call(
             functools.partial(_knn_kernel_soa, m_real=m_real, area=area, params=params),
@@ -231,7 +247,7 @@ def phase1_alpha_from_candidates(
             scratch_shapes=scratch,
             compiler_params=_SEMANTICS,
             interpret=interpret,
-        )(qx2, qy2, cand_x, cand_y)
+        )(qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -239,8 +255,8 @@ def phase1_alpha_from_candidates(
         in_specs=[
             pl.BlockSpec((block_q, 1), _pf_query_map),
             pl.BlockSpec((block_q, 1), _pf_query_map),
-            pl.BlockSpec((1, block_d), _pf_clamped_tile_map),
-            pl.BlockSpec((1, block_d), _pf_clamped_tile_map),
+            _row_spec(block_d, _pf_clamped_tile_map),
+            _row_spec(block_d, _pf_clamped_tile_map),
         ],
         out_specs=pl.BlockSpec((block_q, 1), _pf_query_map),
         scratch_shapes=scratch,
@@ -251,7 +267,7 @@ def phase1_alpha_from_candidates(
         out_shape=out_shape,
         compiler_params=_SEMANTICS,
         interpret=interpret,
-    )(num_tiles.astype(jnp.int32), qx2, qy2, cand_x, cand_y)
+    )(num_tiles.astype(jnp.int32), qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
 
 
 def _near_weight_kernel(nt_ref, qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref,
@@ -308,7 +324,7 @@ def phase2_near_weights(
     dtype = qx_s.dtype
     qx2, qy2 = qx_s[:, None], qy_s[:, None]
     q_spec = pl.BlockSpec((block_q, 1), _pf_query_map)
-    c_spec = pl.BlockSpec((1, block_d), _pf_clamped_tile_map)
+    c_spec = _row_spec(block_d, _pf_clamped_tile_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb, c_tot // block_d),
@@ -322,14 +338,16 @@ def phase2_near_weights(
         out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 4,
         compiler_params=_SEMANTICS,
         interpret=interpret,
-    )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half, cand_x, cand_y, cand_z)
+    )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half,
+      *map(_row_tiles, (cand_x, cand_y, cand_z)))
 
 
 def _far_cell_kernel(rect_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
                      fix_ref, fiy_ref, fcnt_ref, fzs_ref,
                      sw_ref, swz_ref, acc_w, acc_wz):
     """Far-field half: one aggregate term per cell OUTSIDE the block's near
-    rectangle (scalar-prefetched as ``rect_ref[i] = (xlo, xhi, ylo, yhi)``).
+    rectangle (scalar-prefetched flat, ``rect_ref[4i : 4i+4] = (xlo, xhi,
+    ylo, yhi)``: a 2-D SMEM table pads every row to 128 words).
 
     Each far cell contributes ``count * w(d_centroid)`` to Σw and
     ``z_sum * w(d_centroid)`` to Σw·z.  Cells inside the rectangle are
@@ -345,8 +363,10 @@ def _far_cell_kernel(rect_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
 
     d2 = sq_dist_tile(qx_ref[...], qy_ref[...], fx_ref[...], fy_ref[...])
     w = pow_weight(d2, ah_ref[...])
-    inside = ((fix_ref[...] >= rect_ref[i, 0]) & (fix_ref[...] <= rect_ref[i, 1])
-              & (fiy_ref[...] >= rect_ref[i, 2]) & (fiy_ref[...] <= rect_ref[i, 3]))
+    xlo, xhi = rect_ref[4 * i], rect_ref[4 * i + 1]
+    ylo, yhi = rect_ref[4 * i + 2], rect_ref[4 * i + 3]
+    inside = ((fix_ref[...] >= xlo) & (fix_ref[...] <= xhi)
+              & (fiy_ref[...] >= ylo) & (fiy_ref[...] <= yhi))
     w = jnp.where(inside, jnp.zeros((), d2.dtype), w)
     acc_w[...] += jnp.sum(w * fcnt_ref[...], axis=1, keepdims=True)
     acc_wz[...] += jnp.sum(w * fzs_ref[...], axis=1, keepdims=True)
@@ -390,7 +410,7 @@ def phase2_far_aggregates(
         out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 2,
         compiler_params=_SEMANTICS,
         interpret=interpret,
-    )(rects.astype(jnp.int32), qx2, qy2, alpha_half, fx, fy, fix, fiy, fcnt, fzs)
+    )(rects.astype(jnp.int32).reshape(-1), qx2, qy2, alpha_half, fx, fy, fix, fiy, fcnt, fzs)
 
 
 def _far_node_kernel(nt_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
@@ -458,7 +478,7 @@ def phase2_far_nodes(
     dtype = qx_s.dtype
     qx2, qy2 = qx_s[:, None], qy_s[:, None]
     q_spec = pl.BlockSpec((block_q, 1), _pf_query_map)
-    c_spec = pl.BlockSpec((1, block_d), _pf_clamped_tile_map)
+    c_spec = _row_spec(block_d, _pf_clamped_tile_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb, k_pad // block_d),
@@ -473,7 +493,7 @@ def phase2_far_nodes(
         compiler_params=_SEMANTICS,
         interpret=interpret,
     )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half,
-      node_x, node_y, node_cnt, node_zs, node_mx, node_my)
+      *map(_row_tiles, (node_x, node_y, node_cnt, node_zs, node_mx, node_my)))
 
 
 def phase2_weights_full(

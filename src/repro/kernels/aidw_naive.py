@@ -21,17 +21,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams
+from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
-    merge_k_best,
     sq_dist_tile,
-    tpu_compiler_params,
     weight_tile,
 )
 
-_SEMANTICS = tpu_compiler_params(("parallel",))
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def _naive_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref, *, m_real, area, params):
@@ -40,7 +40,7 @@ def _naive_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref
     d2 = sq_dist_tile(qx, qy, dx_ref[...], dy_ref[...])  # (bn, m)
     k = params.k
     best0 = jnp.full((qx.shape[0], k), jnp.inf, d2.dtype)
-    best = merge_k_best(best0, d2, data_axis=1)
+    best = running_k_best(best0, d2, axis=1)
     alpha = alpha_from_best(best, m_real, area, params, data_axis=1)
     alpha_ref[...] = alpha
     # --- pass 2: distances AGAIN + weighting (paper lines 52-58) ---
@@ -55,7 +55,7 @@ def _naive_kernel_aoas(qx_ref, qy_ref, d_ref, out_ref, alpha_ref, *, m_real, are
     d2 = sq_dist_tile(qx, qy, dxc, dyc)  # (m, bn)
     k = params.k
     best0 = jnp.full((k, qx.shape[1]), jnp.inf, d2.dtype)
-    best = merge_k_best(best0, d2, data_axis=0)
+    best = running_k_best(best0, d2, axis=0)
     alpha = alpha_from_best(best, m_real, area, params, data_axis=0)
     alpha_ref[...] = alpha
     d2b = sq_dist_tile(qx, qy, dxc, dyc)

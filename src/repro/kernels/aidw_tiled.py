@@ -24,15 +24,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams
+from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
-    merge_k_best,
     sq_dist_tile,
-    tpu_compiler_params,
     weight_tile,
 )
 
-_SEMANTICS = tpu_compiler_params(("parallel", "arbitrary"))
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------- SoA family
@@ -59,7 +58,7 @@ def _knn_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best, *, m_real, 
         )
     else:
         cands = d2
-    best[...] = merge_k_best(best[...], cands, data_axis=1)
+    best[...] = running_k_best(best[...], cands, axis=1)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -144,7 +143,7 @@ def _knn_kernel_aoas(qx_ref, qy_ref, d_ref, alpha_ref, best, *, m_real, area, pa
     dxc = d_ref[:, 0:1]
     dyc = d_ref[:, 1:2]
     d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dxc, dyc)  # (bm, bn)
-    best[...] = merge_k_best(best[...], d2, data_axis=0)
+    best[...] = running_k_best(best[...], d2, axis=0)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():

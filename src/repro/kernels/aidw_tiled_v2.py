@@ -25,14 +25,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.aidw import AIDWParams
+from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
-    merge_k_best,
     sq_dist_tile,
-    tpu_compiler_params,
 )
 
-_SEMANTICS = tpu_compiler_params(("parallel", "arbitrary"))
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _knn_kernel_v2(qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, nmerge_ref, best, *, m_real, area, params):
@@ -49,7 +48,7 @@ def _knn_kernel_v2(qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, nmerge_ref, best, 
 
     @pl.when(has_candidate)
     def _merge():
-        best[...] = merge_k_best(best[...], d2, data_axis=1)
+        best[...] = running_k_best(best[...], d2, axis=1)
         nmerge_ref[...] += 1
 
     @pl.when(j == pl.num_programs(1) - 1)

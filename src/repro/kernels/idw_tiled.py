@@ -13,9 +13,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._common import sq_dist_tile, tpu_compiler_params, weight_tile
+from repro.kernels._common import sq_dist_tile, weight_tile
 
-_SEMANTICS = tpu_compiler_params(("parallel", "arbitrary"))
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _idw_kernel(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, acc_w, acc_wz, min_d2, hit_z, *, alpha_half, eps):

@@ -40,7 +40,7 @@ import jax  # noqa: E402
 from repro.configs import ARCHS, SHAPES, cell_is_applicable, get_arch, get_shape  # noqa: E402
 from repro.launch.dryrun import collective_census, _write  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.specs import build_cell, cost_analysis_dict  # noqa: E402
+from repro.launch.specs import build_cell  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.train.steps import make_prefill_step, make_serve_step, make_train_step  # noqa: E402
 
@@ -68,7 +68,7 @@ def _measure(cfg, shape, mesh, rules_name=None, compress_grads=False):
     jitted = jax.jit(fn, in_shardings=cell["in_shardings"], out_shardings=cell["out_shardings"])
     with mesh:
         compiled = jitted.lower(*cell["args"]).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     census = collective_census(compiled.as_text())

@@ -29,7 +29,7 @@ import jax  # noqa: E402
 
 from repro.configs import ARCHS, SHAPES, cell_is_applicable, get_arch, get_shape  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.specs import build_cell, cost_analysis_dict  # noqa: E402
+from repro.launch.specs import build_cell  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.train.steps import make_prefill_step, make_serve_step, make_train_step  # noqa: E402
 
@@ -152,7 +152,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_name: str, out_dir: str,
             record["memory_analysis_error"] = repr(e)
 
         try:
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             record["cost_analysis"] = {
                 k: float(v)
                 for k, v in ca.items()
@@ -241,7 +241,7 @@ def run_aidw_cell(work_name: str, mesh_name: str, out_dir: str):
         except Exception as e:
             record["memory_analysis_error"] = repr(e)
         try:
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis()
             record["cost_analysis"] = {
                 k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float)) and (k in ("flops", "transcendentals", "bytes accessed") or k.startswith("bytes accessed"))

@@ -127,15 +127,3 @@ def build_cell(model, cfg: ArchConfig, shape: ShapeConfig, mesh, rules_name: str
         in_shardings=(psh, csh, tsh, repl),
         out_shardings=(tsh, logits_sh, csh),
     )
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """Version-portable ``compiled.cost_analysis()``: older jax returns a
-    single dict, the 0.4.3x era returns a one-element list of dicts (one per
-    executable).  Every caller goes through this so the shape difference is
-    absorbed in one place (same policy as ``kernels/_common.py``'s
-    compiler-params shim)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca

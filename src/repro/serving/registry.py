@@ -195,6 +195,11 @@ class PlanRegistry:
         with self._lock:
             return dict(self._counters, size=len(self._entries))
 
+    def plans(self) -> list:
+        """Snapshot of the plans currently held (no counter is touched)."""
+        with self._lock:
+            return [plan for _, plan in self._entries.values()]
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._entries)

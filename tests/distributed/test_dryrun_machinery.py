@@ -16,7 +16,7 @@ import dataclasses
 import jax
 import numpy as np
 from repro.configs import ARCHS, ShapeConfig, smoke
-from repro.launch.specs import build_cell, cost_analysis_dict
+from repro.launch.specs import build_cell
 from repro.models import build_model
 from repro.train.steps import make_serve_step, make_train_step
 from repro.launch.dryrun import collective_census
@@ -41,7 +41,7 @@ for arch_name in ("minitron-4b", "mixtral-8x7b"):
     fn = make_serve_step(model, cfg, mesh=mesh, rules=cell["rules"])
     compiled = jax.jit(fn, in_shardings=cell["in_shardings"],
                        out_shardings=cell["out_shardings"]).lower(*cell["args"]).compile()
-    assert cost_analysis_dict(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
     print(f"OK {arch_name}")
 print("OK dryrun-machinery")
 """

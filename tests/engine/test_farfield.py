@@ -100,7 +100,7 @@ def test_measured_error_within_proved_bound(dist, radius):
 def test_measured_error_within_bound_f64(dist):
     """Same enforcement in f64 (no native f64 on the TPU target, but the
     interpret-mode path must honour the budget at both widths)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         dx, dy, dz = _cluster_data(seed=12, dtype=np.float64)
         qx, qy = _queries(dist, 150, seed=13, dtype=np.float64)
         plan = _farfield_plan(dx, dy, dz, radius=2)

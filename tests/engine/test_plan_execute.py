@@ -255,3 +255,18 @@ def test_build_plan_validations():
     for impl in ("idw", "chunked"):
         with pytest.raises(ValueError):
             aidw(dx, dy, dz, qx, qy, params=p, area=1.0, impl=impl)
+
+
+@pytest.mark.parametrize("backend, expected", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_auto_interpret_only_on_cpu(monkeypatch, backend, expected):
+    """Interpret mode only on the CPU; a backend with no Pallas lowering
+    raises rather than hiding behind a slow interpreter."""
+    from repro.engine import plan as plan_mod
+
+    monkeypatch.setattr(plan_mod.jax, "default_backend", lambda: backend)
+    if expected is None:
+        with pytest.raises(RuntimeError, match="no Pallas lowering"):
+            plan_mod._auto_interpret(None)
+    else:
+        assert plan_mod._auto_interpret(None) is expected
+    assert plan_mod._auto_interpret(False) is False

@@ -133,7 +133,7 @@ def test_quadtree_proves_where_single_level_cannot():
 def test_measured_error_within_bound_f64():
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         dx, dy, dz = _tight_data(seed=12, dtype=np.float64)
         qx, qy = _queries("out_of_bbox", 150, seed=13, dtype=np.float64)
         plan = _quadtree_plan(dx, dy, dz)

@@ -1,0 +1,144 @@
+"""Mosaic compile checks for the main-path kernels at real widths.
+
+Every other kernel test runs in Pallas interpret mode, which accepts block
+shapes and primitives the TPU compiler refuses.  These tests compile each
+kernel with ``interpret=False`` for a described (not attached) TPU v5e chip
+at n = m = 2^20 queries/points, ``block_q=256``, ``block_d=512``, grid
+candidate capacity 4096 and k = 10: nothing runs, but a kernel Mosaic would
+refuse on the chip fails here.
+
+The topology is described inside a module-scoped fixture (never at import):
+only one process may hold the TPU compiler library, so describing it while
+pytest workers import this module would break the others.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.aidw import AIDWParams
+from repro.kernels.aidw_grid import (
+    phase1_alpha_from_candidates,
+    phase2_far_aggregates,
+    phase2_far_nodes,
+    phase2_near_weights,
+    phase2_weights_full,
+)
+from repro.kernels.aidw_naive import aidw_naive_soa
+from repro.kernels.aidw_tiled import aidw_tiled_aoas, aidw_tiled_soa
+
+N = M = 1 << 20
+BLOCK_Q, BLOCK_D = 256, 512
+CAPACITY = 4096
+NB = N // BLOCK_Q
+N_CELLS = 1 << 16          # ~16 points per cell at m = 2^20 (grid default)
+PARAMS = AIDWParams(k=10, area=1.0)
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip):
+    """``compile_for_chip(fn, *shapes)`` lowers and compiles ``fn`` for the
+    described chip, with the persistent compile cache off (an entry written
+    for a described chip cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_tiled_soa_both_phases(compile_for_chip):
+    fn = functools.partial(aidw_tiled_soa, params=PARAMS, area=1.0, m_real=M,
+                           block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
+    col, row = ((N, 1), F32), ((1, M), F32)
+    compile_for_chip(lambda dx, dy, dz, qx, qy: fn(dx, dy, dz, qx, qy),
+                     row, row, row, col, col)
+
+
+def test_tiled_aoas_both_phases(compile_for_chip):
+    fn = functools.partial(aidw_tiled_aoas, params=PARAMS, area=1.0, m_real=M,
+                           block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
+    row = ((1, N), F32)
+    compile_for_chip(lambda data, qx, qy: fn(data, qx, qy), ((M, 4), F32), row, row)
+
+
+def test_naive_soa_at_10k(compile_for_chip):
+    m = 10 * 1024
+    fn = functools.partial(aidw_naive_soa, params=PARAMS, area=1.0, m_real=m,
+                           block_q=64, interpret=False)
+    col, row = ((m, 1), F32), ((1, m), F32)
+    compile_for_chip(lambda dx, dy, dz, qx, qy: fn(dx, dy, dz, qx, qy),
+                     row, row, row, col, col)
+
+
+@pytest.mark.parametrize("pipeline", ["prefetch", "dense"])
+def test_grid_phase1(compile_for_chip, pipeline):
+    def fn(qx, qy, cx, cy, nt):
+        return phase1_alpha_from_candidates(
+            qx, qy, cx, cy, params=PARAMS, area=1.0, m_real=M,
+            block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False,
+            num_tiles=nt if pipeline == "prefetch" else None,
+        )
+
+    q, cand = ((N,), F32), ((NB, CAPACITY), F32)
+    compile_for_chip(fn, q, q, cand, cand, ((NB,), jnp.int32))
+
+
+def test_near_weight_kernel(compile_for_chip):
+    fn = functools.partial(phase2_near_weights, block_q=BLOCK_Q, block_d=BLOCK_D,
+                           interpret=False)
+    q, cand = ((N,), F32), ((NB, CAPACITY), F32)
+    compile_for_chip(fn, q, q, ((N, 1), F32), cand, cand, cand, ((NB,), jnp.int32))
+
+
+def test_far_cell_kernel(compile_for_chip):
+    def fn(qx, qy, ah, rects, fx, fy, fcnt, fzs, fix, fiy):
+        return phase2_far_aggregates(qx, qy, ah, rects, (fx, fy, fcnt, fzs, fix, fiy),
+                                     block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
+
+    q, cells, ids = ((N,), F32), ((1, N_CELLS), F32), ((1, N_CELLS), jnp.int32)
+    compile_for_chip(fn, q, q, ((N, 1), F32), ((NB, 4), jnp.int32),
+                     cells, cells, cells, cells, ids, ids)
+
+
+def test_far_node_kernel(compile_for_chip):
+    fn = functools.partial(phase2_far_nodes, block_q=BLOCK_Q, block_d=BLOCK_D,
+                           interpret=False)
+    q, nodes = ((N,), F32), ((NB, CAPACITY), F32)
+    compile_for_chip(fn, q, q, ((N, 1), F32), *[nodes] * 6, ((NB,), jnp.int32))
+
+
+def test_phase2_weights_full(compile_for_chip):
+    fn = functools.partial(phase2_weights_full, eps=PARAMS.exact_hit_eps,
+                           block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
+    q, row = ((N,), F32), ((1, M), F32)
+    compile_for_chip(fn, q, q, ((N, 1), F32), row, row, row)

@@ -40,6 +40,18 @@ def test_register_get_hit_miss_counters():
     assert (s["hits"], s["misses"], s["size"]) == (1, 1, 1)
 
 
+def test_plans_snapshot_touches_no_counter():
+    reg = PlanRegistry()
+    plans = [_plan(10), _plan(11)]
+    reg.register("a", plans[0])
+    reg.register("b", plans[1])
+    assert sorted(map(id, reg.plans())) == sorted(map(id, plans))
+    reg.swap("a", plans[1])
+    assert [id(p) for p in reg.plans()] == [id(plans[1])] * 2
+    s = reg.stats()
+    assert (s["hits"], s["misses"]) == (0, 0)
+
+
 def test_lru_bound_evicts_oldest_and_get_refreshes_recency():
     reg = PlanRegistry(max_plans=2)
     plans = {k: _plan(i) for i, k in enumerate("abc")}
