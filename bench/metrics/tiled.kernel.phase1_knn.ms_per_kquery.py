@@ -1,0 +1,7 @@
+"""``kernel.phase1_knn.ms_per_kquery`` in the tiled cell: the same reader, under a
+name of its own because there it moves ``queries_per_s``, not the
+served cells' ``served_queries_per_s``."""
+
+from bench.manifest import metric_reader
+
+read = metric_reader("kernel.phase1_knn.ms_per_kquery").read
