@@ -266,7 +266,8 @@ def _phase2_quadtree(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
     closed_counts = []
     opened_tot = jnp.zeros(need.shape, jnp.int32)
     proc_tot = jnp.zeros(need.shape, jnp.int32)
-    tables = _quadtree_walk(plan, hxlo, hxhi, hylo, hyhi)
+    with jax.named_scope("aidw.phase2.quadtree_walk"):
+        tables = _quadtree_walk(plan, hxlo, hxhi, hylo, hyhi)
     for lv, (tbl, n_closed, n_opened, n_proc) in enumerate(tables):
         _nx, _ny, _step, k_pad, tile = plan.qt_levels[lv]
         fx, fy, fcnt, fzs, fmx, fmy, _fe = plan.far[lv]
@@ -339,145 +340,155 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
 
     # Morton-sort queries so each block's home cells form a compact patch,
     # pad the tail by repetition (adds no candidate cells)
-    cx, cy = cell_of(grid, qx, qy)
-    order = jnp.argsort(morton_ids(cx, cy), stable=True)
-    n_pad = (-n) % plan.block_q
-    qx_s = pad_tail(qx[order], n_pad)
-    qy_s = pad_tail(qy[order], n_pad)
-    cx_s, cy_s = cell_of(grid, qx_s, qy_s)
+    with jax.named_scope("aidw.sort"):
+        cx, cy = cell_of(grid, qx, qy)
+        order = jnp.argsort(morton_ids(cx, cy), stable=True)
+        n_pad = (-n) % plan.block_q
+        qx_s = pad_tail(qx[order], n_pad)
+        qy_s = pad_tail(qy[order], n_pad)
+        cx_s, cy_s = cell_of(grid, qx_s, qy_s)
 
-    # Phase-1 view: seam-split blocks (rectangles can't straddle a Morton
-    # seam, the measured overflow worst case); pad slots repeat a real query
-    qx_v, qy_v, cx_v, cy_v, src, dest = _seam_split_layout(plan, qx_s, qy_s, cx_s, cy_s)
+        # Phase-1 view: seam-split blocks (rectangles can't straddle a Morton
+        # seam, the measured overflow worst case); pad slots repeat a real query
+        qx_v, qy_v, cx_v, cy_v, src, dest = _seam_split_layout(plan, qx_s, qy_s, cx_s, cy_s)
 
     # containment-safe radii: plan-time table + closed-form overhang term
-    r_need = plan.r_need[cy_v, cx_v]
-    r_safe = safe_radius_from_need(grid, qx_v, qy_v, cx_v, cy_v, r_need)
-    xlo, xhi, ylo, yhi = block_rectangles(grid, cx_v, cy_v, r_safe, plan.block_q)
-    cand_x, cand_y, need = gather_candidates_csr(
-        grid, xlo, xhi, ylo, yhi, plan.cand_capacity
-    )
+    with jax.named_scope("aidw.gather"):
+        r_need = plan.r_need[cy_v, cx_v]
+        r_safe = safe_radius_from_need(grid, qx_v, qy_v, cx_v, cy_v, r_need)
+        xlo, xhi, ylo, yhi = block_rectangles(grid, cx_v, cy_v, r_safe, plan.block_q)
+        cand_x, cand_y, need = gather_candidates_csr(
+            grid, xlo, xhi, ylo, yhi, plan.cand_capacity
+        )
+        n_tiles_static = plan.cand_capacity // plan.cand_block_d
+        # always the prefetch-style count: the dense pipeline ignores it but the
+        # skipped_tile_fraction diagnostic reports what the launch WOULD skip
+        num_tiles = _tile_table(need, plan.cand_capacity, plan.cand_block_d,
+                                "prefetch")
 
     # Phase 1, always on the kernel path: the per-block tile table clamps
     # each block's walk to its own non-sentinel tiles ("prefetch"), and an
     # overflowing block simply computes a (cheap, discarded) alpha from its
     # first `cand_capacity` candidates
-    n_tiles_static = plan.cand_capacity // plan.cand_block_d
-    # always the prefetch-style count: the dense pipeline ignores it but the
-    # skipped_tile_fraction diagnostic reports what the launch WOULD skip
-    num_tiles = _tile_table(need, plan.cand_capacity, plan.cand_block_d,
-                            "prefetch")
-    alpha_fast = phase1_alpha_from_candidates(
-        qx_v, qy_v, cand_x, cand_y,
-        params=params, area=plan.area, m_real=plan.m,
-        block_q=plan.block_q, block_d=plan.cand_block_d,
-        interpret=plan.interpret,
-        num_tiles=num_tiles if plan.pipeline == "prefetch" else None,
-    )
+    with jax.named_scope("aidw.phase1"):
+        alpha_fast = phase1_alpha_from_candidates(
+            qx_v, qy_v, cand_x, cand_y,
+            params=params, area=plan.area, m_real=plan.m,
+            block_q=plan.block_q, block_d=plan.cand_block_d,
+            interpret=plan.interpret,
+            num_tiles=num_tiles if plan.pipeline == "prefetch" else None,
+        )
 
     # Per-block overflow blend: back in the sorted layout, ring-search ONLY
     # queries whose block overflowed (masked — a clean batch adds zero loop
     # iterations) and keep the kernel alpha everywhere else.  Exactness is
     # per query: kernel where covered, ring search where not.
-    over_b = need > plan.cand_capacity
-    over_v = jnp.repeat(over_b, plan.block_q)
-    if dest is not None:
-        alpha_fast = alpha_fast[dest]
-        over_q = over_v[dest]
-    else:
-        over_q = over_v
-    r_obs = grid_r_obs(grid, qx_s, qy_s, params.k, active=over_q)
-    alpha_exact = adaptive_alpha(r_obs, plan.m, plan.area, params).astype(dtype)[:, None]
-    alpha = jnp.where(over_q[:, None], alpha_exact, alpha_fast)
+    with jax.named_scope("aidw.ring_search"):
+        over_b = need > plan.cand_capacity
+        over_v = jnp.repeat(over_b, plan.block_q)
+        if dest is not None:
+            alpha_fast = alpha_fast[dest]
+            over_q = over_v[dest]
+        else:
+            over_q = over_v
+        r_obs = grid_r_obs(grid, qx_s, qy_s, params.k, active=over_q)
+        alpha_exact = adaptive_alpha(r_obs, plan.m, plan.area, params).astype(dtype)[:, None]
+        alpha = jnp.where(over_q[:, None], alpha_exact, alpha_fast)
 
     dxp, dyp, dzp = plan.data
     qt_diag = None
-    if plan.phase2 in ("farfield", "quadtree"):
-        # approximated Phase 2 runs in the seam-split view (its rectangles
-        # must not straddle Morton seams either); alpha maps in through src,
-        # the per-slot z maps back through dest.  Blocks whose near field
-        # overflows p2_capacity — or, for the quadtree, whose closed-node
-        # table overflows its level capacity — would violate the error
-        # bound (truncated sweep), so their queries take the per-block
-        # masked exact sweep instead: one overflowing block costs
-        # O(block_q * m), a clean batch costs nothing.
-        alpha_v = alpha[src] if src is not None else alpha
-        if plan.phase2 == "quadtree":
-            (z_v, need2, over2_b, rect_cells, closed_counts, opened_tot,
-             proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v)
-            qt_diag = (closed_counts, opened_tot, proc_tot)
+    with jax.named_scope("aidw.phase2"):
+        if plan.phase2 in ("farfield", "quadtree"):
+            # approximated Phase 2 runs in the seam-split view (its rectangles
+            # must not straddle Morton seams either); alpha maps in through
+            # src, the per-slot z maps back through dest.  Blocks whose near
+            # field overflows p2_capacity — or, for the quadtree, whose
+            # closed-node table overflows its level capacity — would violate
+            # the error bound (truncated sweep), so their queries take the
+            # per-block masked exact sweep instead: one overflowing block
+            # costs O(block_q * m), a clean batch costs nothing.
+            alpha_v = alpha[src] if src is not None else alpha
+            if plan.phase2 == "quadtree":
+                (z_v, need2, over2_b, rect_cells, closed_counts, opened_tot,
+                 proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v)
+                qt_diag = (closed_counts, opened_tot, proc_tot)
+            else:
+                with jax.named_scope("aidw.phase2.farfield"):
+                    z_v, need2, rect_cells = _phase2_farfield(plan, qx_v, qy_v,
+                                                              alpha_v, cx_v, cy_v)
+                over2_b = need2 > plan.p2_capacity
+            over2_v = jnp.repeat(over2_b, plan.block_q)
+            if dest is not None:
+                z_near = z_v[dest]
+                over2_s = over2_v[dest]
+            else:
+                z_near = z_v
+                over2_s = over2_v
+            with jax.named_scope("aidw.phase2.masked_exact"):
+                z_full = _phase2_exact_masked(plan, qx_s, qy_s, alpha, over2_s)
+            zhat = jnp.where(over2_s[:, None], z_full, z_near)
         else:
-            z_v, need2, rect_cells = _phase2_farfield(plan, qx_v, qy_v,
-                                                      alpha_v, cx_v, cy_v)
-            over2_b = need2 > plan.p2_capacity
-        over2_v = jnp.repeat(over2_b, plan.block_q)
+            zhat = phase2_weights_full(
+                qx_s, qy_s, alpha, dxp, dyp, dzp,
+                eps=params.exact_hit_eps, block_q=plan.block_q, block_d=plan.block_d,
+                interpret=plan.interpret,
+            )
+    with jax.named_scope("aidw.sort"):
+        inv = jnp.argsort(order)
+    with jax.named_scope("aidw.stats"):
+        # diagnostics count only blocks holding at least one real query — seam
+        # pad blocks (all-duplicate, ~1 tile) would otherwise inflate the skip
+        # fraction and the overflow-block count
+        nb = need.shape[0]
         if dest is not None:
-            z_near = z_v[dest]
-            over2_s = over2_v[dest]
+            real_slot = jnp.zeros((nb * plan.block_q,), bool).at[dest].set(True)
+            real_b = jnp.any(real_slot.reshape(nb, plan.block_q), axis=1)
         else:
-            z_near = z_v
-            over2_s = over2_v
-        z_full = _phase2_exact_masked(plan, qx_s, qy_s, alpha, over2_s)
-        zhat = jnp.where(over2_s[:, None], z_full, z_near)
-    else:
-        zhat = phase2_weights_full(
-            qx_s, qy_s, alpha, dxp, dyp, dzp,
-            eps=params.exact_hit_eps, block_q=plan.block_q, block_d=plan.block_d,
-            interpret=plan.interpret,
-        )
-    inv = jnp.argsort(order)
-    # diagnostics count only blocks holding at least one real query — seam
-    # pad blocks (all-duplicate, ~1 tile) would otherwise inflate the skip
-    # fraction and the overflow-block count
-    nb = need.shape[0]
-    if dest is not None:
-        real_slot = jnp.zeros((nb * plan.block_q,), bool).at[dest].set(True)
-        real_b = jnp.any(real_slot.reshape(nb, plan.block_q), axis=1)
-    else:
-        real_b = jnp.ones((nb,), bool)
-    n_real_tiles = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)) * n_tiles_static, 1)
-    stats = {
-        # every real query took the ring path — the batch got no kernel help
-        "grid_fallback": jnp.all(over_q[:n]),
-        "cand_need_max": jnp.max(need),
-        "overflow_blocks": jnp.sum((over_b & real_b).astype(jnp.int32)),
-        "overflow_queries": jnp.sum(over_q[:n].astype(jnp.int32)),
-        "overflow_query_mask": over_q[:n][inv],
-        "skipped_tile_fraction": 1.0
-        - jnp.sum(jnp.where(real_b, num_tiles, 0)).astype(jnp.float32) / n_real_tiles,
-    }
-    if plan.phase2 in ("farfield", "quadtree"):
-        n_real_b = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)), 1).astype(jnp.float32)
-        if plan.phase2 == "quadtree":
-            # far work per block is the number of CLOSED nodes summed over
-            # levels — the quantity the O(log m) sweep benchmark tracks
-            closed_counts, opened_tot, proc_tot = qt_diag
-            closed_stack = jnp.stack(closed_counts)           # (n_levels, nb)
-            far_terms = jnp.sum(closed_stack, axis=0)
-            far_mean = jnp.sum(
-                jnp.where(real_b, far_terms, 0)).astype(jnp.float32) / n_real_b
+            real_b = jnp.ones((nb,), bool)
+        n_real_tiles = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)) * n_tiles_static, 1)
+        stats = {
+            # every real query took the ring path — the batch got no kernel help
+            "grid_fallback": jnp.all(over_q[:n]),
+            "cand_need_max": jnp.max(need),
+            "overflow_blocks": jnp.sum((over_b & real_b).astype(jnp.int32)),
+            "overflow_queries": jnp.sum(over_q[:n].astype(jnp.int32)),
+            "overflow_query_mask": over_q[:n][inv],
+            "skipped_tile_fraction": 1.0
+            - jnp.sum(jnp.where(real_b, num_tiles, 0)).astype(jnp.float32) / n_real_tiles,
+        }
+        if plan.phase2 in ("farfield", "quadtree"):
+            n_real_b = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)), 1).astype(jnp.float32)
+            if plan.phase2 == "quadtree":
+                # far work per block is the number of CLOSED nodes summed over
+                # levels — the quantity the O(log m) sweep benchmark tracks
+                closed_counts, opened_tot, proc_tot = qt_diag
+                closed_stack = jnp.stack(closed_counts)           # (n_levels, nb)
+                far_terms = jnp.sum(closed_stack, axis=0)
+                far_mean = jnp.sum(
+                    jnp.where(real_b, far_terms, 0)).astype(jnp.float32) / n_real_b
+                stats.update({
+                    "cells_per_level": jnp.sum(
+                        jnp.where(real_b[None, :], closed_stack, 0), axis=1
+                    ).astype(jnp.float32) / n_real_b,
+                    "opened_fraction": jnp.sum(
+                        jnp.where(real_b, opened_tot, 0)).astype(jnp.float32)
+                    / jnp.maximum(jnp.sum(jnp.where(real_b, proc_tot, 0)), 1
+                                  ).astype(jnp.float32),
+                    "quadtree_rtol_bound": plan.farfield_bound,
+                })
+            else:
+                far_mean = jnp.sum(
+                    jnp.where(real_b, grid.n_cells - rect_cells, 0)
+                ).astype(jnp.float32) / n_real_b
+                stats["farfield_rtol_bound"] = plan.farfield_bound
             stats.update({
-                "cells_per_level": jnp.sum(
-                    jnp.where(real_b[None, :], closed_stack, 0), axis=1
-                ).astype(jnp.float32) / n_real_b,
-                "opened_fraction": jnp.sum(
-                    jnp.where(real_b, opened_tot, 0)).astype(jnp.float32)
-                / jnp.maximum(jnp.sum(jnp.where(real_b, proc_tot, 0)), 1
-                              ).astype(jnp.float32),
-                "quadtree_rtol_bound": plan.farfield_bound,
+                "near_points_mean": jnp.sum(
+                    jnp.where(real_b, need2, 0)).astype(jnp.float32) / n_real_b,
+                "far_cells_mean": far_mean,
+                "p2_overflow_queries": jnp.sum(over2_s[:n].astype(jnp.int32)),
             })
-        else:
-            far_mean = jnp.sum(
-                jnp.where(real_b, grid.n_cells - rect_cells, 0)
-            ).astype(jnp.float32) / n_real_b
-            stats["farfield_rtol_bound"] = plan.farfield_bound
-        stats.update({
-            "near_points_mean": jnp.sum(
-                jnp.where(real_b, need2, 0)).astype(jnp.float32) / n_real_b,
-            "far_cells_mean": far_mean,
-            "p2_overflow_queries": jnp.sum(over2_s[:n].astype(jnp.int32)),
-        })
-    return zhat[:n, 0][inv], alpha[:n, 0][inv], stats
+    with jax.named_scope("aidw.sort"):
+        return zhat[:n, 0][inv], alpha[:n, 0][inv], stats
 
 
 def _execute_dense(plan: InterpolationPlan, qx, qy):
@@ -541,10 +552,11 @@ def _execute_idw(plan: InterpolationPlan, qx, qy):
     qx2 = pad_to(qx, plan.block_q, zero)[:, None]
     qy2 = pad_to(qy, plan.block_q, zero)[:, None]
     dx2, dy2, dz2 = plan.data
-    z = idw_tiled_soa(
-        dx2, dy2, dz2, qx2, qy2, alpha=plan.idw_alpha,
-        block_q=plan.block_q, block_d=plan.block_d, interpret=plan.interpret,
-    )
+    with jax.named_scope("aidw.phase2"):
+        z = idw_tiled_soa(
+            dx2, dy2, dz2, qx2, qy2, alpha=plan.idw_alpha,
+            block_q=plan.block_q, block_d=plan.block_d, interpret=plan.interpret,
+        )
     alpha = jnp.full((n,), plan.idw_alpha, dtype)
     return z[:n, 0], alpha, {}
 

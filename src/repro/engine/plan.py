@@ -31,6 +31,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.aidw import AIDWParams
 from repro.core.grid import (
     DEFAULT_OCCUPANCY,
@@ -651,6 +652,7 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
                 data=data, grid=grid, r_need=r_need, **ff)
 
 
+@telemetry.spanned("plan.build")
 def build_plan(
     dx, dy, dz, *,
     params: AIDWParams = AIDWParams(),
@@ -836,6 +838,7 @@ def build_plan(
     return InterpolationPlan(**fields)
 
 
+@telemetry.spanned("plan.replan")
 def replan_with_capacity(
     plan: InterpolationPlan, *,
     min_cand_capacity: int | None = None,

@@ -105,4 +105,5 @@ def aidw_fused_soa(
         + [pltpu.VMEM((block_q, 1), dtype) for _ in range(5)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_fused_kernel",
     )(qx, qy, dx, dy, dz)

@@ -247,6 +247,7 @@ def phase1_alpha_from_candidates(
             scratch_shapes=scratch,
             compiler_params=_SEMANTICS,
             interpret=interpret,
+            name="_knn_kernel_soa",
         )(qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -267,6 +268,7 @@ def phase1_alpha_from_candidates(
         out_shape=out_shape,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_knn_kernel_skip",
     )(num_tiles.astype(jnp.int32), qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
 
 
@@ -338,6 +340,7 @@ def phase2_near_weights(
         out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 4,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_near_weight_kernel",
     )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half,
       *map(_row_tiles, (cand_x, cand_y, cand_z)))
 
@@ -410,6 +413,7 @@ def phase2_far_aggregates(
         out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 2,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_far_cell_kernel",
     )(rects.astype(jnp.int32).reshape(-1), qx2, qy2, alpha_half, fx, fy, fix, fiy, fcnt, fzs)
 
 
@@ -492,6 +496,7 @@ def phase2_far_nodes(
         out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 2,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_far_node_kernel",
     )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half,
       *map(_row_tiles, (node_x, node_y, node_cnt, node_zs, node_mx, node_my)))
 
@@ -520,4 +525,5 @@ def phase2_weights_full(
         scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_weight_kernel_soa",
     )(qx2, qy2, alpha * 0.5, dxp, dyp, dzp)

@@ -82,6 +82,7 @@ def aidw_naive_soa(
         out_shape=[jax.ShapeDtypeStruct((n, 1), dtype)] * 2,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_naive_kernel_soa",
     )(qx, qy, dx, dy, dz)
 
 
@@ -104,4 +105,5 @@ def aidw_naive_aoas(
         out_shape=[jax.ShapeDtypeStruct((1, n), dtype)] * 2,
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_naive_kernel_aoas",
     )(qx, qy, data)

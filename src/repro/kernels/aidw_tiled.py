@@ -107,27 +107,31 @@ def aidw_tiled_soa(
     d_spec = pl.BlockSpec((1, block_d), lambda i, j: (0, j))
     o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
 
-    alpha = pl.pallas_call(
-        functools.partial(_knn_kernel_soa, m_real=m_real, area=area, params=params, nbins=nbins),
-        grid=grid,
-        in_specs=[q_spec, q_spec, d_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, k), dtype)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-    )(qx, qy, dx, dy)
+    with jax.named_scope("aidw.phase1"):
+        alpha = pl.pallas_call(
+            functools.partial(_knn_kernel_soa, m_real=m_real, area=area, params=params, nbins=nbins),
+            grid=grid,
+            in_specs=[q_spec, q_spec, d_spec, d_spec],
+            out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, k), dtype)],
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_knn_kernel_soa",
+        )(qx, qy, dx, dy)
 
-    zhat = pl.pallas_call(
-        functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
-        grid=grid,
-        in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-    )(qx, qy, alpha * 0.5, dx, dy, dz)
+    with jax.named_scope("aidw.phase2"):
+        zhat = pl.pallas_call(
+            functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
+            grid=grid,
+            in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
+            out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_weight_kernel_soa",
+        )(qx, qy, alpha * 0.5, dx, dy, dz)
     return zhat, alpha
 
 
@@ -192,25 +196,29 @@ def aidw_tiled_aoas(
     d_spec = pl.BlockSpec((block_d, 4), lambda i, j: (j, 0))
     o_spec = pl.BlockSpec((1, block_q), lambda i, j: (0, i))
 
-    alpha = pl.pallas_call(
-        functools.partial(_knn_kernel_aoas, m_real=m_real, area=area, params=params),
-        grid=grid,
-        in_specs=[q_spec, q_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n), dtype),
-        scratch_shapes=[pltpu.VMEM((k, block_q), dtype)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-    )(qx, qy, data)
+    with jax.named_scope("aidw.phase1"):
+        alpha = pl.pallas_call(
+            functools.partial(_knn_kernel_aoas, m_real=m_real, area=area, params=params),
+            grid=grid,
+            in_specs=[q_spec, q_spec, d_spec],
+            out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((1, n), dtype),
+            scratch_shapes=[pltpu.VMEM((k, block_q), dtype)],
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_knn_kernel_aoas",
+        )(qx, qy, data)
 
-    zhat = pl.pallas_call(
-        functools.partial(_weight_kernel_aoas, eps=params.exact_hit_eps),
-        grid=grid,
-        in_specs=[q_spec, q_spec, q_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n), dtype),
-        scratch_shapes=[pltpu.VMEM((1, block_q), dtype) for _ in range(4)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-    )(qx, qy, alpha * 0.5, data)
+    with jax.named_scope("aidw.phase2"):
+        zhat = pl.pallas_call(
+            functools.partial(_weight_kernel_aoas, eps=params.exact_hit_eps),
+            grid=grid,
+            in_specs=[q_spec, q_spec, q_spec, d_spec],
+            out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((1, n), dtype),
+            scratch_shapes=[pltpu.VMEM((1, block_q), dtype) for _ in range(4)],
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_weight_kernel_aoas",
+        )(qx, qy, alpha * 0.5, data)
     return zhat, alpha

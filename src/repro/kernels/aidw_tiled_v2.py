@@ -82,6 +82,7 @@ def aidw_knn_v2(
         scratch_shapes=[pltpu.VMEM((block_q, k), dtype)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_knn_kernel_v2",
     )(qx, qy, dx, dy)
 
 
@@ -96,21 +97,24 @@ def aidw_tiled_v2_soa(
     n, m = qx.shape[0], dx.shape[1]
     dtype = qx.dtype
     grid = (n // block_q, m // block_d)
-    alpha, merges = aidw_knn_v2(
-        dx, dy, qx, qy, params=params, area=area, m_real=m_real,
-        block_q=block_q, block_d=block_d, interpret=interpret,
-    )
+    with jax.named_scope("aidw.phase1"):
+        alpha, merges = aidw_knn_v2(
+            dx, dy, qx, qy, params=params, area=area, m_real=m_real,
+            block_q=block_q, block_d=block_d, interpret=interpret,
+        )
     q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
     d_spec = pl.BlockSpec((1, block_d), lambda i, j: (0, j))
     o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-    zhat = pl.pallas_call(
-        functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
-        grid=grid,
-        in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-    )(qx, qy, alpha * 0.5, dx, dy, dz)
+    with jax.named_scope("aidw.phase2"):
+        zhat = pl.pallas_call(
+            functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
+            grid=grid,
+            in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
+            out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_weight_kernel_soa",
+        )(qx, qy, alpha * 0.5, dx, dy, dz)
     return zhat, alpha, merges

@@ -62,4 +62,5 @@ def idw_tiled_soa(
         scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="_idw_kernel",
     )(qx, qy, dx, dy, dz)

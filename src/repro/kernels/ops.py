@@ -7,10 +7,10 @@ autodetection, the grid snapshot — all captured once, in one place) and
 runs the jitted ``execute`` step.  Repeated convenience calls against the
 *same* data arrays reuse one memoized plan — since PR 9 the memo is the
 process-default :class:`repro.serving.PlanRegistry` (bounded LRU, identity
-guards, counters; ``_PLAN_CACHE``/``_plan_cache_counters`` remain as
-read-only shims over it), so they stop paying the plan rebuild; callers
-that interpolate many query batches should still hold the plan themselves
-— it is explicit about lifetime and survives array identity changes:
+guards, counters: ``default_registry().stats()``), so they stop paying the
+plan rebuild; callers that interpolate many query batches should still
+hold the plan themselves — it is explicit about lifetime and survives
+array identity changes:
 
     from repro.engine import build_plan, execute
     plan = build_plan(dx, dy, dz, params=p, area=1.0, impl="grid")
@@ -60,18 +60,6 @@ def _cached_build_plan(dx, dy, dz, **config):
     return default_registry().get_or_build(
         key, lambda: build_plan(dx, dy, dz, **config), guards=(dx, dy, dz)
     )
-
-
-def __getattr__(name):
-    # Back-compat shims over the serving registry for the PR-4 cache
-    # internals: the entry dict (entries are (guards, plan) tuples, as
-    # before) and the 2-key counter view.
-    if name == "_PLAN_CACHE":
-        return default_registry()._entries
-    if name == "_plan_cache_counters":
-        stats = default_registry().stats()
-        return {"hits": stats["hits"], "misses": stats["misses"]}
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def aidw(

@@ -44,6 +44,7 @@ import threading
 import time
 import warnings
 
+from repro import telemetry
 from repro.errors import PlanBuildError, PlanDegradedWarning
 from repro.serving import faults
 
@@ -94,7 +95,8 @@ class CapacityReestimator:
         self._need_max = 0
         self.last_error: PlanBuildError | None = None
         self.counters = {"batches": 0, "triggers": 0, "replans": 0,
-                         "build_failures": 0, "swaps": 0, "degraded": 0}
+                         "build_failures": 0, "swaps": 0, "degraded": 0,
+                         "overflow_queries": 0, "replan_s": 0.0}
         if key not in registry:
             registry.register(key, plan)
 
@@ -127,19 +129,24 @@ class CapacityReestimator:
         from repro.engine.execute import _execute_with_stats_jit, _note_overflow
 
         plan = self.plan
-        z, a, stats = _execute_with_stats_jit(plan, qx, qy)
-        if not isinstance(stats["overflow_queries"], jax.core.Tracer):
-            stats = dict(faults.fire("reestimator.stats", dict(stats)))
-            n_overflow = int(stats["overflow_queries"])
-            with self._lock:
-                self.counters["batches"] += 1
-                self._need_max = max(self._need_max,
-                                     int(stats["cand_need_max"]))
-            persistent = _note_overflow(plan, n_overflow)
-            stats["persistent_overflow"] = persistent
-            if persistent:
-                self._maybe_replan(plan)
-        self._deliver_pending()
+        with telemetry.span("serving.execute", call=self.counters["batches"]):
+            with telemetry.span("serving.dispatch"):
+                z, a, stats = _execute_with_stats_jit(plan, qx, qy)
+            if not isinstance(stats["overflow_queries"], jax.core.Tracer):
+                stats = dict(faults.fire("reestimator.stats", dict(stats)))
+                with telemetry.span("serving.sync"):
+                    n_overflow = int(stats["overflow_queries"])
+                    need = int(stats["cand_need_max"])
+                with telemetry.span("serving.observe"):
+                    with self._lock:
+                        self.counters["batches"] += 1
+                        self.counters["overflow_queries"] += n_overflow
+                        self._need_max = max(self._need_max, need)
+                    persistent = _note_overflow(plan, n_overflow)
+                    stats["persistent_overflow"] = persistent
+                    if persistent:
+                        self._maybe_replan(plan)
+            self._deliver_pending()
         return z, a, stats
 
     # ------------------------------------------------------ replan machinery
@@ -170,6 +177,15 @@ class CapacityReestimator:
         return min(max(int(plan.cand_capacity * self.growth), need), cap)
 
     def _replan(self, plan, need: int):
+        t0 = time.perf_counter()
+        try:
+            with telemetry.span("serving.replan"):
+                self._replan_and_swap(plan, need)
+        finally:
+            with self._lock:
+                self.counters["replan_s"] += time.perf_counter() - t0
+
+    def _replan_and_swap(self, plan, need: int):
         from repro.engine.plan import replan_with_capacity
 
         try:
@@ -264,7 +280,11 @@ class CapacityReestimator:
             self.last_error = None
 
     def stats(self) -> dict:
-        """Snapshot: counters + state + the installed plan's capacity."""
+        """Snapshot: counters + state + the installed plan's capacity.
+
+        Besides the event counts, ``overflow_queries`` sums every served
+        batch's overflowed queries and ``replan_s`` the seconds background
+        re-plans took, build to swap."""
         with self._lock:
             out = dict(self.counters, state=self._state,
                        need_max=self._need_max)

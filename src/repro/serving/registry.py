@@ -35,6 +35,7 @@ import threading
 import weakref
 from collections import OrderedDict
 
+from repro import telemetry
 from repro.serving import faults
 
 
@@ -63,8 +64,7 @@ class PlanRegistry:
             raise ValueError(f"max_plans must be >= 1, got {max_plans!r}")
         self.max_plans = int(max_plans)
         # key -> (guards, plan); guards is a tuple of weakrefs (possibly
-        # empty).  The tuple layout is load-bearing: kernels/ops.py exposes
-        # this dict as the back-compat ``_PLAN_CACHE``.
+        # empty)
         self._entries: OrderedDict = OrderedDict()
         # RLock, not Lock: a guard's weakref eviction callback can fire
         # during a GC that happens to run inside a locked section on the
@@ -143,6 +143,7 @@ class PlanRegistry:
         return self.register(key, build(), guards=guards, warmup=warmup)
 
     # ----------------------------------------------------------- hot-swap
+    @telemetry.spanned("registry.swap")
     def swap(self, key, plan, *, warmup=None):
         """Atomically replace the plan under ``key``; returns the old plan.
 
@@ -210,8 +211,8 @@ class PlanRegistry:
 
 
 # Process-default registry: backs the convenience-API memoization in
-# kernels/ops.py (which keeps plan_cache_clear()/_PLAN_CACHE as thin shims
-# over it) and is the default home for serving sessions.
+# kernels/ops.py (``plan_cache_clear()`` clears it) and is the default home
+# for serving sessions.
 _default: PlanRegistry | None = None
 _default_lock = threading.Lock()
 

@@ -24,6 +24,7 @@ from repro.core.grid import build_grid, cell_of, grid_r_obs, seam_layout, seam_s
 from repro.engine import build_plan, execute, execute_with_stats
 from repro.errors import CapacityOverflowWarning
 from repro.kernels import aidw, ops
+from repro.serving import default_registry
 
 RTOL, ATOL = 2e-4, 2e-5
 
@@ -317,6 +318,11 @@ def test_execute_with_stats_composes_under_outer_jit():
 
 
 # --------------------------------------------------- convenience plan memoization
+def _hits_misses(registry) -> dict:
+    stats = registry.stats()
+    return {"hits": stats["hits"], "misses": stats["misses"]}
+
+
 def test_ops_plan_cache_reuses_plan():
     """Two aidw() calls on the same data arrays must build ONE plan (weak-ref
     cache keyed on array ids + statics); new arrays — even equal ones — miss."""
@@ -325,15 +331,15 @@ def test_ops_plan_cache_reuses_plan():
     qx, qy = rng.random(100).astype(np.float32), rng.random(100).astype(np.float32)
     qx2, qy2 = rng.random(100).astype(np.float32), rng.random(100).astype(np.float32)
     p = AIDWParams(k=10, area=1.0)
+    registry = default_registry()
     ops.plan_cache_clear()
     z1, a1 = aidw(dx, dy, dz, qx, qy, params=p, area=1.0, impl="grid")
-    assert ops._plan_cache_counters == {"hits": 0, "misses": 1}
-    (entry,) = ops._PLAN_CACHE.values()
-    plan_first = entry[1]
+    assert _hits_misses(registry) == {"hits": 0, "misses": 1}
+    (plan_first,) = registry.plans()
     z2, a2 = aidw(dx, dy, dz, qx2, qy2, params=p, area=1.0, impl="grid")
-    assert ops._plan_cache_counters == {"hits": 1, "misses": 1}
-    (entry,) = ops._PLAN_CACHE.values()
-    assert entry[1] is plan_first, "second call must reuse the same plan object"
+    assert _hits_misses(registry) == {"hits": 1, "misses": 1}
+    (plan_second,) = registry.plans()
+    assert plan_second is plan_first, "second call must reuse the same plan object"
     # a same-shape second batch through the cached plan matches a fresh plan
     fresh = build_plan(dx, dy, dz, params=p, area=1.0, impl="grid")
     z_ref, a_ref = execute(fresh, jnp.asarray(qx2), jnp.asarray(qy2))
@@ -341,15 +347,15 @@ def test_ops_plan_cache_reuses_plan():
     np.testing.assert_array_equal(np.asarray(a2), np.asarray(a_ref))
     # different array objects (equal contents) are a different dataset identity
     z3, _ = aidw(dx.copy(), dy.copy(), dz.copy(), qx, qy, params=p, area=1.0, impl="grid")
-    assert ops._plan_cache_counters["misses"] == 2
+    assert registry.stats()["misses"] == 2
     np.testing.assert_array_equal(np.asarray(z3), np.asarray(z1))
     # dropping the data arrays evicts their entry (no pinned dataset copies)
-    n_before = len(ops._PLAN_CACHE)
-    del dx, dy, dz, entry, plan_first
+    n_before = len(registry)
+    del dx, dy, dz, plan_first, plan_second
     import gc
 
     gc.collect()
-    assert len(ops._PLAN_CACHE) < n_before
+    assert len(registry) < n_before
     ops.plan_cache_clear()
 
 
@@ -361,6 +367,6 @@ def test_ops_plan_cache_distinguishes_config():
     ops.plan_cache_clear()
     aidw(dx, dy, dz, qx, qy, params=p, area=1.0, impl="grid")
     aidw(dx, dy, dz, qx, qy, params=p, area=1.0, impl="tiled", block_q=64, block_d=128)
-    assert ops._plan_cache_counters == {"hits": 0, "misses": 2}
-    assert len(ops._PLAN_CACHE) == 2
+    assert _hits_misses(default_registry()) == {"hits": 0, "misses": 2}
+    assert len(default_registry()) == 2
     ops.plan_cache_clear()
