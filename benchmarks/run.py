@@ -323,9 +323,9 @@ def grid_blend(quick=False, smoke=False, json_path=None):
     Three serving-shaped scenarios against the grid plan, each parity-checked
     (eager AND jitted execute vs the exact chunked ring-search oracle):
 
-      uniform   — full-bbox batch on uniform data: prefetch-skip vs dense
-                  Phase-1 pipelines (same gather, same kernel body; the skip
-                  pipeline clamps each block to its own non-sentinel tiles).
+      uniform   — full-bbox batch on uniform data: prefetch vs dense
+                  Phase-1 pipelines (prefetch walks each block's CSR row
+                  runs in place; dense gathers them into capacity rows).
       clustered — tile-local sparse batch on clustered data: the skip
                   fraction is highest here (most blocks need few tiles).
       seam      — mostly tile-local batch plus a small full-diagonal slice
@@ -369,7 +369,7 @@ def grid_blend(quick=False, smoke=False, json_path=None):
         assert err < 1e-3, (tag, err)
         return err
 
-    # ---- uniform + clustered: dense vs prefetch-skip pipelines
+    # ---- uniform + clustered: dense vs prefetch (row-run) pipelines
     for dist, gen in (("uniform", uniform_points), ("clustered", clustered_points)):
         dxn, dyn, dzn = gen(m, seed=0)
         dx, dy, dz = map(jnp.asarray, (dxn, dyn, dzn))
@@ -453,7 +453,7 @@ def grid_blend(quick=False, smoke=False, json_path=None):
                         "ring_full is PR-2's whole-batch lax.cond exact arm (its "
                         "batch latency lower bound); blend_exec is the shipped "
                         "path end to end; dense vs prefetch differ only in the "
-                        "Phase-1 pipeline (same gather, same kernel body).",
+                        "Phase-1 pipeline (gathered rows vs CSR row runs read in place).",
         }
         with open(json_path, "w") as f:
             json.dump(blob, f, indent=2)

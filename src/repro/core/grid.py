@@ -104,6 +104,17 @@ class UniformGrid:
     def n_points(self) -> int:
         return self.pt_x.shape[0] - 1
 
+    @functools.cached_property
+    def point_cells(self) -> jnp.ndarray:
+        """``(n_points,)`` int32 cell of each CSR point, packed ``cy << 16 |
+        cx`` — what the row-run Phase 1 masks its lanes by.  Computed on
+        first use and kept with the grid, so every plan of one grid shares
+        it."""
+        if self.gx > 0xFFFF or self.gy > 0x7FFF:
+            raise ValueError(f"grid {self.gx}x{self.gy} is too large to pack a cell "
+                             "into one int32 (gx <= 65535, gy <= 32767)")
+        return _packed_point_cells(self.starts, self.gx, self.n_points)
+
     def tree_flatten(self):
         children = (self.origin, self.cell_size, self.cell_x, self.cell_y,
                     self.cell_z, self.counts, self.cum, self.pt_x, self.pt_y,
@@ -116,6 +127,12 @@ class UniformGrid:
         return cls(gx, gy, cap, *children)
 
 
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _packed_point_cells(starts, gx: int, m: int):
+    cid = jnp.searchsorted(starts, jnp.arange(m, dtype=jnp.int32), side="right") - 1
+    return jnp.left_shift(cid // gx, 16) | (cid % gx)
 
 
 def build_grid(

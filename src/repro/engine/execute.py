@@ -9,8 +9,9 @@ request" serving shape.
 The grid path (DESIGN.md §6) runs entirely under the trace: Morton sort,
 seam-split block layout, per-query safe radii from the plan's
 ``required_radius`` table (closed form — no while-loop), the
-static-capacity CSR candidate gather, the sparsity-skipping Phase 1 over
-candidate rows and the full-data Phase 2 — or, for
+Phase 1 reading each block's CSR row runs in place (or, for
+``pipeline="dense"``, over candidate rows gathered to the static capacity)
+and the full-data Phase 2 — or, for
 ``build_plan(phase2="farfield")`` plans, the near/far split Phase 2 with a
 plan-proved error bound (DESIGN.md §7), or, for ``phase2="quadtree"``
 plans, the multi-level Barnes–Hut far field whose per-node opening
@@ -49,10 +50,14 @@ from repro.kernels.aidw_grid import (
     block_rectangles,
     gather_candidates_csr,
     phase1_alpha_from_candidates,
+    phase1_alpha_row_runs,
     phase2_far_aggregates,
     phase2_far_nodes,
     phase2_near_weights,
     phase2_weights_full,
+    rectangle_need,
+    row_run_max_tiles,
+    row_run_tiles,
 )
 from repro.kernels.aidw_naive import aidw_naive_aoas, aidw_naive_soa
 from repro.kernels.aidw_tiled import aidw_tiled_aoas, aidw_tiled_soa
@@ -357,34 +362,49 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
         r_need = plan.r_need[cy_v, cx_v]
         r_safe = safe_radius_from_need(grid, qx_v, qy_v, cx_v, cy_v, r_need)
         xlo, xhi, ylo, yhi = block_rectangles(grid, cx_v, cy_v, r_safe, plan.block_q)
-        cand_x, cand_y, need = gather_candidates_csr(
-            grid, xlo, xhi, ylo, yhi, plan.cand_capacity
-        )
+        need = rectangle_need(grid, xlo, xhi, ylo, yhi)
+        over_b = need > plan.cand_capacity
+        # the row-run walk's tile list (an overflowing block walks nothing:
+        # the ring-search arm answers it); the dense pipeline keeps only the
+        # count, for phase1_tile_fill
+        tiles, run_tiles = row_run_tiles(
+            grid, xlo, xhi, ylo, yhi, plan.row_tile,
+            row_run_max_tiles(plan.cand_capacity, plan.row_tile, grid.gy))
+        run_tiles = jnp.where(over_b, 0, run_tiles)
+        if plan.pipeline == "dense":
+            cand_x, cand_y, _ = gather_candidates_csr(
+                grid, xlo, xhi, ylo, yhi, plan.cand_capacity
+            )
         n_tiles_static = plan.cand_capacity // plan.cand_block_d
-        # always the prefetch-style count: the dense pipeline ignores it but the
-        # skipped_tile_fraction diagnostic reports what the launch WOULD skip
+        # the capacity tiles each block's candidates occupy, for the
+        # skipped_tile_fraction diagnostic
         num_tiles = _tile_table(need, plan.cand_capacity, plan.cand_block_d,
                                 "prefetch")
 
-    # Phase 1, always on the kernel path: the per-block tile table clamps
-    # each block's walk to its own non-sentinel tiles ("prefetch"), and an
-    # overflowing block simply computes a (cheap, discarded) alpha from its
-    # first `cand_capacity` candidates
+    # Phase 1, always on the kernel path; an overflowing block's alpha is
+    # discarded by the blend below
     with jax.named_scope("aidw.phase1"):
-        alpha_fast = phase1_alpha_from_candidates(
-            qx_v, qy_v, cand_x, cand_y,
-            params=params, area=plan.area, m_real=plan.m,
-            block_q=plan.block_q, block_d=plan.cand_block_d,
-            interpret=plan.interpret,
-            num_tiles=num_tiles if plan.pipeline == "prefetch" else None,
-        )
+        if plan.pipeline == "prefetch":
+            alpha_fast = phase1_alpha_row_runs(
+                qx_v, qy_v, tiles, run_tiles,
+                jnp.stack([xlo, xhi, ylo, yhi], axis=1),
+                (grid.pt_x, grid.pt_y, plan.row_cells),
+                tile=plan.row_tile, params=params, area=plan.area, m_real=plan.m,
+                block_q=plan.block_q, interpret=plan.interpret,
+            )
+        else:
+            alpha_fast = phase1_alpha_from_candidates(
+                qx_v, qy_v, cand_x, cand_y,
+                params=params, area=plan.area, m_real=plan.m,
+                block_q=plan.block_q, block_d=plan.cand_block_d,
+                interpret=plan.interpret,
+            )
 
     # Per-block overflow blend: back in the sorted layout, ring-search ONLY
     # queries whose block overflowed (masked — a clean batch adds zero loop
     # iterations) and keep the kernel alpha everywhere else.  Exactness is
     # per query: kernel where covered, ring search where not.
     with jax.named_scope("aidw.ring_search"):
-        over_b = need > plan.cand_capacity
         over_v = jnp.repeat(over_b, plan.block_q)
         if dest is not None:
             alpha_fast = alpha_fast[dest]
@@ -455,6 +475,10 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
             "overflow_query_mask": over_q[:n][inv],
             "skipped_tile_fraction": 1.0
             - jnp.sum(jnp.where(real_b, num_tiles, 0)).astype(jnp.float32) / n_real_tiles,
+            # rectangle points per lane the row-run walk reads
+            "phase1_tile_fill": jnp.sum(jnp.where(real_b & ~over_b, need, 0).astype(jnp.float32))
+            / jnp.maximum(jnp.sum(jnp.where(real_b, run_tiles, 0).astype(jnp.float32))
+                          * plan.row_tile, 1.0),
         }
         if plan.phase2 in ("farfield", "quadtree"):
             n_real_b = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)), 1).astype(jnp.float32)
@@ -666,8 +690,10 @@ def execute_with_stats(plan: InterpolationPlan, qx, qy):
     batch exceeded the plan's static candidate capacity and took the exact
     masked ring-search arm of the blend), ``overflow_query_mask`` (bool
     ``(n,)``, caller order — which queries those were),
-    ``skipped_tile_fraction`` (share of Phase-1 candidate-tile steps the
-    scalar-prefetch pipeline skipped as all-sentinel), ``cand_need_max``,
+    ``skipped_tile_fraction`` (share of the static capacity's Phase-1 tiles
+    that hold no candidate of their block), ``phase1_tile_fill`` (share of
+    the lanes the row-run Phase 1 walks that hold a rectangle point, over
+    blocks with real queries — what aligned tiles waste), ``cand_need_max``,
     ``grid_fallback`` (bool — EVERY query overflowed, i.e. the batch got no
     kernel fast path at all; single blocks overflowing no longer drag the
     batch down), and ``persistent_overflow`` (host-side bool — overflow has

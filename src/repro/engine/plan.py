@@ -68,6 +68,14 @@ _FALLBACK_BOUND_CEIL = 0.5
 # capped so the in-kernel (block_q, tile_d) f32 distance tile stays ~1 MiB.
 _P2_TILE_ELEMS = 64 * 4096
 
+# Row-run Phase 1 (DESIGN.md §6): the CSR tile widths it may walk, and a
+# grid step's fixed cost in lanes of k-best merge work, fitted on a TPU v5e
+# to the kernel's times at all three widths on 1M uniform points.  A row run
+# of L points walked in T-point tiles costs about
+# (L + T - 1) * (_STEP_LANES / T + 1) lanes, least near sqrt(_STEP_LANES * L).
+_ROW_TILES = (128, 256, 512)
+_STEP_LANES = 300
+
 
 def _auto_interpret(interpret: bool | None) -> bool:
     """Pallas interpret mode only where it is the one way to run: on the
@@ -118,7 +126,7 @@ class InterpolationPlan:
     cand_block_d: int         # grid: Phase-1 candidate tile (autotuned)
     grid_rebuilds: int        # grid: coarsening rebuilds during planning
     seam_level: int           # grid: Morton quadrant split depth (0 = off)
-    pipeline: str             # grid Phase 1: "prefetch" (tile-skip) | "dense"
+    pipeline: str             # grid Phase 1: "prefetch" (row runs) | "dense"
     phase2: str               # grid Phase 2: "exact" (full sweep) | "farfield"
     farfield_rtol: float      # farfield: user-requested relative error target
     farfield_radius: int      # far field/quadtree: near-field radius (cells)
@@ -128,12 +136,14 @@ class InterpolationPlan:
     p2_far_block_d: int       # farfield: far cell-aggregate sweep tile
     qt_tau: float             # quadtree: effective opening ratio tau_eff
     qt_levels: tuple          # quadtree: per-level (nx, ny, step, k_pad, tile)
+    row_tile: int             # grid Phase 1: CSR tile of the row-run walk
     # --- children ---
     data: tuple               # impl-specific padded arrays
     grid: UniformGrid | None
     r_need: jnp.ndarray | None  # (gy, gx) int32 per-cell required_radius
     far: tuple                # farfield: padded (1, ncp) cell-aggregate arrays
                               # quadtree: per-level node-aggregate tuples
+    row_cells: jnp.ndarray | None  # grid "prefetch": packed cell per CSR point
 
     def tree_flatten(self):
         aux = (self.impl, self.layout, self.params, self.area, self.m,
@@ -143,13 +153,14 @@ class InterpolationPlan:
                self.seam_level, self.pipeline, self.phase2,
                self.farfield_rtol, self.farfield_radius, self.farfield_bound,
                self.p2_capacity, self.p2_block_d, self.p2_far_block_d,
-               self.qt_tau, self.qt_levels)
-        return (self.data, self.grid, self.r_need, self.far), aux
+               self.qt_tau, self.qt_levels, self.row_tile)
+        return (self.data, self.grid, self.r_need, self.far, self.row_cells), aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        data, grid, r_need, far = children
-        return cls(*aux, data=data, grid=grid, r_need=r_need, far=far)
+        data, grid, r_need, far, row_cells = children
+        return cls(*aux, data=data, grid=grid, r_need=r_need, far=far,
+                   row_cells=row_cells)
 
 
 def _choose_candidate_capacity(grid: UniformGrid, r_need, block_q: int, m: int,
@@ -181,6 +192,14 @@ def _choose_candidate_capacity(grid: UniformGrid, r_need, block_q: int, m: int,
     window = min(side + 2 * r_static + 1, max(grid.gx, grid.gy))
     capacity = _densest_window_count(grid, window)
     return capacity, r_static, window, side
+
+
+def _choose_row_tile(grid: UniformGrid, m: int, window: int) -> int:
+    """Tile width of the row-run Phase 1 from the plan's mean row run: the
+    mean cell occupancy times the capacity model's rectangle width."""
+    run = max(m / max(grid.n_cells, 1), 1.0) * window
+    best = math.sqrt(_STEP_LANES * run)
+    return min(_ROW_TILES, key=lambda t: abs(math.log2(t / best)))
 
 
 def _densest_window_count(grid: UniformGrid, window: int) -> int:
@@ -492,7 +511,7 @@ def _choose_seam_level(grid: UniformGrid, window: int) -> int:
 
 
 def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
-               query_occupancy, seam_level, phase2, farfield_rtol,
+               query_occupancy, seam_level, pipeline, phase2, farfield_rtol,
                farfield_radius, min_cand_capacity=None, min_p2_capacity=None):
     """Grid-impl plan: snapshot + static capacity + block_d autotune.
 
@@ -649,6 +668,8 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
 
     return dict(block_d=bd2, cand_capacity=cand_capacity, cand_block_d=cand_block_d,
                 grid_rebuilds=rebuilds, seam_level=int(seam_level),
+                row_tile=_choose_row_tile(grid, m, window),
+                row_cells=grid.point_cells if pipeline == "prefetch" else None,
                 data=data, grid=grid, r_need=r_need, **ff)
 
 
@@ -698,9 +719,10 @@ def build_plan(
     top-level Z-order seam (the rectangle-blowup worst case); ``None``
     auto-chooses from the occupancy histogram, ``0`` disables.
     ``pipeline`` (grid impl) selects the Phase-1 kernel: "prefetch" (default;
-    scalar-prefetch indexed tile table — sparse blocks skip their
-    all-sentinel candidate tiles) or "dense" (every block walks the full
-    static capacity; the conservative fallback, bit-identical results).
+    each block's CSR row runs are read in place, in aligned tiles listed
+    per block and scalar-prefetched) or "dense" (candidate rows are
+    gathered to the static capacity and every block walks all of it; the
+    oracle the default is tested against, bit-identical results).
     ``phase2`` (grid impl) selects the Phase-2 sweep: "exact" (default; the
     full m-point weighted sweep, bit-identical to every prior release),
     "farfield" (exact per-point weights only inside a plan-chosen near-field
@@ -804,8 +826,8 @@ def build_plan(
         phase2=phase2, farfield_rtol=float(farfield_rtol),
         farfield_radius=0, farfield_bound=0.0,
         p2_capacity=0, p2_block_d=0, p2_far_block_d=0,
-        qt_tau=0.0, qt_levels=(),
-        data=(), grid=None, r_need=None, far=(),
+        qt_tau=0.0, qt_levels=(), row_tile=0,
+        data=(), grid=None, r_need=None, far=(), row_cells=None,
     )
 
     if impl == "grid":
@@ -813,7 +835,7 @@ def build_plan(
             dx, dy, dz, params=params, block_q=block_q, block_d=block_d,
             grid=grid, target_occupancy=target_occupancy,
             query_occupancy=query_occupancy, seam_level=seam_level,
-            phase2=phase2, farfield_rtol=float(farfield_rtol),
+            pipeline=pipeline, phase2=phase2, farfield_rtol=float(farfield_rtol),
             farfield_radius=farfield_radius,
             min_cand_capacity=min_cand_capacity,
             min_p2_capacity=min_p2_capacity,

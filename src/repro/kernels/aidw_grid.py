@@ -17,25 +17,30 @@ of ``(snapshot arrays, queries, static capacity)`` and runs under ``jax.jit``:
   so the engine can fall back to the exact ring search when the plan-time
   capacity is exceeded (far out-of-bbox queries, adversarial batches) —
   the static fast path never silently drops a neighbour.
-* :func:`phase1_alpha_from_candidates` — Phase 1 (kNN → adaptive alpha) over
-  the candidate rows.  Two interchangeable pipelines behind one signature:
-  the **scalar-prefetch indexed** pipeline (default, ``num_tiles`` given)
-  drives a ``pltpu.PrefetchScalarGridSpec`` whose candidate index map clamps
-  each block's tile walk to its own non-sentinel tiles — a sparse block does
-  ``ceil(need/block_d)`` real steps instead of ``capacity/block_d`` (the
-  block-sparse / ragged-kernel idiom: clamped revisits cost no DMA, the
-  merge is predicated off) — and the **dense** fallback (``num_tiles=None``)
-  walks every tile with the same kernel body as the tiled version
-  (``_knn_kernel_soa``).  Either way per-query work is O(|neighbourhood|)
-  instead of O(m).
+* :func:`row_run_tiles` + :func:`phase1_alpha_row_runs` — Phase 1 (kNN →
+  adaptive alpha) of the default ``pipeline="prefetch"``, reading the CSR
+  row runs in place: a block's rectangle rows are contiguous runs of the
+  CSR twin, so :func:`row_run_tiles` lists the aligned ``tile``-point
+  slices of the CSR arrays that cover them (a ``(nb, max_tiles)`` int32
+  table, bounded by :func:`row_run_max_tiles`), and the kernel streams
+  those slices from HBM through a ``pltpu.PrefetchScalarGridSpec`` index
+  map, masking every lane whose point lies outside the block's rectangle
+  (or past the data's end, where blocks overrun it) — it merges exactly the
+  point set :func:`gather_candidates_csr` would have materialised, without
+  materialising it.
+* :func:`phase1_alpha_from_candidates` — the ``pipeline="dense"`` Phase 1
+  over materialised candidate rows: every block walks every tile of its
+  row with the tiled version's kernel body (``_knn_kernel_soa``).  It is
+  the oracle the row-run walk is tested against bit for bit.  Either way
+  per-query work is O(|neighbourhood|) instead of O(m).
 * :func:`phase2_weights_full` — exact Phase 2 (the default): AIDW weights
   ALL m data points, so the full-data sweep (``_weight_kernel_soa``) is
   reused verbatim.
 * :func:`phase2_near_weights` + :func:`phase2_far_aggregates` — the
   far-field approximated Phase 2 (``build_plan(phase2="farfield")``,
   DESIGN.md §7).  The near kernel sweeps exact per-point weights over the
-  block's near-rectangle candidate rows (same CSR gather, same
-  scalar-prefetch tile table as Phase 1 — sparse blocks skip their
+  block's near-rectangle candidate rows (the materialised CSR gather,
+  with a scalar-prefetched per-block tile count — sparse blocks skip their
   all-sentinel tail tiles) and returns the four partial accumulators
   ``(sum_w, sum_wz, min_d2, hit_z)`` instead of a finished z.  The far
   kernel sweeps the plan's per-cell aggregates (count, z-sum, centroid)
@@ -148,9 +153,78 @@ def gather_candidates_csr(grid: UniformGrid, xlo, xhi, ylo, yhi, capacity: int,
     return grid.pt_x[idx], grid.pt_y[idx], need
 
 
-# Index maps shared by the scalar-prefetch pipelines (Phase-1 skip, Phase-2
-# near, Phase-2 far); the first argument after (i, j) is the prefetched
-# scalar ref, unused by the query/output maps.
+def rectangle_need(grid: UniformGrid, xlo, xhi, ylo, yhi):
+    """Points inside each block's rectangle, ``(nb,)`` — O(1) per block from
+    the integral image (the ``need`` :func:`gather_candidates_csr` returns)."""
+    c = grid.cum
+    return (c[yhi + 1, xhi + 1] - c[ylo, xhi + 1]
+            - c[yhi + 1, xlo] + c[ylo, xlo])
+
+
+def row_run_max_tiles(capacity: int, tile: int, gy: int) -> int:
+    """Static bound on the tiles :func:`row_run_tiles` lists for a block
+    whose rectangle holds ``need <= capacity`` points.
+
+    Proof.  Tile ``t`` is the CSR slice ``[t*tile, (t+1)*tile)``.  A
+    non-empty row run ``[a, b)`` of ``L = b - a`` points meets the tiles
+    ``floor(a/tile) .. floor((b-1)/tile)``, and for integers ``p >= q``,
+    ``floor(p/T) - floor(q/T) <= (p - q + T - 1)/T``, so the run meets at
+    most ``(L - 1 + T - 1)/T + 1 < L/T + 2`` tiles.  Summed over the
+    rectangle's at most ``gy`` non-empty rows, the listed tiles number
+    fewer than ``need/T + 2*gy <= capacity/T + 2*gy``, hence at most
+    ``ceil(capacity/T) + 2*gy``.  Every listed tile also holds at least one
+    rectangle point, so there are at most ``need <= capacity`` of them.  A
+    block with ``need > capacity`` is answered by the ring-search arm, and
+    its walk is never launched.
+    """
+    return min(-(-capacity // tile) + 2 * gy, capacity)
+
+
+def row_run_tiles(grid: UniformGrid, xlo, xhi, ylo, yhi, tile: int, max_tiles: int):
+    """Aligned CSR tiles covering each block's rectangle rows, in place.
+
+    Row ``y`` of block ``i``'s rectangle is the CSR run ``[starts[y*gx +
+    xlo], starts[y*gx + xhi + 1])``.  The runs of successive rows follow one
+    another in CSR order (cell ids are row-major), so the tiles meeting them
+    are listed in increasing order, and a tile can repeat only where a row's
+    first tile is the previous non-empty row's last: it is listed once.
+
+    Returns ``(tiles (nb, max_tiles) int32, n_tiles (nb,) int32)``: block
+    ``i``'s first ``n_tiles[i]`` entries are its tiles, strictly increasing,
+    and the rest repeat its last tile (0 where it has none).  ``n_tiles`` is
+    the true count: past ``max_tiles`` the list is truncated, which
+    :func:`row_run_max_tiles` proves cannot happen to a block within the
+    plan's capacity.
+    """
+    gx, gy = grid.gx, grid.gy
+    rows = jnp.arange(gy, dtype=jnp.int32)[None, :]                 # (1, gy)
+    y = jnp.minimum(ylo[:, None] + rows, gy - 1)
+    a = grid.starts[y * gx + xlo[:, None]]
+    b = grid.starts[y * gx + xhi[:, None] + 1]
+    live = (rows <= (yhi - ylo)[:, None]) & (b > a)
+    first = a // tile
+    last = (b - 1) // tile
+    # last tile of the previous non-empty row: at most this row's first
+    seen = jax.lax.cummax(jnp.where(live, last, -1), axis=1)
+    prev = jnp.concatenate([jnp.full_like(seen[:, :1], -1), seen[:, :-1]], axis=1)
+    start = jnp.where(first > prev, first, first + 1)
+    cnt = jnp.where(live, last - start + 1, 0)
+    ends = jnp.cumsum(cnt, axis=1)
+    n_tiles = ends[:, -1]
+
+    s = jnp.arange(max_tiles, dtype=jnp.int32)[None, :]
+    s = jnp.minimum(s, jnp.maximum(n_tiles - 1, 0)[:, None])          # pad: last tile
+    row = jax.vmap(functools.partial(jnp.searchsorted, side="right"))(ends, s)
+    row = jnp.minimum(row, gy - 1)
+    at = functools.partial(jnp.take_along_axis, indices=row, axis=1)
+    tiles = at(start) + s - (at(ends) - at(cnt))
+    tiles = jnp.where(n_tiles[:, None] > 0, tiles, 0)
+    return tiles.astype(jnp.int32), n_tiles.astype(jnp.int32)
+
+
+# Index maps shared by the scalar-prefetch Phase-2 kernels (near, far cell,
+# far node); the first argument after (i, j) is the prefetched scalar ref,
+# unused by the query/output maps.
 def _pf_query_map(i, j, _scalar):
     return (i, 0)
 
@@ -181,15 +255,66 @@ def _pf_shared_tile_map(i, j, _scalar):
     return (0, j)
 
 
-def _knn_kernel_skip(nt_ref, qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best,
-                     *, m_real, area, params):
-    """Sparsity-skipping twin of ``_knn_kernel_soa``.
+def phase1_alpha_from_candidates(
+    qx_s, qy_s, cand_x, cand_y, *,
+    params: AIDWParams, area: float, m_real: int,
+    block_q: int, block_d: int, interpret: bool,
+):
+    """Phase 1 over materialised per-block candidate rows (``pipeline="dense"``).
 
-    ``nt_ref`` is the scalar-prefetched per-block tile count: steps past it
-    are clamped revisits of the block's last real tile (no DMA) and the
-    k-best merge is predicated off, so an all-sentinel tail costs grid
-    overhead only.  Init/finish still fire on the first/last *grid* step —
-    the output block is written exactly once per query block.
+    qx_s/qy_s: (n_tot,) Morton-sorted padded queries, ``n_tot % block_q == 0``;
+    cand_x/cand_y: (nb, c_tot) with ``c_tot % block_d == 0``.  Every block
+    streams all ``c_tot // block_d`` tiles of its row.
+    Returns alpha, shape ``(n_tot, 1)``.
+    """
+    n_tot = qx_s.shape[0]
+    nb, c_tot = cand_x.shape
+    dtype = qx_s.dtype
+    q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_knn_kernel_soa, m_real=m_real, area=area, params=params),
+        grid=(nb, c_tot // block_d),
+        in_specs=[q_spec, q_spec,
+                  _row_spec(block_d, lambda i, j: (i, 0, j)),
+                  _row_spec(block_d, lambda i, j: (i, 0, j))],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tot, 1), dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, params.k), dtype)],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="_knn_kernel_soa",
+    )(qx_s[:, None], qy_s[:, None], _row_tiles(cand_x), _row_tiles(cand_y))
+
+
+# Scalar-prefetch operands live in SMEM (1 MiB on a TPU v5e); one launch's
+# tile table, counts and rectangles are held to this many words, and a
+# larger batch launches over static chunks of blocks.
+_SMEM_TABLE_WORDS = 1 << 17
+
+# The row-run walk reads the CSR arrays in place, 1-D, in blocks of this many
+# points: a TPU lays a 1-D f32 array out in 1024-element tiles, and Mosaic
+# takes no smaller block of it.  Each step rotates its tile's lanes to the
+# front of the block it sits in.
+_ROW_BLOCK = 1024
+
+
+def _knn_kernel_skip(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, px_ref, py_ref,
+                     pc_ref, _alpha_in, alpha_ref, best, *, tile, max_tiles,
+                     m_real, area, params):
+    """Phase-1 kNN over a block's CSR row runs, one aligned tile a step.
+
+    ``tiles_ref`` (flat ``(nb * max_tiles,)``) lists each block's tiles and
+    ``nt_ref`` counts them: steps past the count are clamped revisits of the
+    block's last tile (no DMA) with the merge predicated off.  The point
+    refs hold the ``_ROW_BLOCK``-point block the step's tile lies in.  A lane
+    counts only if it holds a point (index below ``m_real``: the blocks run
+    past the arrays' ends) whose cell (packed ``cy << 16 | cx``) lies in the
+    block's rectangle ``rect_ref[4i : 4i+4] = (xlo, xhi, ylo, yhi)``; every
+    other lane reads ``d2 = +inf``, as a sentinel slot of a materialised
+    candidate row does.  Init/finish fire on the first/last
+    grid step, so the output block is written exactly once per query block.
+    ``_alpha_in`` is the output buffer itself (aliased, never read): a batch
+    launched in chunks writes one buffer, chunk by chunk.
     """
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -199,7 +324,22 @@ def _knn_kernel_skip(nt_ref, qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best,
 
     @pl.when(j < nt_ref[i])
     def _merge():
-        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])
+        t = tiles_ref[i * max_tiles + j]
+        shift = (_ROW_BLOCK - t % (_ROW_BLOCK // tile) * tile) % _ROW_BLOCK
+
+        def lanes(ref):
+            block = ref[...].reshape(1, _ROW_BLOCK)
+            return pltpu.roll(block, shift, 1)[:, :tile]
+
+        cell = lanes(pc_ref)
+        cx = jnp.bitwise_and(cell, 0xFFFF)
+        cy = jnp.right_shift(cell, 16)
+        index = t * tile + jax.lax.broadcasted_iota(jnp.int32, cell.shape, 1)
+        inside = ((index < m_real)
+                  & (cx >= rect_ref[4 * i]) & (cx <= rect_ref[4 * i + 1])
+                  & (cy >= rect_ref[4 * i + 2]) & (cy <= rect_ref[4 * i + 3]))
+        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], lanes(px_ref), lanes(py_ref))
+        d2 = jnp.where(inside, d2, jnp.asarray(jnp.inf, d2.dtype))
         best[...] = running_k_best(best[...], d2, axis=1)
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -207,69 +347,60 @@ def _knn_kernel_skip(nt_ref, qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best,
         alpha_ref[...] = alpha_from_best(best[...], m_real, area, params, data_axis=1)
 
 
-def phase1_alpha_from_candidates(
-    qx_s, qy_s, cand_x, cand_y, *,
-    params: AIDWParams, area: float, m_real: int,
-    block_q: int, block_d: int, interpret: bool,
-    num_tiles=None,
+def phase1_alpha_row_runs(
+    qx_s, qy_s, tiles, n_tiles, rects, points, *,
+    tile: int, params: AIDWParams, area: float, m_real: int,
+    block_q: int, interpret: bool,
 ):
-    """Phase 1 over per-block candidate rows.
+    """Phase 1 over each block's CSR row runs, read in place (``pipeline="prefetch"``).
 
     qx_s/qy_s: (n_tot,) Morton-sorted padded queries, ``n_tot % block_q == 0``;
-    cand_x/cand_y: (nb, c_tot) with ``c_tot % block_d == 0``.
+    tiles: (nb, max_tiles) int32 from :func:`row_run_tiles`; n_tiles: (nb,)
+    int32 tiles to walk per block (0 skips the block: its alpha is then
+    meaningless and must be discarded); rects: (nb, 4) int32 ``(xlo, xhi,
+    ylo, yhi)``; points: the grid's ``(pt_x, pt_y, point_cells)``, read in
+    place in ``_ROW_BLOCK``-point blocks (lanes past ``m_real`` are masked).
+
+    The step axis of each launch is as long as its longest block walk (a
+    traced bound), so the static ``max_tiles`` sizes only the SMEM table.
     Returns alpha, shape ``(n_tot, 1)``.
-
-    ``num_tiles`` (optional ``(nb,)`` int32, ``ceil(covered_need/block_d)``)
-    selects the scalar-prefetch pipeline: block ``i``'s candidate index map
-    becomes ``min(j, num_tiles[i]-1)`` so its all-sentinel tail tiles are
-    never fetched and never merged — the per-block tile table the plan's
-    launch-wide capacity cannot express.  ``None`` keeps the dense walk
-    (every block streams all ``c_tot // block_d`` tiles); both pipelines
-    merge identical non-sentinel candidates, so their alpha agrees exactly.
     """
-    n_tot = qx_s.shape[0]
-    nb, c_tot = cand_x.shape
     dtype = qx_s.dtype
+    nb, max_tiles = tiles.shape
     qx2, qy2 = qx_s[:, None], qy_s[:, None]
-    out_shape = jax.ShapeDtypeStruct((n_tot, 1), dtype)
-    scratch = [pltpu.VMEM((block_q, params.k), dtype)]
+    n_chunks = -(-nb * (max_tiles + 5) // _SMEM_TABLE_WORDS)
+    chunk = -(-nb // n_chunks)
+    alpha = jnp.zeros((nb * block_q, 1), dtype)
+    for b0 in range(0, nb, chunk):
+        b1 = min(b0 + chunk, nb)
+        nt = n_tiles[b0:b1]
 
-    if num_tiles is None:
-        q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-        c_spec = _row_spec(block_d, lambda i, j: (i, 0, j))
-        o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-        return pl.pallas_call(
-            functools.partial(_knn_kernel_soa, m_real=m_real, area=area, params=params),
-            grid=(nb, c_tot // block_d),
-            in_specs=[q_spec, q_spec, c_spec, c_spec],
-            out_specs=o_spec,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
+        def q_map(i, j, *_refs, b0=b0):
+            return (i + b0, 0)
+
+        def p_map(i, j, tiles_ref, nt_ref, _rect):
+            t = tiles_ref[i * max_tiles + jnp.maximum(jnp.minimum(j, nt_ref[i] - 1), 0)]
+            return (t * tile // _ROW_BLOCK,)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b1 - b0, jnp.maximum(jnp.max(nt), 1)),
+            in_specs=[pl.BlockSpec((block_q, 1), q_map)] * 2
+            + [pl.BlockSpec((_ROW_BLOCK,), p_map)] * 3 + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block_q, 1), q_map),
+            scratch_shapes=[pltpu.VMEM((block_q, params.k), dtype)],
+        )
+        alpha = pl.pallas_call(
+            functools.partial(_knn_kernel_skip, tile=tile, max_tiles=max_tiles,
+                              m_real=m_real, area=area, params=params),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(alpha.shape, dtype),
+            input_output_aliases={8: 0},
             compiler_params=_SEMANTICS,
             interpret=interpret,
-            name="_knn_kernel_soa",
-        )(qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, c_tot // block_d),
-        in_specs=[
-            pl.BlockSpec((block_q, 1), _pf_query_map),
-            pl.BlockSpec((block_q, 1), _pf_query_map),
-            _row_spec(block_d, _pf_clamped_tile_map),
-            _row_spec(block_d, _pf_clamped_tile_map),
-        ],
-        out_specs=pl.BlockSpec((block_q, 1), _pf_query_map),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(_knn_kernel_skip, m_real=m_real, area=area, params=params),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-        name="_knn_kernel_skip",
-    )(num_tiles.astype(jnp.int32), qx2, qy2, _row_tiles(cand_x), _row_tiles(cand_y))
+            name="_knn_kernel_skip",
+        )(tiles[b0:b1].reshape(-1), nt, rects[b0:b1].reshape(-1), qx2, qy2, *points, alpha)
+    return alpha
 
 
 def _near_weight_kernel(nt_ref, qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref,

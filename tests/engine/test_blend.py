@@ -5,14 +5,16 @@ Covers the three layers of the grid-path worst-case fix:
   candidate capacity get their alpha from the exact masked ring search,
   everyone else keeps the kernel result (regression for the ROADMAP m=100K
   seam-overflow batch, scaled down; full-size variant marked slow);
-* the scalar-prefetch tile-skipping Phase-1 pipeline vs its dense twin
-  (bit-identical results, nonzero skipped_tile_fraction on sparse batches);
+* the row-run Phase-1 pipeline vs its dense twin over the materialised
+  gather (bit-identical results on tile-local, full-width, edge-clipped,
+  voided, seam-split and overflowing batches, at every row tile);
 * Morton seam splitting of query blocks (layout invariants + a
   deterministic straddle whose overflow the split eliminates);
 plus the extended execute_with_stats diagnostics (static dict structure,
 no retrace) and the convenience-API plan memoization in kernels.ops.
 """
 
+import dataclasses
 import warnings
 
 import numpy as np
@@ -23,14 +25,15 @@ from repro.core.aidw import AIDWParams, adaptive_alpha, aidw_reference
 from repro.core.grid import build_grid, cell_of, grid_r_obs, seam_layout, seam_segment_ids
 from repro.engine import build_plan, execute, execute_with_stats
 from repro.errors import CapacityOverflowWarning
-from repro.kernels import aidw, ops
+from repro.kernels import aidw, aidw_grid, ops
 from repro.serving import default_registry
 
 RTOL, ATOL = 2e-4, 2e-5
 
 STATS_KEYS = {
     "grid_fallback", "cand_need_max", "overflow_blocks", "overflow_queries",
-    "overflow_query_mask", "skipped_tile_fraction", "persistent_overflow",
+    "overflow_query_mask", "skipped_tile_fraction", "phase1_tile_fill",
+    "persistent_overflow",
 }
 
 
@@ -125,27 +128,103 @@ def test_out_of_bbox_batch_all_overflow_is_fallback():
     np.testing.assert_allclose(np.asarray(z), np.asarray(z_ref), rtol=RTOL, atol=ATOL)
 
 
-# ------------------------------------------------ prefetch-skip Phase-1 pipeline
-def test_prefetch_and_dense_pipelines_bitwise_equal():
-    """The tile-skipping pipeline merges exactly the candidates the dense
-    walk merges (the skipped tiles are all-sentinel), so z and alpha must be
-    bitwise identical — on a sparse tile-local batch where the skip fraction
-    is large, and on a full-bbox batch."""
-    m = 20000
-    dx, dy, dz = _uniform(m, 3)
-    p = AIDWParams(k=10, area=1.0)
+# ------------------------------------------------ row-run Phase-1 pipeline
+def _voided(m, seed):
+    """Uniform points with an empty band of grid rows and an empty disk."""
+    rng = np.random.default_rng(seed)
+    x = rng.random(2 * m).astype(np.float32)
+    y = rng.random(2 * m).astype(np.float32)
+    keep = ~(((y > 0.40) & (y < 0.52)) | ((x - 0.7) ** 2 + (y - 0.25) ** 2 < 0.015))
+    x, y = x[keep][:m], y[keep][:m]
+    return x, y, np.sin(6 * x) * np.cos(6 * y)
+
+
+def _pipeline_case(name):
+    """``(data, queries, build_plan kwargs, row tile or None)`` of one case."""
     rng = np.random.default_rng(4)
-    corner = (0.05 + 0.1 * rng.random((256, 2))).astype(np.float32)
-    plans = {pipe: build_plan(dx, dy, dz, params=p, area=1.0, impl="grid", pipeline=pipe)
+    kw = {}
+    tile = None
+    if name == "tile-local":
+        data = _uniform(20000, 3)
+        q = 0.05 + 0.1 * rng.random((256, 2))
+    elif name == "full-bbox":
+        # one block spans the grid: full-width rows whose runs touch, so the
+        # boundary tile of each row pair must be listed once; the last tile
+        # runs past the data, whose lanes must not count
+        data = _uniform(4000, 6)
+        q = rng.random((256, 2))
+        kw = dict(seam_level=0, min_cand_capacity=4000)
+    elif name == "edges":
+        # one block at each grid corner and edge midpoint, its rectangle
+        # clipped there; some queries lie just outside the data
+        data = _uniform(8192, 7)
+        centres = [(u, v) for u in (0.0, 0.5, 1.0) for v in (0.0, 0.5, 1.0) if (u, v) != (0.5, 0.5)]
+        q = np.concatenate([np.asarray(c) + 0.06 * (rng.random((32, 2)) - 0.5) for c in centres])
+        kw = dict(block_q=32, seam_level=0)
+    elif name == "voids":
+        # an empty band of grid rows and an empty disk inside the rectangles
+        data = _voided(8192, 8)
+        q = rng.random((512, 2))
+        kw = dict(block_q=64)
+    elif name.startswith("tile-"):
+        # every candidate tile width: row runs start and end mid-tile
+        data = _uniform(8192, 9)
+        q = 0.3 + 0.4 * rng.random((512, 2))
+        tile = int(name.split("-")[1])
+    elif name == "chunked":
+        # a tile table too large for one launch's SMEM: one launch per block,
+        # each writing its blocks of one shared output
+        data = _uniform(6000, 11)
+        q = rng.random((640, 2))
+        kw = dict(block_q=64)
+    elif name == "seam-split":
+        data = _uniform(16384, 10)
+        q = rng.random((1024, 2))
+        kw = dict(block_q=64, seam_level=2)
+    else:  # one-overflow: a seam-straddling diagonal block beside a local one
+        data = _uniform(4096, 42)
+        t = np.linspace(0.02, 0.98, 256)
+        q = np.concatenate([0.05 + 0.03 * rng.random((256, 2)), np.stack([t, t], 1)])
+        kw = dict(query_occupancy=64.0, seam_level=0)
+    q = q.astype(np.float32)
+    return data, (jnp.asarray(q[:, 0]), jnp.asarray(q[:, 1])), kw, tile
+
+
+@pytest.mark.parametrize("case", [
+    "tile-local", "full-bbox", "edges", "voids", "tile-128", "tile-256",
+    "tile-512", "chunked", "seam-split", "one-overflow",
+])
+def test_prefetch_and_dense_pipelines_bitwise_equal(case, monkeypatch):
+    """The row-run walk merges exactly the points the materialised gather
+    puts in a block's candidate row (every other lane reads +inf, as a
+    sentinel slot does), and the k-best merge returns the k smallest in
+    ascending order whatever the order it sees them in: z and alpha must be
+    bitwise equal to the dense pipeline's, and the capacity diagnostics
+    equal."""
+    (dx, dy, dz), (qx, qy), kw, tile = _pipeline_case(case)
+    if case == "chunked":
+        monkeypatch.setattr(aidw_grid, "_SMEM_TABLE_WORDS", 1)
+    p = AIDWParams(k=10, area=1.0)
+    plans = {pipe: build_plan(dx, dy, dz, params=p, area=1.0, impl="grid",
+                              pipeline=pipe, **kw)
              for pipe in ("prefetch", "dense")}
-    qx, qy = jnp.asarray(corner[:, 0]), jnp.asarray(corner[:, 1])
+    if tile is not None:
+        plans["prefetch"] = dataclasses.replace(plans["prefetch"], row_tile=tile)
     z_p, a_p, stats = execute_with_stats(plans["prefetch"], qx, qy)
     z_d, a_d, stats_d = execute_with_stats(plans["dense"], qx, qy)
     np.testing.assert_array_equal(np.asarray(z_p), np.asarray(z_d))
     np.testing.assert_array_equal(np.asarray(a_p), np.asarray(a_d))
-    assert float(stats["skipped_tile_fraction"]) > 0.5, "tile-local batch should skip most tiles"
-    # the diagnostic reports what the launch *would* skip for dense too
-    assert float(stats_d["skipped_tile_fraction"]) == float(stats["skipped_tile_fraction"])
+    for key in ("skipped_tile_fraction", "cand_need_max", "overflow_queries"):
+        assert float(stats[key]) == float(stats_d[key]), key
+    np.testing.assert_array_equal(np.asarray(stats["overflow_query_mask"]),
+                                  np.asarray(stats_d["overflow_query_mask"]))
+    assert 0.0 < float(stats["phase1_tile_fill"]) <= 1.0
+    overflowed = int(stats["overflow_blocks"])
+    assert overflowed == (1 if case == "one-overflow" else 0)
+    if case == "tile-local":
+        assert float(stats["skipped_tile_fraction"]) > 0.5, "tile-local batch should skip most tiles"
+    if case == "full-bbox":
+        assert int(stats["cand_need_max"]) == dx.shape[0], "the block should span the grid"
 
 
 def test_build_plan_rejects_bad_pipeline_and_seam_level():
@@ -247,6 +326,7 @@ def test_stats_structure_static_per_plan():
     assert set(stats1) == set(stats2) == STATS_KEYS
     assert stats1["overflow_query_mask"].shape == (300,)
     assert 0.0 <= float(stats1["skipped_tile_fraction"]) <= 1.0
+    assert 0.0 < float(stats1["phase1_tile_fill"]) <= 1.0
 
 
 def test_persistent_overflow_counter_and_warning():

@@ -4,8 +4,10 @@ Every other kernel test runs in Pallas interpret mode, which accepts block
 shapes and primitives the TPU compiler refuses.  These tests compile each
 kernel with ``interpret=False`` for a described (not attached) TPU v5e chip
 at n = m = 2^20 queries/points, ``block_q=256``, ``block_d=512``, grid
-candidate capacity 4096 and k = 10: nothing runs, but a kernel Mosaic would
-refuse on the chip fails here.
+candidate capacity 4096 and k = 10 — and Phase 1 of the grid path also at
+the served benchmark cells' shapes: nothing runs, but a kernel Mosaic would
+refuse on the chip (a tile it cannot lay out, an SMEM table that does not
+fit) fails here.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process may hold the TPU compiler library, so describing it while
@@ -20,12 +22,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.aidw import AIDWParams
+from repro.engine.plan import _ROW_TILES
 from repro.kernels.aidw_grid import (
     phase1_alpha_from_candidates,
+    phase1_alpha_row_runs,
     phase2_far_aggregates,
     phase2_far_nodes,
     phase2_near_weights,
     phase2_weights_full,
+    row_run_max_tiles,
 )
 from repro.kernels.aidw_naive import aidw_naive_soa
 from repro.kernels.aidw_tiled import aidw_tiled_aoas, aidw_tiled_soa
@@ -100,17 +105,42 @@ def test_naive_soa_at_10k(compile_for_chip):
                      row, row, row, col, col)
 
 
-@pytest.mark.parametrize("pipeline", ["prefetch", "dense"])
-def test_grid_phase1(compile_for_chip, pipeline):
-    def fn(qx, qy, cx, cy, nt):
-        return phase1_alpha_from_candidates(
-            qx, qy, cx, cy, params=PARAMS, area=1.0, m_real=M,
-            block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False,
-            num_tiles=nt if pipeline == "prefetch" else None,
-        )
+# Phase-1 launches: (queries incl. seam padding, blocks, capacity, gy, m).
+# "2^20" is the paper-size launch above; the raster and scatter shapes are
+# the two served cells' (a 91x91 grid at seam level 2; the healed capacity
+# on a 253x253 grid at seam level 3), where the row-run walk's SMEM tile
+# table is largest.
+PHASE1_SHAPES = {
+    "2^20": (N, NB, CAPACITY, 256, M),
+    "raster": (80 * BLOCK_Q, 80, 114_688, 91, 2_097_152),
+    "scatter": (320 * BLOCK_Q, 320, 21_504, 253, 1_024_000),
+}
+PHASE1_CASES = [
+    pytest.param("prefetch", "2^20", 128, id="prefetch"),
+    pytest.param("dense", "2^20", 0, id="dense"),
+    *(pytest.param("prefetch", cell, tile, id=f"prefetch-{cell}-{tile}")
+      for cell in ("raster", "scatter") for tile in _ROW_TILES),
+    *(pytest.param("dense", cell, 0, id=f"dense-{cell}") for cell in ("raster", "scatter")),
+]
 
-    q, cand = ((N,), F32), ((NB, CAPACITY), F32)
-    compile_for_chip(fn, q, q, cand, cand, ((NB,), jnp.int32))
+
+@pytest.mark.parametrize("pipeline,shape,tile", PHASE1_CASES)
+def test_grid_phase1(compile_for_chip, pipeline, shape, tile):
+    n, nb, capacity, gy, m = PHASE1_SHAPES[shape]
+    q = ((n,), F32)
+    kw = dict(params=PARAMS, area=1.0, m_real=m, block_q=BLOCK_Q, interpret=False)
+    if pipeline == "dense":
+        fn = functools.partial(phase1_alpha_from_candidates, block_d=BLOCK_D, **kw)
+        compile_for_chip(fn, q, q, ((nb, capacity), F32), ((nb, capacity), F32))
+        return
+
+    def fn(qx, qy, tiles, nt, rects, px, py, pc):
+        return phase1_alpha_row_runs(qx, qy, tiles, nt, rects, (px, py, pc), tile=tile, **kw)
+
+    max_tiles = row_run_max_tiles(capacity, tile, gy)
+    compile_for_chip(fn, q, q, ((nb, max_tiles), jnp.int32), ((nb,), jnp.int32),
+                     ((nb, 4), jnp.int32), ((m + 1,), F32), ((m + 1,), F32),
+                     ((m,), jnp.int32))
 
 
 def test_near_weight_kernel(compile_for_chip):
