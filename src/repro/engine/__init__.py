@@ -14,7 +14,7 @@ plan is built once and reused across query batches with zero retraces:
 """
 
 from repro.engine.plan import InterpolationPlan, build_plan, replan_with_capacity
-from repro.engine.execute import execute, execute_with_stats
+from repro.engine.execute import exact_arm_mask, execute, execute_with_stats
 
-__all__ = ["InterpolationPlan", "build_plan", "execute", "execute_with_stats",
-           "replan_with_capacity"]
+__all__ = ["InterpolationPlan", "build_plan", "exact_arm_mask", "execute",
+           "execute_with_stats", "replan_with_capacity"]

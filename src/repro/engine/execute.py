@@ -42,7 +42,7 @@ from repro.core.grid import (
     seam_layout,
     seam_segment_ids,
 )
-from repro.core.layouts import pad_tail, pad_to
+from repro.core.layouts import coord_sentinel, pad_tail, pad_to
 from repro.engine.plan import InterpolationPlan
 from repro.errors import CapacityOverflowWarning
 from repro.kernels.aidw_fused import aidw_fused_soa
@@ -53,6 +53,7 @@ from repro.kernels.aidw_grid import (
     phase1_alpha_row_runs,
     phase2_far_aggregates,
     phase2_far_nodes,
+    phase2_near_row_runs,
     phase2_near_weights,
     phase2_weights_full,
     rectangle_need,
@@ -148,11 +149,11 @@ def _phase2_farfield(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
 
 
 def _quadtree_walk(plan: InterpolationPlan, hxlo, hxhi, hylo, hyhi):
-    """Barnes–Hut walk over the plan's quadtree, one table per level.
+    """Barnes–Hut walk over the plan's quadtree, one mask per level.
 
     Per query block (home rectangle ``hxlo..hxhi x hylo..hyhi``, inclusive
     cell coords) and per level, every node gets the OPENING criterion: a
-    node is CLOSED — emitted as one aggregate+dipole term — iff its
+    node is CLOSED — swept as one aggregate+dipole term — iff its
     Chebyshev cell gap from the home rectangle clears ``radius + 1`` (its
     cells are all outside the near rectangle, and the ring invariant gives
     every point distance ``>= (gap-1) * cell_min``) and its stored
@@ -167,12 +168,9 @@ def _quadtree_walk(plan: InterpolationPlan, hxlo, hxhi, hylo, hyhi):
     by EXACTLY one closed node, every near cell by none.
 
     The walk is plain masked arithmetic over all ``(block, node)`` pairs —
-    cheap bools, no weights — while the expensive weight evaluation runs
-    only over the ~O(log m) closed nodes each block compacts into its
-    static ``(nb, k_pad)`` id tables (pad slots point at the sentinel
-    node).  Returns per level ``(table, n_closed, n_opened, n_processed)``;
-    ``n_closed > k_pad`` means the table overflowed and the caller must
-    route the block to the exact sweep.
+    cheap bools, no weights.  Returns per level ``(closed (nb, n_nodes),
+    n_closed, n_opened, n_processed)``; the far sweep weighs each block's
+    closed nodes of the level and masks the rest to weight 0.
     """
     grid = plan.grid
     dtype = grid.pt_x.dtype
@@ -185,7 +183,7 @@ def _quadtree_walk(plan: InterpolationPlan, hxlo, hxhi, hylo, hyhi):
     opened_up = None
     parent_nx = 0
     for lv in range(n_lv - 1, -1, -1):
-        nx, ny, step, k_pad, _tile = plan.qt_levels[lv]
+        nx, ny, step, _n_pad, _tile = plan.qt_levels[lv]
         n_nodes = nx * ny
         jx = jnp.arange(nx, dtype=jnp.int32)
         jy = jnp.arange(ny, dtype=jnp.int32)
@@ -219,35 +217,31 @@ def _quadtree_walk(plan: InterpolationPlan, hxlo, hxhi, hylo, hyhi):
             n_opened = jnp.sum(opened.astype(jnp.int32), axis=1)
         n_proc = jnp.sum((proc & nonempty).astype(jnp.int32), axis=1)
         n_closed = jnp.sum(closed.astype(jnp.int32), axis=1)
-        # compact the closed ids into the static-width table: cumsum
-        # positions, one dump slot past k_pad for everything else
-        pos = jnp.cumsum(closed.astype(jnp.int32), axis=1) - 1
-        col = jnp.where(closed, jnp.minimum(pos, k_pad), k_pad)
-        ids = jnp.broadcast_to(jnp.arange(n_nodes, dtype=jnp.int32)[None, :],
-                               (nb, n_nodes))
-        tbl = jnp.full((nb, k_pad + 1), n_nodes, jnp.int32)
-        tbl = tbl.at[jnp.arange(nb, dtype=jnp.int32)[:, None], col].set(
-            jnp.where(closed, ids, n_nodes), mode="drop"
-        )
-        out[lv] = (tbl[:, :k_pad], n_closed, n_opened, n_proc)
+        out[lv] = (closed, n_closed, n_opened, n_proc)
     return out
 
 
 def _phase2_quadtree(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
-                     cx_v=None, cy_v=None):
+                     cx_v=None, cy_v=None, real_b=None):
     """Quadtree far-field Phase 2 over a blocked query view (DESIGN.md §8).
 
-    The near field is the single-level arm's, verbatim: exact per-point
-    weights over the home rectangle expanded by ``plan.farfield_radius``
-    (CSR gather at ``p2_capacity``, tile-table skip).  The far field runs
+    The near field takes exact per-point weights over the home rectangle
+    expanded by ``plan.farfield_radius``.  On the default ``pipeline=
+    "prefetch"`` it reads the rectangle's CSR row runs in place
+    (:func:`row_run_tiles` sized from ``p2_capacity``, walked by
+    :func:`phase2_near_row_runs` in ``p2_block_d``-point tiles); the
+    ``"dense"`` pipeline keeps the gathered near field (CSR gather at
+    ``p2_capacity``, tile-table skip), its oracle.  The far field runs
     :func:`_quadtree_walk` and then one :func:`phase2_far_nodes` sweep per
-    level over the gathered node tables, accumulating into the same
-    ``(sum_w, sum_wz)`` the near sweep produced.  Returns ``(z, need,
-    overflow, rect_cells, closed_counts, opened_tot, proc_tot)`` —
-    ``overflow (nb,)`` flags blocks whose near gather OR any level table
-    was truncated (their queries must take the exact sweep; the bound
-    assumes completeness), ``closed_counts`` the per-level ``(nb,)`` closed
-    node counts for the stats dict.
+    level over the level's nodes, each block's non-closed ones masked to
+    the sentinel node, accumulating into the same
+    ``(sum_w, sum_wz)`` the near sweep produced.  ``real_b (nb,)``, where
+    given, flags the blocks holding a real query: the others (seam pad
+    blocks) walk nothing.  Returns ``(z, need, overflow, rect_cells,
+    closed_counts, opened_tot, proc_tot)`` — ``overflow (nb,)`` flags
+    blocks whose near field was truncated (their queries must take the
+    exact sweep; the bound assumes completeness), ``closed_counts`` the
+    per-level ``(nb,)`` closed node counts for the stats dict.
     """
     grid = plan.grid
     if cx_v is None or cy_v is None:
@@ -257,48 +251,81 @@ def _phase2_quadtree(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
                                               plan.block_q)
     r_near = jnp.full(cx_v.shape, plan.farfield_radius, jnp.int32)
     xlo, xhi, ylo, yhi = block_rectangles(grid, cx_v, cy_v, r_near, plan.block_q)
-    cand_x, cand_y, cand_z, need = gather_candidates_csr(
-        grid, xlo, xhi, ylo, yhi, plan.p2_capacity, with_z=True
-    )
-    num_tiles = _tile_table(need, plan.p2_capacity, plan.p2_block_d,
-                            plan.pipeline)
     ah = alpha_v * 0.5
-    sw, swz, md_n, hz_n = phase2_near_weights(
-        qx_v, qy_v, ah, cand_x, cand_y, cand_z, num_tiles,
-        block_q=plan.block_q, block_d=plan.p2_block_d, interpret=plan.interpret,
-    )
-    overflow = need > plan.p2_capacity
+    live = jnp.ones(xlo.shape, bool) if real_b is None else real_b
+    with jax.named_scope("aidw.phase2.near"):
+        need = rectangle_need(grid, xlo, xhi, ylo, yhi)
+        overflow = need > plan.p2_capacity
+        if plan.pipeline == "prefetch":
+            tiles, run_tiles = row_run_tiles(
+                grid, xlo, xhi, ylo, yhi, plan.p2_block_d,
+                row_run_max_tiles(plan.p2_capacity, plan.p2_block_d, grid.gy))
+            run_tiles = jnp.where(overflow | ~live, 0, run_tiles)
+            sw, swz, md_n, hz_n = phase2_near_row_runs(
+                qx_v, qy_v, ah, tiles, run_tiles,
+                jnp.stack([xlo, xhi, ylo, yhi], axis=1),
+                (grid.pt_x, grid.pt_y, grid.pt_z, plan.row_cells),
+                tile=plan.p2_block_d, m_real=plan.m, block_q=plan.block_q,
+                interpret=plan.interpret,
+            )
+        else:
+            cand_x, cand_y, cand_z, _ = gather_candidates_csr(
+                grid, xlo, xhi, ylo, yhi, plan.p2_capacity, with_z=True
+            )
+            num_tiles = _tile_table(need, plan.p2_capacity, plan.p2_block_d,
+                                    plan.pipeline)
+            sw, swz, md_n, hz_n = phase2_near_weights(
+                qx_v, qy_v, ah, cand_x, cand_y, cand_z, num_tiles,
+                block_q=plan.block_q, block_d=plan.p2_block_d,
+                interpret=plan.interpret,
+            )
     closed_counts = []
     opened_tot = jnp.zeros(need.shape, jnp.int32)
     proc_tot = jnp.zeros(need.shape, jnp.int32)
     with jax.named_scope("aidw.phase2.quadtree_walk"):
-        tables = _quadtree_walk(plan, hxlo, hxhi, hylo, hyhi)
-    for lv, (tbl, n_closed, n_opened, n_proc) in enumerate(tables):
-        _nx, _ny, _step, k_pad, tile = plan.qt_levels[lv]
-        fx, fy, fcnt, fzs, fmx, fmy, _fe = plan.far[lv]
-        covered = jnp.minimum(n_closed, k_pad)
-        nt = (covered + tile - 1) // tile
-        sw_f, swz_f = phase2_far_nodes(
-            qx_v, qy_v, ah, fx[tbl], fy[tbl], fcnt[tbl], fzs[tbl],
-            fmx[tbl], fmy[tbl], nt,
-            block_q=plan.block_q, block_d=tile, interpret=plan.interpret,
-        )
-        sw = sw + sw_f
-        swz = swz + swz_f
-        overflow = overflow | (n_closed > k_pad)
-        closed_counts.append(n_closed)
-        opened_tot = opened_tot + n_opened
-        proc_tot = proc_tot + n_proc
+        levels = _quadtree_walk(plan, hxlo, hxhi, hylo, hyhi)
+    big = coord_sentinel(qx_v.dtype)
+    zero = jnp.zeros((), qx_v.dtype)
+    with jax.named_scope("aidw.phase2.far_nodes"):
+        for lv, (closed, n_closed, n_opened, n_proc) in enumerate(levels):
+            _nx, _ny, _step, n_pad, tile = plan.qt_levels[lv]
+            keep = jnp.pad(closed & live[:, None], ((0, 0), (0, n_pad - closed.shape[1])))
+            # every block sweeps the whole level: a node it does not close
+            # is the sentinel node (d2 -> inf, w -> 0, zero count, z-sum
+            # and moment), so it adds exactly 0 to both sums
+            fx, fy, fcnt, fzs, fmx, fmy = (
+                jnp.where(keep, v[None, :], fill)
+                for v, fill in zip(plan.far[lv][:6], (big, big, zero, zero, zero, zero)))
+            nt = jnp.where(live & (n_closed > 0), n_pad // tile, 0)
+            sw_f, swz_f = phase2_far_nodes(
+                qx_v, qy_v, ah, fx, fy, fcnt, fzs, fmx, fmy, nt,
+                block_q=plan.block_q, block_d=tile, interpret=plan.interpret,
+            )
+            sw = sw + sw_f
+            swz = swz + swz_f
+            closed_counts.append(n_closed)
+            opened_tot = opened_tot + n_opened
+            proc_tot = proc_tot + n_proc
     z = jnp.where(md_n <= plan.params.exact_hit_eps, hz_n, swz / sw)
     rect_cells = (xhi - xlo + 1) * (yhi - ylo + 1)
     return z, need, overflow, rect_cells, closed_counts, opened_tot, proc_tot
+
+
+def _real_blocks(plan: InterpolationPlan, nb: int, dest):
+    """``(nb,)`` bool: the blocks of the seam-split view holding at least
+    one real query (``dest`` maps the sorted queries to their slots; no
+    split means every block is real)."""
+    if dest is None:
+        return jnp.ones((nb,), bool)
+    real_slot = jnp.zeros((nb * plan.block_q,), bool).at[dest].set(True)
+    return jnp.any(real_slot.reshape(nb, plan.block_q), axis=1)
 
 
 def _phase2_exact_masked(plan: InterpolationPlan, qx_s, qy_s, alpha, over_q):
     """Per-block masked exact Phase 2 — the overflow arm of the blend.
 
     ``over_q (n_tot,)`` flags queries (sorted layout) whose approximated
-    Phase 2 is unusable (near gather or level table truncated).  Instead of
+    Phase 2 is unusable (near field truncated).  Instead of
     the old whole-batch ``lax.cond`` full sweep, each ``block_q`` run with
     at least one flagged query gets its OWN full-data sweep — a
     ``fori_loop`` whose per-block ``cond`` skips clean blocks, so one
@@ -430,7 +457,8 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
             alpha_v = alpha[src] if src is not None else alpha
             if plan.phase2 == "quadtree":
                 (z_v, need2, over2_b, rect_cells, closed_counts, opened_tot,
-                 proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v)
+                 proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v,
+                                              _real_blocks(plan, need.shape[0], dest))
                 qt_diag = (closed_counts, opened_tot, proc_tot)
             else:
                 with jax.named_scope("aidw.phase2.farfield"):
@@ -460,11 +488,7 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
         # pad blocks (all-duplicate, ~1 tile) would otherwise inflate the skip
         # fraction and the overflow-block count
         nb = need.shape[0]
-        if dest is not None:
-            real_slot = jnp.zeros((nb * plan.block_q,), bool).at[dest].set(True)
-            real_b = jnp.any(real_slot.reshape(nb, plan.block_q), axis=1)
-        else:
-            real_b = jnp.ones((nb,), bool)
+        real_b = _real_blocks(plan, nb, dest)
         n_real_tiles = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)) * n_tiles_static, 1)
         stats = {
             # every real query took the ring path — the batch got no kernel help
@@ -510,6 +534,8 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
                     jnp.where(real_b, need2, 0)).astype(jnp.float32) / n_real_b,
                 "far_cells_mean": far_mean,
                 "p2_overflow_queries": jnp.sum(over2_s[:n].astype(jnp.int32)),
+                "p2_overflow_query_mask": over2_s[:n][inv],
+                "p2_need_max": jnp.max(jnp.where(real_b, need2, 0)),
             })
     with jax.named_scope("aidw.sort"):
         return zhat[:n, 0][inv], alpha[:n, 0][inv], stats
@@ -702,8 +728,12 @@ def execute_with_stats(plan: InterpolationPlan, qx, qy):
     fires when the streak is first reached).  ``grid`` with
     ``phase2="farfield"`` additionally reports ``near_points_mean`` /
     ``far_cells_mean`` (per real query block), the plan's proved
-    ``farfield_rtol_bound``, and ``p2_overflow_queries`` (queries routed to
-    the exact Phase-2 sweep because their block's near gather overflowed).
+    ``farfield_rtol_bound``, ``p2_overflow_queries`` (queries routed to
+    the exact Phase-2 sweep because their block's near field overflowed),
+    ``p2_overflow_query_mask`` (bool ``(n,)``, caller order — which
+    queries the masked exact sweep answered) and ``p2_need_max`` (the
+    largest near-field point count of a real block — what a re-plan sizes
+    ``p2_capacity`` from).
     ``grid`` with ``phase2="quadtree"`` reports the same near/overflow keys
     plus ``far_cells_mean`` (mean CLOSED nodes per real block, summed over
     levels — the ~O(log m) quantity), ``cells_per_level`` (its per-level
@@ -733,3 +763,14 @@ def execute_with_stats(plan: InterpolationPlan, qx, qy):
 
 # the no-retrace contract is asserted against the underlying jit cache
 execute_with_stats._cache_size = _execute_with_stats_jit._cache_size
+
+
+def exact_arm_mask(stats):
+    """``(n,)`` bool, caller order: the queries of one ``execute_with_stats``
+    call that an exact fallback arm answered — the ring search (Phase-1
+    overflow) or, on far-field and quadtree plans, the masked exact
+    Phase-2 sweep."""
+    mask = stats["overflow_query_mask"]
+    if "p2_overflow_query_mask" in stats:
+        mask = mask | stats["p2_overflow_query_mask"]
+    return mask

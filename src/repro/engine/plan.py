@@ -132,10 +132,11 @@ class InterpolationPlan:
     farfield_radius: int      # far field/quadtree: near-field radius (cells)
     farfield_bound: float     # far field/quadtree: proved worst-case rel error
     p2_capacity: int          # farfield: static near-field candidate width
-    p2_block_d: int           # farfield: near-field sweep tile
+    p2_block_d: int           # farfield/quadtree: near-field sweep tile (the
+                              # quadtree's row-run tile on "prefetch")
     p2_far_block_d: int       # farfield: far cell-aggregate sweep tile
     qt_tau: float             # quadtree: effective opening ratio tau_eff
-    qt_levels: tuple          # quadtree: per-level (nx, ny, step, k_pad, tile)
+    qt_levels: tuple          # quadtree: per-level (nx, ny, step, n_pad, tile)
     row_tile: int             # grid Phase 1: CSR tile of the row-run walk
     # --- children ---
     data: tuple               # impl-specific padded arrays
@@ -453,39 +454,17 @@ def _choose_quadtree_radius(grid: UniformGrid, params: AIDWParams,
     return radius, tau_eff, bound
 
 
-def _quadtree_level_statics(qt, radius: int, tau_eff: float, cell_min: float,
-                            side: int, tile_cap: int):
-    """Static per-level ``(nx, ny, step, k_pad, tile)`` table.
-
-    ``k_pad`` bounds how many CLOSED nodes one query block may emit at the
-    level; the heuristic inverts the opening criterion with the level
-    maxima: a level-``l`` node is closed only where its PARENT opened, and
-    a parent at cell gap ``>= Gcap = max(radius+1, e_parent_max /
-    (tau_eff*cell_min) + 1)`` never opens — so closed nodes live inside a
-    bounded annulus of the block.  The top level has no parent (every node
-    is a candidate).  Undersizing is safe: the engine detects per-block
-    table overflow at execute time and routes those queries to the exact
-    sweep, exactly like the near-capacity overflow blend.
-    """
-    n_lv = len(qt)
+def _quadtree_level_statics(qt, tile_cap: int):
+    """Static per-level ``(nx, ny, step, n_pad, tile)`` table: the level's
+    node grid, its cells per node side, and the sweep tile and the node
+    count padded to it.  Every block sweeps the whole level, its
+    non-closed nodes masked to weight 0 (DESIGN.md §8), so a level's
+    sweep width is its node count."""
     out = []
-    for lv, level in enumerate(qt):
+    for level in qt:
         n_nodes = level.nx * level.ny
-        if lv == n_lv - 1:
-            k_est = n_nodes
-        else:
-            parent = qt[lv + 1]
-            if tau_eff > 0 and cell_min > 0 and math.isfinite(tau_eff):
-                gcap = max(radius + 1,
-                           int(math.ceil(parent.e_max / (tau_eff * cell_min))) + 1)
-            else:
-                gcap = radius + 1
-            span = (side + 2 * gcap) // parent.step + 2
-            k_est = min(4 * span * span, n_nodes)
-        k_est = max(k_est, 8)
-        tile = min(tile_cap, max(128, _round_up(k_est, 128)))
-        k_pad = _round_up(k_est, tile)
-        out.append((level.nx, level.ny, level.step, k_pad, tile))
+        tile = min(tile_cap, max(128, _round_up(n_nodes, 128)))
+        out.append((level.nx, level.ny, level.step, _round_up(n_nodes, tile), tile))
     return tuple(out)
 
 
@@ -535,7 +514,12 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
             grid, r_need, block_q, m, query_occupancy
         )
         pathological = grid.n_cells > 1 and r_static > _MAX_SAFE_RADIUS
-        if not pathological:
+        # A quadtree plan keeps the occupancy grid: its near radius is a
+        # number of cells set by rtol and the cells' dispersion ratio
+        # (DESIGN.md §8), so coarser cells only widen its near field (and
+        # on a grid narrower than that radius nothing is provable), while
+        # Phase 1 pays for sparse regions (voids) only in their own blocks
+        if not pathological or phase2 == "quadtree":
             break
         if user_grid or rebuilds >= _MAX_REBUILDS:
             warnings.warn(
@@ -596,34 +580,36 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
             radius, tau_eff, bound = _choose_quadtree_radius(
                 grid, params, farfield_rtol, qt[0].e_max, side=side, m=m
             )
-        # near-field machinery is shared with the single-level arm: same
-        # densest-window capacity model, same tile autotune
+        # near-field capacity: the single-level arm's densest-window model.
+        # The row-run near field walks CSR tiles of the row-run rule's
+        # width for its own (near-rectangle-wide) runs; the dense pipeline
+        # keeps the single-level arm's gathered-tile autotune
         window2 = min(side + 2 * radius + 1, max(grid.gx, grid.gy))
         cap2 = min(_densest_window_count(grid, window2), m)
         if min_p2_capacity is not None:
             cap2 = min(max(cap2, int(min_p2_capacity)), m)
         tile_cap = max(512, _round_up(_P2_TILE_ELEMS // block_q, 128))
-        p2_block_d = min(tile_cap, max(128, _round_up(cap2, 128)))
+        if pipeline == "prefetch":
+            p2_block_d = _choose_row_tile(grid, m, window2)
+        else:
+            p2_block_d = min(tile_cap, max(128, _round_up(cap2, 128)))
         p2_capacity = _round_up(cap2, p2_block_d)
-        qt_levels = _quadtree_level_statics(qt, radius, tau_eff, cell_min,
-                                            side, tile_cap)
-        # per level: node aggregates + ONE appended sentinel node (index
-        # nx*ny) that pad slots of the gathered per-block tables point to —
-        # sentinel centroid (d2 -> inf, w -> 0) and zero count/z-sum/moment,
-        # so pad slots contribute exactly 0 to both accumulators
-        zero1 = jnp.zeros((1,), dtype)
-        big1 = jnp.full((1,), big, dtype)
+        qt_levels = _quadtree_level_statics(qt, tile_cap)
+        # per level: node aggregates padded to the sweep tile with sentinel
+        # nodes — sentinel centroid (d2 -> inf, w -> 0) and zero
+        # count/z-sum/moment, so they contribute exactly 0 to both sums
+        zero = jnp.zeros((), dtype)
         far = tuple(
             (
-                jnp.concatenate([level.cent_x.astype(dtype), big1]),
-                jnp.concatenate([level.cent_y.astype(dtype), big1]),
-                jnp.concatenate([level.count.astype(dtype), zero1]),
-                jnp.concatenate([level.z_sum.astype(dtype), zero1]),
-                jnp.concatenate([level.mx.astype(dtype), zero1]),
-                jnp.concatenate([level.my.astype(dtype), zero1]),
-                jnp.concatenate([level.e.astype(dtype), zero1]),
+                pad_to(level.cent_x.astype(dtype), tile, big),
+                pad_to(level.cent_y.astype(dtype), tile, big),
+                pad_to(level.count.astype(dtype), tile, zero),
+                pad_to(level.z_sum.astype(dtype), tile, zero),
+                pad_to(level.mx.astype(dtype), tile, zero),
+                pad_to(level.my.astype(dtype), tile, zero),
+                pad_to(level.e.astype(dtype), tile, zero),
             )
-            for level in qt
+            for level, (_nx, _ny, _step, _n_pad, tile) in zip(qt, qt_levels)
         )
         ff = dict(farfield_radius=radius, farfield_bound=float(bound),
                   p2_capacity=p2_capacity, p2_block_d=p2_block_d,

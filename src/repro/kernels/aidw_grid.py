@@ -49,10 +49,17 @@ of ``(snapshot arrays, queries, static capacity)`` and runs under ``jax.jit``:
   ``z_sum*w(centroid)`` into ``(sum_w, sum_wz)``.  The engine combines the
   two and applies the exact-hit guard; the worst-case relative error is
   bounded at plan time (``engine.plan._choose_farfield_radius``).
+* :func:`phase2_near_row_runs` — the quadtree arm's near field on the
+  default ``pipeline="prefetch"`` (DESIGN.md §8): the same accumulators as
+  :func:`phase2_near_weights`, read from the CSR row runs of each block's
+  near rectangle in place, by the Phase-1 row-run walk (the near
+  rectangle is far too wide to gather).
 * :func:`phase2_far_nodes` — the multi-level quadtree far field
-  (``build_plan(phase2="quadtree")``, DESIGN.md §8): the same near kernel,
-  but the far sweep runs once per quadtree LEVEL over per-block tables of
-  closed nodes (gathered by the engine's Barnes–Hut walk), each node
+  (``build_plan(phase2="quadtree")``, DESIGN.md §8): the near field above
+  (or, on ``pipeline="dense"``, the gathered near kernel), while the far
+  sweep runs once per quadtree LEVEL over the level's nodes, those a
+  block does not close (by the engine's Barnes–Hut walk) masked to the
+  sentinel node, each closed node
   contributing its aggregate term plus a dipole z-moment correction — the
   piece that cancels the z budget's first-order error and makes the plan's
   bound second-order in the opening ratio.
@@ -298,6 +305,59 @@ _SMEM_TABLE_WORDS = 1 << 17
 _ROW_BLOCK = 1024
 
 
+def _row_run_step(tiles_ref, rect_ref, pc_ref, i, j, *, tile, max_tiles, m_real):
+    """Step ``j`` of block ``i``'s row-run walk: ``(lanes, inside)``.
+
+    The point refs hold the ``_ROW_BLOCK``-point block the step's tile
+    ``tiles_ref[i * max_tiles + j]`` lies in; ``lanes(ref)`` rotates the
+    tile's lanes to the front and returns them ``(1, tile)``.  ``inside``
+    marks the lanes that hold a point (index below ``m_real``: the blocks
+    run past the arrays' ends) whose cell (packed ``cy << 16 | cx``, from
+    ``pc_ref``) lies in the block's rectangle ``rect_ref[4i : 4i+4] =
+    (xlo, xhi, ylo, yhi)``.
+    """
+    t = tiles_ref[i * max_tiles + j]
+    shift = (_ROW_BLOCK - t % (_ROW_BLOCK // tile) * tile) % _ROW_BLOCK
+
+    def lanes(ref):
+        block = ref[...].reshape(1, _ROW_BLOCK)
+        return pltpu.roll(block, shift, 1)[:, :tile]
+
+    cell = lanes(pc_ref)
+    cx = jnp.bitwise_and(cell, 0xFFFF)
+    cy = jnp.right_shift(cell, 16)
+    index = t * tile + jax.lax.broadcasted_iota(jnp.int32, cell.shape, 1)
+    inside = ((index < m_real)
+              & (cx >= rect_ref[4 * i]) & (cx <= rect_ref[4 * i + 1])
+              & (cy >= rect_ref[4 * i + 2]) & (cy <= rect_ref[4 * i + 3]))
+    return lanes, inside
+
+
+def _row_run_chunks(tiles, n_tiles, tile: int):
+    """The launches of a row-run walk, ``(b0, b1, steps, q_map, p_map)``
+    each: a static chunk ``b0:b1`` of the blocks, its traced step count
+    (its longest block walk, so the static ``max_tiles`` sizes only the
+    SMEM table) and the index maps of its query columns and of its points,
+    read in place in ``_ROW_BLOCK``-point blocks at each step's tile (a
+    step past a block's count revisits its last tile).  SMEM holds one
+    launch's tile table, counts and rectangles, so a batch launches over
+    chunks of at most ``_SMEM_TABLE_WORDS`` words."""
+    nb, max_tiles = tiles.shape
+    n_chunks = -(-nb * (max_tiles + 5) // _SMEM_TABLE_WORDS)
+    chunk = -(-nb // n_chunks)
+    for b0 in range(0, nb, chunk):
+        b1 = min(b0 + chunk, nb)
+
+        def q_map(i, j, *_refs, b0=b0):
+            return (i + b0, 0)
+
+        def p_map(i, j, tiles_ref, nt_ref, _rect):
+            t = tiles_ref[i * max_tiles + jnp.maximum(jnp.minimum(j, nt_ref[i] - 1), 0)]
+            return (t * tile // _ROW_BLOCK,)
+
+        yield b0, b1, jnp.maximum(jnp.max(n_tiles[b0:b1]), 1), q_map, p_map
+
+
 def _knn_kernel_skip(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, px_ref, py_ref,
                      pc_ref, _alpha_in, alpha_ref, best, *, tile, max_tiles,
                      m_real, area, params):
@@ -305,16 +365,11 @@ def _knn_kernel_skip(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, px_ref, py_ref
 
     ``tiles_ref`` (flat ``(nb * max_tiles,)``) lists each block's tiles and
     ``nt_ref`` counts them: steps past the count are clamped revisits of the
-    block's last tile (no DMA) with the merge predicated off.  The point
-    refs hold the ``_ROW_BLOCK``-point block the step's tile lies in.  A lane
-    counts only if it holds a point (index below ``m_real``: the blocks run
-    past the arrays' ends) whose cell (packed ``cy << 16 | cx``) lies in the
-    block's rectangle ``rect_ref[4i : 4i+4] = (xlo, xhi, ylo, yhi)``; every
-    other lane reads ``d2 = +inf``, as a sentinel slot of a materialised
-    candidate row does.  Init/finish fire on the first/last
+    block's last tile (no DMA) with the merge predicated off.  A lane
+    counts only if :func:`_row_run_step` finds it inside the block's
+    rectangle; every other lane reads ``d2 = +inf``, as a sentinel slot of
+    a materialised candidate row does.  Init/finish fire on the first/last
     grid step, so the output block is written exactly once per query block.
-    ``_alpha_in`` is the output buffer itself (aliased, never read): a batch
-    launched in chunks writes one buffer, chunk by chunk.
     """
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -324,20 +379,8 @@ def _knn_kernel_skip(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, px_ref, py_ref
 
     @pl.when(j < nt_ref[i])
     def _merge():
-        t = tiles_ref[i * max_tiles + j]
-        shift = (_ROW_BLOCK - t % (_ROW_BLOCK // tile) * tile) % _ROW_BLOCK
-
-        def lanes(ref):
-            block = ref[...].reshape(1, _ROW_BLOCK)
-            return pltpu.roll(block, shift, 1)[:, :tile]
-
-        cell = lanes(pc_ref)
-        cx = jnp.bitwise_and(cell, 0xFFFF)
-        cy = jnp.right_shift(cell, 16)
-        index = t * tile + jax.lax.broadcasted_iota(jnp.int32, cell.shape, 1)
-        inside = ((index < m_real)
-                  & (cx >= rect_ref[4 * i]) & (cx <= rect_ref[4 * i + 1])
-                  & (cy >= rect_ref[4 * i + 2]) & (cy <= rect_ref[4 * i + 3]))
+        lanes, inside = _row_run_step(tiles_ref, rect_ref, pc_ref, i, j, tile=tile,
+                                      max_tiles=max_tiles, m_real=m_real)
         d2 = sq_dist_tile(qx_ref[...], qy_ref[...], lanes(px_ref), lanes(py_ref))
         d2 = jnp.where(inside, d2, jnp.asarray(jnp.inf, d2.dtype))
         best[...] = running_k_best(best[...], d2, axis=1)
@@ -360,31 +403,18 @@ def phase1_alpha_row_runs(
     meaningless and must be discarded); rects: (nb, 4) int32 ``(xlo, xhi,
     ylo, yhi)``; points: the grid's ``(pt_x, pt_y, point_cells)``, read in
     place in ``_ROW_BLOCK``-point blocks (lanes past ``m_real`` are masked).
-
-    The step axis of each launch is as long as its longest block walk (a
-    traced bound), so the static ``max_tiles`` sizes only the SMEM table.
-    Returns alpha, shape ``(n_tot, 1)``.
+    Launches: :func:`_row_run_chunks`; ``_alpha_in`` is the output buffer
+    itself (aliased, never read), so the chunks write one buffer.  Returns
+    alpha, shape ``(n_tot, 1)``.
     """
     dtype = qx_s.dtype
     nb, max_tiles = tiles.shape
     qx2, qy2 = qx_s[:, None], qy_s[:, None]
-    n_chunks = -(-nb * (max_tiles + 5) // _SMEM_TABLE_WORDS)
-    chunk = -(-nb // n_chunks)
     alpha = jnp.zeros((nb * block_q, 1), dtype)
-    for b0 in range(0, nb, chunk):
-        b1 = min(b0 + chunk, nb)
-        nt = n_tiles[b0:b1]
-
-        def q_map(i, j, *_refs, b0=b0):
-            return (i + b0, 0)
-
-        def p_map(i, j, tiles_ref, nt_ref, _rect):
-            t = tiles_ref[i * max_tiles + jnp.maximum(jnp.minimum(j, nt_ref[i] - 1), 0)]
-            return (t * tile // _ROW_BLOCK,)
-
+    for b0, b1, steps, q_map, p_map in _row_run_chunks(tiles, n_tiles, tile):
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b1 - b0, jnp.maximum(jnp.max(nt), 1)),
+            grid=(b1 - b0, steps),
             in_specs=[pl.BlockSpec((block_q, 1), q_map)] * 2
             + [pl.BlockSpec((_ROW_BLOCK,), p_map)] * 3 + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block_q, 1), q_map),
@@ -399,7 +429,8 @@ def phase1_alpha_row_runs(
             compiler_params=_SEMANTICS,
             interpret=interpret,
             name="_knn_kernel_skip",
-        )(tiles[b0:b1].reshape(-1), nt, rects[b0:b1].reshape(-1), qx2, qy2, *points, alpha)
+        )(tiles[b0:b1].reshape(-1), n_tiles[b0:b1], rects[b0:b1].reshape(-1), qx2, qy2,
+          *points, alpha)
     return alpha
 
 
@@ -474,6 +505,95 @@ def phase2_near_weights(
         name="_near_weight_kernel",
     )(num_tiles.astype(jnp.int32), qx2, qy2, alpha_half,
       *map(_row_tiles, (cand_x, cand_y, cand_z)))
+
+
+def _near_weight_kernel_rows(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, ah_ref,
+                             px_ref, py_ref, pz_ref, pc_ref, _sw_in, _swz_in, _md_in,
+                             _hz_in, sw_ref, swz_ref, md_ref, hz_ref,
+                             acc_w, acc_wz, min_d2, hit_z, *, tile, max_tiles, m_real):
+    """Near-field weight sweep over a block's CSR row runs, read in place.
+
+    The walk is :func:`_knn_kernel_skip`'s; a lane that
+    :func:`_row_run_step` finds outside the block's near rectangle reads
+    ``d2 = +inf`` (weight 0) and ``z = 0`` (the blocks run past the
+    arrays' ends, where z is not data).  Each step folds ``weight_tile``
+    into the four accumulators of :func:`_near_weight_kernel`.
+    """
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
+        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
+        min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
+        hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+
+    @pl.when(j < nt_ref[i])
+    def _accumulate():
+        lanes, inside = _row_run_step(tiles_ref, rect_ref, pc_ref, i, j, tile=tile,
+                                      max_tiles=max_tiles, m_real=m_real)
+        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], lanes(px_ref), lanes(py_ref))
+        d2 = jnp.where(inside, d2, jnp.asarray(jnp.inf, d2.dtype))
+        dz = jnp.where(inside, lanes(pz_ref), jnp.zeros((), d2.dtype))
+        sw, swz, tmin, thz = weight_tile(d2, dz, ah_ref[...], data_axis=1)
+        acc_w[...] += sw
+        acc_wz[...] += swz
+        better = tmin < min_d2[...]
+        hit_z[...] = jnp.where(better, thz, hit_z[...])
+        min_d2[...] = jnp.where(better, tmin, min_d2[...])
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        sw_ref[...] = acc_w[...]
+        swz_ref[...] = acc_wz[...]
+        md_ref[...] = min_d2[...]
+        hz_ref[...] = hit_z[...]
+
+
+def phase2_near_row_runs(
+    qx_s, qy_s, alpha_half, tiles, n_tiles, rects, points, *,
+    tile: int, m_real: int, block_q: int, interpret: bool,
+):
+    """Exact near-field weight sweep over each block's CSR row runs, in place.
+
+    qx_s/qy_s/alpha_half: (n_tot,) / (n_tot, 1), ``n_tot % block_q == 0``;
+    tiles/n_tiles/rects: the near rectangles' :func:`row_run_tiles` table,
+    counts (0 skips a block: its accumulators are then meaningless) and
+    ``(nb, 4)`` int32 bounds; points: the grid's ``(pt_x, pt_y, pt_z,
+    point_cells)``.  Launches: :func:`_row_run_chunks`; the ``_*_in``
+    refs of the kernel are the output buffers themselves (aliased, never
+    read), so the chunks write one set of buffers.
+
+    Returns ``(sum_w, sum_wz, min_d2, hit_z)``, each ``(n_tot, 1)``: the
+    accumulators :func:`phase2_near_weights` returns for the same point
+    set, summed in row-run order.
+    """
+    dtype = qx_s.dtype
+    nb, max_tiles = tiles.shape
+    qx2, qy2 = qx_s[:, None], qy_s[:, None]
+    outs = [jnp.zeros((nb * block_q, 1), dtype) for _ in range(4)]
+    for b0, b1, steps, q_map, p_map in _row_run_chunks(tiles, n_tiles, tile):
+        q_spec = pl.BlockSpec((block_q, 1), q_map)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b1 - b0, steps),
+            in_specs=[q_spec] * 3 + [pl.BlockSpec((_ROW_BLOCK,), p_map)] * 4
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
+            out_specs=[q_spec] * 4,
+            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+        )
+        outs = pl.pallas_call(
+            functools.partial(_near_weight_kernel_rows, tile=tile, max_tiles=max_tiles,
+                              m_real=m_real),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(o.shape, dtype) for o in outs],
+            input_output_aliases={10: 0, 11: 1, 12: 2, 13: 3},
+            compiler_params=_SEMANTICS,
+            interpret=interpret,
+            name="_near_weight_kernel_rows",
+        )(tiles[b0:b1].reshape(-1), n_tiles[b0:b1], rects[b0:b1].reshape(-1), qx2, qy2,
+          alpha_half, *points, *outs)
+    return tuple(outs)
 
 
 def _far_cell_kernel(rect_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
@@ -552,7 +672,7 @@ def _far_node_kernel(nt_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
                      fcnt_ref, fzs_ref, fmx_ref, fmy_ref,
                      sw_ref, swz_ref, acc_w, acc_wz):
     """Quadtree far-field level sweep: one aggregate + DIPOLE term per
-    closed node of the block's gathered level table (DESIGN.md §8).
+    closed node of the block's row of the level (DESIGN.md §8).
 
     The monopole terms are the far-cell kernel's (``count * w`` / ``z_sum *
     w`` at the centroid distance); the dipole adds ``grad w(cent) . M`` with
@@ -560,10 +680,10 @@ def _far_node_kernel(nt_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
     for ``w(p) = |q - p|^-a``, ``grad_p w = a |q - p|^(-a-2) (q - p)``, so
     the term is ``a * w / d2 * ((qx-cx) mx + (qy-cy) my)`` — it cancels the
     z budget's first-order error, which is what makes the plan's quadtree
-    bound second-order.  Pad slots of the table point at the plan's
-    sentinel node: centroid at the coordinate sentinel (``d2`` overflows to
-    +inf, ``w = 0``, ``w / d2 = 0``) and zero count/z-sum/moment, so they
-    add exactly 0 to both accumulators.  Steps past ``nt_ref[i]`` are
+    bound second-order.  A node the block does not close, and a pad slot,
+    is the sentinel node: centroid at the coordinate sentinel (``d2``
+    overflows to +inf, ``w = 0``, ``w / d2 = 0``) and zero count/z-sum/
+    moment, so it adds exactly 0 to both accumulators.  Steps past ``nt_ref[i]`` are
     clamped revisits with the accumulation predicated off, same tile-table
     discipline as the near kernel.
     """
@@ -600,10 +720,10 @@ def phase2_far_nodes(
     """One quadtree level's far sweep over per-block gathered node tables.
 
     qx_s/qy_s/alpha_half: (n_tot,) / (n_tot, 1), ``n_tot % block_q == 0``;
-    node_*: (nb, k_pad) closed-node aggregates gathered by the engine's
-    level walk (pad slots = the sentinel node), ``k_pad % block_d == 0``;
-    num_tiles: (nb,) int32 ``ceil(closed_count / block_d)`` — a block with
-    few closed nodes at this level walks only its real tiles.
+    node_*: (nb, k_pad) per-block node aggregates — the engine passes the
+    level's nodes with those a block does not close set to the sentinel
+    node — ``k_pad % block_d == 0``; num_tiles: (nb,) int32 tiles each
+    block walks (0 skips it).
 
     Returns ``(sum_w_far, sum_wz_far)``, each ``(n_tot, 1)`` — the engine
     accumulates them across levels before the near/far combine.

@@ -9,14 +9,19 @@ this module ships the response:
 
 ``healthy``
     Every batch is served through the registry's current plan; the observed
-    ``cand_need_max`` high-water mark is tracked.
+    ``cand_need_max`` high-water mark is tracked, and on far-field and
+    quadtree plans the near field's ``p2_need_max`` too.
 ``replanning``
-    The streak reached ``PERSISTENT_OVERFLOW_BATCHES``: a background thread
+    The streak — consecutive batches in which Phase 1 overflowed (ring
+    search) or, on far-field and quadtree plans, Phase 2 did (masked exact
+    sweep) — reached ``PERSISTENT_OVERFLOW_BATCHES``: a background thread
     rebuilds the plan via ``engine.plan.replan_with_capacity`` with a
-    geometrically bumped capacity floor — at least ``growth ×`` the current
-    capacity AND at least the observed ``cand_need_max``, hard-capped at
-    ``min(m, capacity_cap)`` (capacity ``m`` provably cannot overflow:
-    a candidate row never needs more than every data point).  Build
+    geometrically bumped floor on the capacity of each phase that
+    overflowed — at least ``growth ×`` the current capacity AND at least
+    the phase's observed need, hard-capped at ``min(m, capacity_cap)``
+    (capacity ``m`` provably cannot overflow: a candidate row never needs
+    more than every data point).  The other phase's capacity, ``phase2``,
+    the radius and ``farfield_rtol`` carry over.  Build
     failures retry with exponential backoff, at most ``max_retries``
     attempts.  Serving continues on the OLD plan throughout — exact via
     the blend — and the new plan is published by the registry's atomic
@@ -96,7 +101,10 @@ class CapacityReestimator:
         self.last_error: PlanBuildError | None = None
         self.counters = {"batches": 0, "triggers": 0, "replans": 0,
                          "build_failures": 0, "swaps": 0, "degraded": 0,
-                         "overflow_queries": 0, "replan_s": 0.0}
+                         "overflow_queries": 0, "p2_overflow_queries": 0,
+                         "replan_s": 0.0}
+        self._p2_need_max = 0
+        self._overflowed = set()  # phases that overflowed since the last swap
         if key not in registry:
             registry.register(key, plan)
 
@@ -137,12 +145,20 @@ class CapacityReestimator:
                 with telemetry.span("serving.sync"):
                     n_overflow = int(stats["overflow_queries"])
                     need = int(stats["cand_need_max"])
+                    n_p2 = int(stats.get("p2_overflow_queries", 0))
+                    need_p2 = int(stats.get("p2_need_max", 0))
                 with telemetry.span("serving.observe"):
                     with self._lock:
                         self.counters["batches"] += 1
                         self.counters["overflow_queries"] += n_overflow
+                        self.counters["p2_overflow_queries"] += n_p2
                         self._need_max = max(self._need_max, need)
-                    persistent = _note_overflow(plan, n_overflow)
+                        self._p2_need_max = max(self._p2_need_max, need_p2)
+                        if n_overflow:
+                            self._overflowed.add(1)
+                        if n_p2:
+                            self._overflowed.add(2)
+                    persistent = _note_overflow(plan, n_overflow + n_p2)
                     stats["persistent_overflow"] = persistent
                     if persistent:
                         self._maybe_replan(plan)
@@ -162,40 +178,54 @@ class CapacityReestimator:
                 return
             self._state = REPLANNING
             self.counters["triggers"] += 1
-            need = self._need_max
+            needs = (self._need_max, self._p2_need_max, frozenset(self._overflowed))
             t = threading.Thread(
-                target=self._replan, args=(plan, need),
+                target=self._replan, args=(plan, needs),
                 name="repro-capacity-replan", daemon=True,
             )
             self._thread = t
         t.start()
 
-    def _propose_capacity(self, plan, need: int) -> int:
-        cap = plan.m
+    def _propose_capacity(self, capacity: int, need: int, m: int) -> int:
+        cap = m
         if self.capacity_cap is not None:
             cap = min(cap, self.capacity_cap)
-        return min(max(int(plan.cand_capacity * self.growth), need), cap)
+        return min(max(int(capacity * self.growth), need), cap)
 
-    def _replan(self, plan, need: int):
+    def _replan(self, plan, needs):
         t0 = time.perf_counter()
         try:
             with telemetry.span("serving.replan"):
-                self._replan_and_swap(plan, need)
+                self._replan_and_swap(plan, *needs)
         finally:
             with self._lock:
                 self.counters["replan_s"] += time.perf_counter() - t0
 
-    def _replan_and_swap(self, plan, need: int):
+    def _replan_and_swap(self, plan, need: int, need_p2: int, overflowed):
         from repro.engine.plan import replan_with_capacity
 
         try:
-            target = int(faults.fire("reestimator.capacity",
-                                     self._propose_capacity(plan, need)))
-            if target <= plan.cand_capacity:
+            # a streak of Phase-2 overflow alone keeps Phase 1's capacity
+            target = plan.cand_capacity
+            if 1 in overflowed or not overflowed:
+                target = int(faults.fire(
+                    "reestimator.capacity",
+                    self._propose_capacity(plan.cand_capacity, need, plan.m)))
+            target_p2 = None
+            if plan.phase2 != "exact":
+                target_p2 = plan.p2_capacity
+                if 2 in overflowed:
+                    target_p2 = self._propose_capacity(plan.p2_capacity, need_p2, plan.m)
+            grows = target > plan.cand_capacity or (
+                target_p2 is not None and target_p2 > plan.p2_capacity)
+            if not grows:
                 self._degrade(
                     f"capacity cap exhausted: current cand_capacity="
                     f"{plan.cand_capacity} already meets the bumped target "
-                    f"{target} (cap {self.capacity_cap or plan.m}, m={plan.m})",
+                    f"{target}"
+                    + ("" if target_p2 is None else
+                       f" and p2_capacity={plan.p2_capacity} the target {target_p2}")
+                    + f" (cap {self.capacity_cap or plan.m}, m={plan.m})",
                     None,
                 )
                 return
@@ -209,7 +239,7 @@ class CapacityReestimator:
                     with self._lock:
                         self.counters["replans"] += 1
                     new_plan = replan_with_capacity(
-                        plan, min_cand_capacity=target, min_p2_capacity=target
+                        plan, min_cand_capacity=target, min_p2_capacity=target_p2
                     )
                     break
                 except Exception as exc:  # noqa: BLE001 — any build failure retries
@@ -229,6 +259,8 @@ class CapacityReestimator:
                 self.counters["swaps"] += 1
                 self._state = HEALTHY
                 self._need_max = 0
+                self._p2_need_max = 0
+                self._overflowed = set()
         except Exception as exc:  # noqa: BLE001 — swap/injection failures degrade too
             self._degrade(f"background re-plan crashed "
                           f"({type(exc).__name__}: {exc})", exc)
@@ -276,6 +308,8 @@ class CapacityReestimator:
         with self._lock:
             self._state = HEALTHY
             self._need_max = 0
+            self._p2_need_max = 0
+            self._overflowed = set()
             self._pending_warning = None
             self.last_error = None
 
@@ -283,8 +317,10 @@ class CapacityReestimator:
         """Snapshot: counters + state + the installed plan's capacity.
 
         Besides the event counts, ``overflow_queries`` sums every served
-        batch's overflowed queries and ``replan_s`` the seconds background
-        re-plans took, build to swap."""
+        batch's Phase-1 overflowed queries (ring search),
+        ``p2_overflow_queries`` its Phase-2 ones (masked exact sweep; 0 on
+        exact plans), and ``replan_s`` the seconds background re-plans
+        took, build to swap."""
         with self._lock:
             out = dict(self.counters, state=self._state,
                        need_max=self._need_max)

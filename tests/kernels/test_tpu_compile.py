@@ -28,6 +28,7 @@ from repro.kernels.aidw_grid import (
     phase1_alpha_row_runs,
     phase2_far_aggregates,
     phase2_far_nodes,
+    phase2_near_row_runs,
     phase2_near_weights,
     phase2_weights_full,
     row_run_max_tiles,
@@ -148,6 +149,27 @@ def test_near_weight_kernel(compile_for_chip):
                            interpret=False)
     q, cand = ((N,), F32), ((NB, CAPACITY), F32)
     compile_for_chip(fn, q, q, ((N, 1), F32), cand, cand, cand, ((NB,), jnp.int32))
+
+
+# The quadtree cell's near field: 16,384 raster queries split at seam level
+# 3 (128 blocks), a 512x512 grid over m = 8,388,608 points, near capacity
+# about 2.1M points: the SMEM tile table takes three launches.
+QUADTREE_NEAR = (128 * BLOCK_Q, 128, 2_097_152, 512, 8_388_608)
+
+
+@pytest.mark.parametrize("tile", _ROW_TILES)
+def test_near_weight_kernel_rows(compile_for_chip, tile):
+    n, nb, capacity, gy, m = QUADTREE_NEAR
+
+    def fn(qx, qy, ah, tiles, nt, rects, px, py, pz, pc):
+        return phase2_near_row_runs(qx, qy, ah, tiles, nt, rects, (px, py, pz, pc),
+                                    tile=tile, m_real=m, block_q=BLOCK_Q, interpret=False)
+
+    q, pts = ((n,), F32), ((m + 1,), F32)
+    compile_for_chip(fn, q, q, ((n, 1), F32),
+                     ((nb, row_run_max_tiles(capacity, tile, gy)), jnp.int32),
+                     ((nb,), jnp.int32), ((nb, 4), jnp.int32), pts, pts, pts,
+                     ((m,), jnp.int32))
 
 
 def test_far_cell_kernel(compile_for_chip):
