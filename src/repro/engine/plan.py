@@ -64,6 +64,12 @@ _MAX_REBUILDS = 3
 # (worst-case relative error above ~half the data scale promises nothing).
 _FALLBACK_BOUND_CEIL = 0.5
 
+# The exact Phase-2 sweep's data tile (grid plans): its lane-dense
+# accumulators do not grow with the tile, and a wider tile spreads a grid
+# step's fixed cost over more pairs (on a TPU v5e the kernel runs 2.89e11
+# pairs/s at 4,096 points, 2.50-2.74e11 at 2,048, 1.18e11 at 512; PERF.md §6).
+_SWEEP_TILE = 4096
+
 # Per-tile element budget for the Phase-2 near/far sweeps: block_q * tile_d
 # capped so the in-kernel (block_q, tile_d) f32 distance tile stays ~1 MiB.
 _P2_TILE_ELEMS = 64 * 4096
@@ -116,7 +122,7 @@ class InterpolationPlan:
     area: float
     m: int                    # real (unpadded) data-point count
     block_q: int
-    block_d: int              # data-axis tile: dense sweep / grid Phase 2
+    block_d: int              # data-axis tile: dense sweep / grid exact Phase 2
     interpret: bool
     knn: str                  # chunked: "brute" | "grid"
     q_chunk: int
@@ -551,7 +557,7 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
 
     # Phase-2 full-data sweep: sentinel-pad to its own tile multiple (kept on
     # farfield plans too — it is the exact arm of the overflow fallback)
-    bd2 = min(block_d, max(128, _round_up(m, 128)))
+    bd2 = min(_SWEEP_TILE, max(128, _round_up(m, 128)))
     big = coord_sentinel(dtype)
     data = (
         pad_to(jnp.asarray(dx), bd2, big)[None, :],

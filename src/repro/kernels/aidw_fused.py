@@ -30,7 +30,10 @@ from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
     sq_dist_tile,
-    weight_tile,
+    sweep_init,
+    sweep_scratch,
+    sweep_step,
+    sweep_z,
 )
 
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary"))
@@ -38,13 +41,11 @@ _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", 
 
 def _fused_kernel(
     qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref,
-    best, ah, acc_w, acc_wz, min_d2, hit_z, *, m_real, area, params,
+    best, ah, *acc, m_real, area, params,
 ):
     phase = pl.program_id(1)
     j = pl.program_id(2)
     last_j = pl.num_programs(2) - 1
-    qx, qy = qx_ref[...], qy_ref[...]
-    d2 = sq_dist_tile(qx, qy, dx_ref[...], dy_ref[...])  # (bn, bm)
 
     @pl.when(phase == 0)
     def _knn_phase():
@@ -52,6 +53,7 @@ def _fused_kernel(
         def _init():
             best[...] = jnp.full(best.shape, jnp.inf, best.dtype)
 
+        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])  # (bn, bm)
         best[...] = running_k_best(best[...], d2, axis=1)
 
         @pl.when(j == last_j)
@@ -64,23 +66,13 @@ def _fused_kernel(
     def _weight_phase():
         @pl.when(j == 0)
         def _init():
-            acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-            acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-            min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
-            hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+            sweep_init(acc)
 
-        sw, swz, tmin, thz = weight_tile(d2, dz_ref[...], ah[...], data_axis=1)
-        acc_w[...] += sw
-        acc_wz[...] += swz
-        better = tmin < min_d2[...]
-        hit_z[...] = jnp.where(better, thz, hit_z[...])
-        min_d2[...] = jnp.where(better, tmin, min_d2[...])
+        sweep_step(qx_ref, qy_ref, ah, dx_ref, dy_ref, dz_ref, acc, j)
 
         @pl.when(j == last_j)
         def _finish():
-            out_ref[...] = jnp.where(
-                min_d2[...] <= params.exact_hit_eps, hit_z[...], acc_wz[...] / acc_w[...]
-            )
+            out_ref[...] = sweep_z(acc, params.exact_hit_eps)
 
 
 def aidw_fused_soa(
@@ -101,8 +93,8 @@ def aidw_fused_soa(
         in_specs=[q_spec, q_spec, d_spec, d_spec, d_spec],
         out_specs=[o_spec, o_spec],
         out_shape=[jax.ShapeDtypeStruct((n, 1), dtype)] * 2,
-        scratch_shapes=[pltpu.VMEM((block_q, k), dtype)]
-        + [pltpu.VMEM((block_q, 1), dtype) for _ in range(5)],
+        scratch_shapes=[pltpu.VMEM((block_q, k), dtype), pltpu.VMEM((block_q, 1), dtype)]
+        + sweep_scratch(block_q, block_d, dtype),
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name="_fused_kernel",

@@ -81,13 +81,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.aidw import AIDWParams
 from repro.core.grid import UniformGrid
 from repro.core.knn import running_k_best
+from repro.core.layouts import coord_sentinel
 from repro.kernels._common import (
     alpha_from_best,
     pow_weight,
     sq_dist_tile,
-    weight_tile,
+    sweep_finish,
+    sweep_init,
+    sweep_scratch,
+    sweep_step,
 )
-from repro.kernels.aidw_tiled import _SEMANTICS, _knn_kernel_soa, _weight_kernel_soa
+from repro.kernels.aidw_tiled import _SEMANTICS, _knn_kernel_soa, weight_sweep_soa
 
 
 def block_rectangles(grid: UniformGrid, cx, cy, r_safe, block_q: int):
@@ -435,38 +439,27 @@ def phase1_alpha_row_runs(
 
 
 def _near_weight_kernel(nt_ref, qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref,
-                        sw_ref, swz_ref, md_ref, hz_ref,
-                        acc_w, acc_wz, min_d2, hit_z):
-    """Near-field half of the far-field Phase 2: ``_weight_kernel_soa`` over
-    per-block candidate rows, with the Phase-1 tile-table skip (steps past
-    ``nt_ref[i]`` are clamped revisits, the accumulation is predicated off)
-    — and the four accumulators written out instead of a finished z, so the
-    engine can fold in the far-cell terms before dividing."""
+                        sw_ref, swz_ref, md_ref, hz_ref, *acc):
+    """Near-field half of the far-field Phase 2: ``_weight_kernel_soa``'s
+    sweep step over per-block candidate rows, with the Phase-1 tile-table
+    skip (steps past ``nt_ref[i]`` are clamped revisits, the accumulation
+    is predicated off) — and the four reduced accumulators written out
+    instead of a finished z, so the engine can fold in the far-cell terms
+    before dividing."""
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-        min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
-        hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+        sweep_init(acc)
 
     @pl.when(j < nt_ref[i])
     def _accumulate():
-        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])
-        sw, swz, tmin, thz = weight_tile(d2, dz_ref[...], ah_ref[...], data_axis=1)
-        acc_w[...] += sw
-        acc_wz[...] += swz
-        better = tmin < min_d2[...]
-        hit_z[...] = jnp.where(better, thz, hit_z[...])
-        min_d2[...] = jnp.where(better, tmin, min_d2[...])
+        sweep_step(qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref, acc, j)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        sw_ref[...] = acc_w[...]
-        swz_ref[...] = acc_wz[...]
-        md_ref[...] = min_d2[...]
-        hz_ref[...] = hit_z[...]
+        for ref, v in zip((sw_ref, swz_ref, md_ref, hz_ref), sweep_finish(acc)):
+            ref[...] = v
 
 
 def phase2_near_weights(
@@ -494,7 +487,7 @@ def phase2_near_weights(
         grid=(nb, c_tot // block_d),
         in_specs=[q_spec, q_spec, q_spec, c_spec, c_spec, c_spec],
         out_specs=[q_spec] * 4,
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+        scratch_shapes=sweep_scratch(block_q, block_d, dtype),
     )
     return pl.pallas_call(
         _near_weight_kernel,
@@ -509,45 +502,38 @@ def phase2_near_weights(
 
 def _near_weight_kernel_rows(tiles_ref, nt_ref, rect_ref, qx_ref, qy_ref, ah_ref,
                              px_ref, py_ref, pz_ref, pc_ref, _sw_in, _swz_in, _md_in,
-                             _hz_in, sw_ref, swz_ref, md_ref, hz_ref,
-                             acc_w, acc_wz, min_d2, hit_z, *, tile, max_tiles, m_real):
+                             _hz_in, sw_ref, swz_ref, md_ref, hz_ref, x_row, y_row, z_row,
+                             *acc, tile, max_tiles, m_real):
     """Near-field weight sweep over a block's CSR row runs, read in place.
 
-    The walk is :func:`_knn_kernel_skip`'s; a lane that
-    :func:`_row_run_step` finds outside the block's near rectangle reads
-    ``d2 = +inf`` (weight 0) and ``z = 0`` (the blocks run past the
-    arrays' ends, where z is not data).  Each step folds ``weight_tile``
-    into the four accumulators of :func:`_near_weight_kernel`.
+    The walk is :func:`_knn_kernel_skip`'s.  Each step writes its tile's
+    points to the ``(1, tile)`` rows ``x_row/y_row/z_row``, a lane that
+    :func:`_row_run_step` finds outside the block's near rectangle as the
+    coordinate sentinel (``d2 = +inf``, weight 0, never a hit) with
+    ``z = 0`` (the blocks run past the arrays' ends, where z is not data),
+    and folds them with :func:`sweep_step` into the accumulators of
+    :func:`_near_weight_kernel`.
     """
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-        min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
-        hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+        sweep_init(acc)
 
     @pl.when(j < nt_ref[i])
     def _accumulate():
         lanes, inside = _row_run_step(tiles_ref, rect_ref, pc_ref, i, j, tile=tile,
                                       max_tiles=max_tiles, m_real=m_real)
-        d2 = sq_dist_tile(qx_ref[...], qy_ref[...], lanes(px_ref), lanes(py_ref))
-        d2 = jnp.where(inside, d2, jnp.asarray(jnp.inf, d2.dtype))
-        dz = jnp.where(inside, lanes(pz_ref), jnp.zeros((), d2.dtype))
-        sw, swz, tmin, thz = weight_tile(d2, dz, ah_ref[...], data_axis=1)
-        acc_w[...] += sw
-        acc_wz[...] += swz
-        better = tmin < min_d2[...]
-        hit_z[...] = jnp.where(better, thz, hit_z[...])
-        min_d2[...] = jnp.where(better, tmin, min_d2[...])
+        big = coord_sentinel(x_row.dtype)
+        x_row[...] = jnp.where(inside, lanes(px_ref), big)
+        y_row[...] = jnp.where(inside, lanes(py_ref), big)
+        z_row[...] = jnp.where(inside, lanes(pz_ref), jnp.zeros((), z_row.dtype))
+        sweep_step(qx_ref, qy_ref, ah_ref, x_row, y_row, z_row, acc, j)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        sw_ref[...] = acc_w[...]
-        swz_ref[...] = acc_wz[...]
-        md_ref[...] = min_d2[...]
-        hz_ref[...] = hit_z[...]
+        for ref, v in zip((sw_ref, swz_ref, md_ref, hz_ref), sweep_finish(acc)):
+            ref[...] = v
 
 
 def phase2_near_row_runs(
@@ -580,7 +566,8 @@ def phase2_near_row_runs(
             in_specs=[q_spec] * 3 + [pl.BlockSpec((_ROW_BLOCK,), p_map)] * 4
             + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
             out_specs=[q_spec] * 4,
-            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+            scratch_shapes=[pltpu.VMEM((1, tile), dtype) for _ in range(3)]
+            + sweep_scratch(block_q, tile, dtype),
         )
         outs = pl.pallas_call(
             functools.partial(_near_weight_kernel_rows, tile=tile, max_tiles=max_tiles,
@@ -761,20 +748,5 @@ def phase2_weights_full(
     dxp/dyp/dzp: (1, mp) sentinel-padded data, ``mp % block_d == 0``.
     Returns z_hat, shape ``(n_tot, 1)``.
     """
-    n_tot = qx_s.shape[0]
-    dtype = qx_s.dtype
-    qx2, qy2 = qx_s[:, None], qy_s[:, None]
-    q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-    d_spec = pl.BlockSpec((1, block_d), lambda i, j: (0, j))
-    o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_weight_kernel_soa, eps=eps),
-        grid=(n_tot // block_q, dxp.shape[1] // block_d),
-        in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tot, 1), dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-        name="_weight_kernel_soa",
-    )(qx2, qy2, alpha * 0.5, dxp, dyp, dzp)
+    return weight_sweep_soa(qx_s[:, None], qy_s[:, None], alpha * 0.5, dxp, dyp, dzp,
+                            eps=eps, block_q=block_q, block_d=block_d, interpret=interpret)

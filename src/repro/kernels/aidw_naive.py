@@ -17,6 +17,7 @@ argument for the paper's tiling strategy on this hardware.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +29,21 @@ from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
     sq_dist_tile,
+    sweep_init,
+    sweep_scratch,
+    sweep_step,
+    sweep_z,
     weight_tile,
 )
 
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel",))
+# The SoA weight pass walks the row in windows of at most this many points
+# (a divisor of the padded m), each one unrolled sweep step.
+_SWEEP_WINDOW = 2048
 
 
-def _naive_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref, *, m_real, area, params):
+def _naive_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref, ah, *acc,
+                      m_real, area, params, window):
     qx, qy = qx_ref[...], qy_ref[...]
     # --- pass 1: distances + kNN (paper Fig. 3 lines 11-34) ---
     d2 = sq_dist_tile(qx, qy, dx_ref[...], dy_ref[...])  # (bn, m)
@@ -43,10 +52,19 @@ def _naive_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, alpha_ref
     best = running_k_best(best0, d2, axis=1)
     alpha = alpha_from_best(best, m_real, area, params, data_axis=1)
     alpha_ref[...] = alpha
-    # --- pass 2: distances AGAIN + weighting (paper lines 52-58) ---
-    d2b = sq_dist_tile(qx, qy, dx_ref[...], dy_ref[...])
-    sw, swz, tmin, thz = weight_tile(d2b, dz_ref[...], alpha * 0.5, data_axis=1)
-    out_ref[...] = jnp.where(tmin <= params.exact_hit_eps, thz, swz / sw)
+    ah[...] = alpha * 0.5
+    # --- pass 2: distances AGAIN + weighting (paper lines 52-58), the
+    # weight sweep step over the row ``window`` points at a time ---
+    sweep_init(acc)
+
+    def body(t, carry):
+        cols = pl.ds(pl.multiple_of(t * window, window), window)
+        sweep_step(qx_ref, qy_ref, ah, dx_ref.at[:, cols], dy_ref.at[:, cols],
+                   dz_ref.at[:, cols], acc, t)
+        return carry
+
+    jax.lax.fori_loop(0, dx_ref.shape[1] // window, body, 0)
+    out_ref[...] = sweep_z(acc, params.exact_hit_eps)
 
 
 def _naive_kernel_aoas(qx_ref, qy_ref, d_ref, out_ref, alpha_ref, *, m_real, area, params):
@@ -71,15 +89,18 @@ def aidw_naive_soa(
     n, m = qx.shape[0], dx.shape[1]
     dtype = qx.dtype
     grid = (n // block_q,)
+    window = math.gcd(m, _SWEEP_WINDOW)
     q_spec = pl.BlockSpec((block_q, 1), lambda i: (i, 0))
     d_spec = pl.BlockSpec((1, m), lambda i: (0, 0))  # full array, re-fetched per block
     o_spec = pl.BlockSpec((block_q, 1), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_naive_kernel_soa, m_real=m_real, area=area, params=params),
+        functools.partial(_naive_kernel_soa, m_real=m_real, area=area, params=params,
+                          window=window),
         grid=grid,
         in_specs=[q_spec, q_spec, d_spec, d_spec, d_spec],
         out_specs=[o_spec, o_spec],
         out_shape=[jax.ShapeDtypeStruct((n, 1), dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype)] + sweep_scratch(block_q, window, dtype),
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name="_naive_kernel_soa",

@@ -11,7 +11,8 @@ Two kernels, matching the paper's two distance sweeps:
   1. knn pass  → per-query adaptive alpha (Eq. 2-6), running k-best in VMEM
      scratch (the vectorised replacement for the per-thread insertion sort).
   2. weight pass → accumulates Σw, Σw·z in VMEM scratch; exact-hit guard via
-     running (min d², z_at_min).
+     running (min d², z_at_min).  The SoA pass keeps them lane-dense and
+     reduces across lanes once per query block (``_common.sweep_step``).
 """
 
 from __future__ import annotations
@@ -28,6 +29,10 @@ from repro.core.knn import running_k_best
 from repro.kernels._common import (
     alpha_from_best,
     sq_dist_tile,
+    sweep_init,
+    sweep_scratch,
+    sweep_step,
+    sweep_z,
     weight_tile,
 )
 
@@ -65,29 +70,41 @@ def _knn_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best, *, m_real, 
         alpha_ref[...] = alpha_from_best(best[...], m_real, area, params, data_axis=1)
 
 
-def _weight_kernel_soa(
-    qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref, out_ref, acc_w, acc_wz, min_d2, hit_z, *, eps
-):
+def _weight_kernel_soa(qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref, out_ref, *acc, eps):
+    """Exact weight sweep: step ``j`` folds data tile ``j`` into the block's
+    lane-dense accumulators (:func:`sweep_step`), the last step reduces them
+    once and applies the exact-hit guard."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-        min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
-        hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+        sweep_init(acc)
 
-    d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])
-    sw, swz, tmin, thz = weight_tile(d2, dz_ref[...], ah_ref[...], data_axis=1)
-    acc_w[...] += sw
-    acc_wz[...] += swz
-    better = tmin < min_d2[...]
-    hit_z[...] = jnp.where(better, thz, hit_z[...])
-    min_d2[...] = jnp.where(better, tmin, min_d2[...])
+    sweep_step(qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref, acc, j)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        out_ref[...] = jnp.where(min_d2[...] <= eps, hit_z[...], acc_wz[...] / acc_w[...])
+        out_ref[...] = sweep_z(acc, eps)
+
+
+def weight_sweep_soa(qx, qy, alpha_half, dx, dy, dz, *, eps: float, block_q: int,
+                     block_d: int, interpret: bool):
+    """Phase 2 over every point: qx/qy/alpha_half (n, 1), dx/dy/dz (1, m)
+    sentinel-padded, ``n % block_q == m % block_d == 0``.  Returns z (n, 1)."""
+    n, m = qx.shape[0], dx.shape[1]
+    q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
+    d_spec = pl.BlockSpec((1, block_d), lambda i, j: (0, j))
+    return pl.pallas_call(
+        functools.partial(_weight_kernel_soa, eps=eps),
+        grid=(n // block_q, m // block_d),
+        in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((n, 1), qx.dtype),
+        scratch_shapes=sweep_scratch(block_q, block_d, qx.dtype),
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="_weight_kernel_soa",
+    )(qx, qy, alpha_half, dx, dy, dz)
 
 
 def aidw_tiled_soa(
@@ -121,17 +138,8 @@ def aidw_tiled_soa(
         )(qx, qy, dx, dy)
 
     with jax.named_scope("aidw.phase2"):
-        zhat = pl.pallas_call(
-            functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
-            grid=grid,
-            in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
-            compiler_params=_SEMANTICS,
-            interpret=interpret,
-            name="_weight_kernel_soa",
-        )(qx, qy, alpha * 0.5, dx, dy, dz)
+        zhat = weight_sweep_soa(qx, qy, alpha * 0.5, dx, dy, dz, eps=params.exact_hit_eps,
+                                block_q=block_q, block_d=block_d, interpret=interpret)
     return zhat, alpha
 
 

@@ -30,6 +30,7 @@ from repro.kernels._common import (
     alpha_from_best,
     sq_dist_tile,
 )
+from repro.kernels.aidw_tiled import weight_sweep_soa
 
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
@@ -92,29 +93,12 @@ def aidw_tiled_v2_soa(
 ):
     """Full v2 AIDW: threshold-skip kNN pass + the baseline weight pass.
     Returns (z_hat (n,1), alpha (n,1), merges (n_blocks,1))."""
-    from repro.kernels.aidw_tiled import _weight_kernel_soa
-
-    n, m = qx.shape[0], dx.shape[1]
-    dtype = qx.dtype
-    grid = (n // block_q, m // block_d)
     with jax.named_scope("aidw.phase1"):
         alpha, merges = aidw_knn_v2(
             dx, dy, qx, qy, params=params, area=area, m_real=m_real,
             block_q=block_q, block_d=block_d, interpret=interpret,
         )
-    q_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
-    d_spec = pl.BlockSpec((1, block_d), lambda i, j: (0, j))
-    o_spec = pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))
     with jax.named_scope("aidw.phase2"):
-        zhat = pl.pallas_call(
-            functools.partial(_weight_kernel_soa, eps=params.exact_hit_eps),
-            grid=grid,
-            in_specs=[q_spec, q_spec, q_spec, d_spec, d_spec, d_spec],
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
-            compiler_params=_SEMANTICS,
-            interpret=interpret,
-            name="_weight_kernel_soa",
-        )(qx, qy, alpha * 0.5, dx, dy, dz)
+        zhat = weight_sweep_soa(qx, qy, alpha * 0.5, dx, dy, dz, eps=params.exact_hit_eps,
+                                block_q=block_q, block_d=block_d, interpret=interpret)
     return zhat, alpha, merges

@@ -13,33 +13,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._common import sq_dist_tile, weight_tile
+from repro.kernels._common import sweep_init, sweep_scratch, sweep_step, sweep_z
 
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
-def _idw_kernel(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, acc_w, acc_wz, min_d2, hit_z, *, alpha_half, eps):
+def _idw_kernel(qx_ref, qy_ref, dx_ref, dy_ref, dz_ref, out_ref, ah, *acc, alpha_half, eps):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-        min_d2[...] = jnp.full(min_d2.shape, jnp.inf, min_d2.dtype)
-        hit_z[...] = jnp.zeros(hit_z.shape, hit_z.dtype)
+        ah[...] = jnp.full(ah.shape, alpha_half, ah.dtype)
+        sweep_init(acc)
 
-    d2 = sq_dist_tile(qx_ref[...], qy_ref[...], dx_ref[...], dy_ref[...])
-    ah = jnp.asarray(alpha_half, d2.dtype)
-    sw, swz, tmin, thz = weight_tile(d2, dz_ref[...], ah, data_axis=1)
-    acc_w[...] += sw
-    acc_wz[...] += swz
-    better = tmin < min_d2[...]
-    hit_z[...] = jnp.where(better, thz, hit_z[...])
-    min_d2[...] = jnp.where(better, tmin, min_d2[...])
+    sweep_step(qx_ref, qy_ref, ah, dx_ref, dy_ref, dz_ref, acc, j)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        out_ref[...] = jnp.where(min_d2[...] <= eps, hit_z[...], acc_wz[...] / acc_w[...])
+        out_ref[...] = sweep_z(acc, eps)
 
 
 def idw_tiled_soa(
@@ -59,7 +50,7 @@ def idw_tiled_soa(
         in_specs=[q_spec, q_spec, d_spec, d_spec, d_spec],
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1), dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(4)],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype)] + sweep_scratch(block_q, block_d, dtype),
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name="_idw_kernel",

@@ -4,10 +4,10 @@ Every other kernel test runs in Pallas interpret mode, which accepts block
 shapes and primitives the TPU compiler refuses.  These tests compile each
 kernel with ``interpret=False`` for a described (not attached) TPU v5e chip
 at n = m = 2^20 queries/points, ``block_q=256``, ``block_d=512``, grid
-candidate capacity 4096 and k = 10 — and Phase 1 of the grid path also at
-the served benchmark cells' shapes: nothing runs, but a kernel Mosaic would
-refuse on the chip (a tile it cannot lay out, an SMEM table that does not
-fit) fails here.
+candidate capacity 4096 and k = 10 — and Phase 1, the exact sweep and the
+row-run near field of the grid path also at the served benchmark cells'
+shapes: nothing runs, but a kernel Mosaic would refuse on the chip (a tile
+it cannot lay out, an SMEM table that does not fit) fails here.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process may hold the TPU compiler library, so describing it while
@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.aidw import AIDWParams
-from repro.engine.plan import _ROW_TILES
+from repro.engine.plan import _ROW_TILES, _SWEEP_TILE
 from repro.kernels.aidw_grid import (
     phase1_alpha_from_candidates,
     phase1_alpha_row_runs,
@@ -151,15 +151,24 @@ def test_near_weight_kernel(compile_for_chip):
     compile_for_chip(fn, q, q, ((N, 1), F32), cand, cand, cand, ((NB,), jnp.int32))
 
 
-# The quadtree cell's near field: 16,384 raster queries split at seam level
-# 3 (128 blocks), a 512x512 grid over m = 8,388,608 points, near capacity
-# about 2.1M points: the SMEM tile table takes three launches.
-QUADTREE_NEAR = (128 * BLOCK_Q, 128, 2_097_152, 512, 8_388_608)
+# The row-run near field: (queries incl. seam padding, blocks, near
+# capacity, gy, m).  The quadtree cell's (16,384 raster queries split at
+# seam level 3, a 512x512 grid over m = 8,388,608 points, near capacity
+# about 2.1M points: the SMEM tile table takes three launches), and the
+# exact cells' batches and data (65,536 and 16,384 queries; m = 1,024,000
+# on a 253x253 grid and 2,097,152 on a 256x256 one) at a quarter-grid
+# near field.
+NEAR_SHAPES = {
+    "quadtree": (128 * BLOCK_Q, 128, 2_097_152, 512, 8_388_608),
+    "scatter": (320 * BLOCK_Q, 320, 262_144, 253, 1_024_000),
+    "lidar-raster": (80 * BLOCK_Q, 80, 524_288, 256, 2_097_152),
+}
 
 
+@pytest.mark.parametrize("shape", sorted(NEAR_SHAPES))
 @pytest.mark.parametrize("tile", _ROW_TILES)
-def test_near_weight_kernel_rows(compile_for_chip, tile):
-    n, nb, capacity, gy, m = QUADTREE_NEAR
+def test_near_weight_kernel_rows(compile_for_chip, tile, shape):
+    n, nb, capacity, gy, m = NEAR_SHAPES[shape]
 
     def fn(qx, qy, ah, tiles, nt, rects, px, py, pz, pc):
         return phase2_near_row_runs(qx, qy, ah, tiles, nt, rects, (px, py, pz, pc),
@@ -189,8 +198,24 @@ def test_far_node_kernel(compile_for_chip):
     compile_for_chip(fn, q, q, ((N, 1), F32), *[nodes] * 6, ((NB,), jnp.int32))
 
 
-def test_phase2_weights_full(compile_for_chip):
+# The exact sweep's launches: (queries incl. seam padding, m).  "2^20" at
+# the paper-size tile above; the served cells' at the tile the plan picks
+# (scatter: 65,536 queries over the paper's 1000K; the two rasters: 16,384
+# cell centres over 1000K uniform points and over the 2,097,152 QL2 returns).
+SWEEP_SHAPES = {
+    "scatter": (320 * BLOCK_Q, 1_024_000),
+    "uniform-raster": (80 * BLOCK_Q, 1_024_000),
+    "lidar-raster": (80 * BLOCK_Q, 2_097_152),
+}
+SWEEP_CASES = [
+    pytest.param(N, M, BLOCK_D, id="2^20"),
+    *(pytest.param(n, m, _SWEEP_TILE, id=cell) for cell, (n, m) in SWEEP_SHAPES.items()),
+]
+
+
+@pytest.mark.parametrize("n,m,block_d", SWEEP_CASES)
+def test_phase2_weights_full(compile_for_chip, n, m, block_d):
     fn = functools.partial(phase2_weights_full, eps=PARAMS.exact_hit_eps,
-                           block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
-    q, row = ((N,), F32), ((1, M), F32)
-    compile_for_chip(fn, q, q, ((N, 1), F32), row, row, row)
+                           block_q=BLOCK_Q, block_d=block_d, interpret=False)
+    q, row = ((n,), F32), ((1, -(-m // block_d) * block_d), F32)
+    compile_for_chip(fn, q, q, ((n, 1), F32), row, row, row)
