@@ -94,15 +94,16 @@ def main():
           f"(cand_capacity {tight.cand_capacity} -> {healer.plan.cand_capacity})")
     print(f"  state={healer.state} swaps={healer.stats()['swaps']}")
 
-    # Phase 2 is a full m-point sweep in every exact impl.  phase2="farfield"
-    # sweeps exact weights only inside a plan-chosen near radius and folds one
-    # aggregate term per far cell — the first approximating path, so it ships
-    # with an error budget: the plan proves a worst-case bound, and
-    # farfield_error_report measures the real error against the Kahan oracle
-    # (DESIGN.md §7; the budget is enforced by tests/engine/test_farfield.py).
-    # The bound is meaningful when cells are compact relative to the near
-    # distance — demo data: tight per-cell sensor clusters on a coarse grid
-    # (on generic data the plan warns and reports an honest, weak bound).
+    # Phase 2 is a full m-point sweep in every exact impl.  phase2="quadtree"
+    # sweeps exact weights only inside a plan-chosen near radius and walks a
+    # quadtree of cell aggregates beyond it, one aggregate + dipole term per
+    # closed node — an approximating path, so it ships with an error budget:
+    # the plan proves a worst-case bound, and farfield_error_report measures
+    # the real error against the Kahan oracle (DESIGN.md §8; the budget is
+    # enforced by tests/engine/test_quadtree.py).  The bound is meaningful
+    # when cells are compact relative to the near distance — demo data: tight
+    # per-cell sensor clusters on a coarse grid (on generic data the plan
+    # warns and reports an honest, weak bound).
     from repro.core.accuracy import farfield_error_report
     from repro.core.grid import build_grid
     import jax.numpy as jnp
@@ -115,16 +116,16 @@ def main():
     sdz = truth(spts[:, 0], spts[:, 1]).astype(np.float32)
     sgrid = build_grid(jnp.asarray(spts[:, 0]), jnp.asarray(spts[:, 1]),
                        jnp.asarray(sdz), gx=g, gy=g)
-    ff = build_plan(spts[:, 0], spts[:, 1], sdz, params=params, area=1.0,
-                    impl="grid", grid=sgrid, phase2="farfield",
+    qt = build_plan(spts[:, 0], spts[:, 1], sdz, params=params, area=1.0,
+                    impl="grid", grid=sgrid, phase2="quadtree",
                     farfield_radius=3, block_q=64)
     fq = rng.random((512, 2)).astype(np.float32)
-    report = farfield_error_report(ff, fq[:, 0], fq[:, 1])
-    _, _, ff_stats = execute_with_stats(ff, fq[:, 0], fq[:, 1])
-    print("far-field Phase 2 (near radius "
-          f"{ff.farfield_radius} cells, proved bound {ff.farfield_bound:.3g}):")
-    print(f"  near_points_mean={float(ff_stats['near_points_mean']):.0f} of m={ff.m}, "
-          f"far_cells_mean={float(ff_stats['far_cells_mean']):.0f}")
+    report = farfield_error_report(qt, fq[:, 0], fq[:, 1])
+    _, _, qt_stats = execute_with_stats(qt, fq[:, 0], fq[:, 1])
+    print("quadtree Phase 2 (near radius "
+          f"{qt.farfield_radius} cells, proved bound {qt.farfield_bound:.3g}):")
+    print(f"  near_points_mean={float(qt_stats['near_points_mean']):.0f} of m={qt.m}, "
+          f"far_cells_mean={float(qt_stats['far_cells_mean']):.0f}")
     print(f"  measured max rel err {report['max_rel_err']:.2e} "
           f"(within_bound={report['within_bound']})")
 

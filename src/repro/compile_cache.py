@@ -1,6 +1,6 @@
 """Where the entry scripts keep JAX's persistent compilation cache.
 
-Called explicitly by ``chip_smoke.py`` and ``benchmarks/run.py`` at start-up,
+Called explicitly by ``chip_smoke.py`` and ``bench/run.py`` at start-up,
 never on import of ``repro``: a library import must not redirect a caller's
 cache.
 """

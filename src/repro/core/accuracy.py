@@ -98,7 +98,7 @@ def relative_rmse(approx, exact):
 # Floating-point slack separating the far-field *model* bound (exact
 # arithmetic) from a measured comparison of two finite-precision pipelines:
 # both the approximated path and the Kahan oracle round, so a mathematically
-# 0-error case (one point per far cell — the aggregate IS the point; or a
+# 0-error case (one point per far node — the aggregate IS the point; or a
 # phase2="exact" plan, bound 0.0) still measures O(eps).  The slack scales
 # with sqrt(m) because the compared path accumulates plain-dtype partial
 # sums over m terms (random-rounding growth); at 64 ulps * sqrt(m) it stays
@@ -111,8 +111,8 @@ FP_SLACK_ULPS = 64
 def farfield_error_report(plan, qx, qy, *, q_chunk: int = 1024, d_chunk: int = 4096):
     """Measure a plan's Phase-2 approximation error against the Kahan oracle.
 
-    The verification half of the far-field contract (the other half is the
-    plan-time model in ``engine.plan._choose_farfield_radius``): runs
+    The verification half of the quadtree contract (the other half is the
+    plan-time model in ``engine.plan._choose_quadtree_radius``): runs
     ``execute(plan, qx, qy)``, recomputes the exact interpolant with the
     Kahan-compensated oracle (:func:`aidw_interpolate_kahan` — ~f64-quality
     accumulation at the data dtype), and reports the measured relative
@@ -120,14 +120,13 @@ def farfield_error_report(plan, qx, qy, *, q_chunk: int = 1024, d_chunk: int = 4
 
     Returns a dict: ``max_rel_err`` / ``rms_rel_err`` / ``max_abs_err``
     (diffs in f64), ``scale``, ``phase2`` (which Phase-2 arm the plan runs
-    — the report covers all three: "exact" plans measure pure fp drift
-    against bound 0.0, "farfield" the single-level aggregate bound, and
-    "quadtree" the multi-level dipole bound of DESIGN.md §8), ``bound``
+    — the report covers both: "exact" plans measure pure fp drift
+    against bound 0.0, "quadtree" the multi-level dipole bound of
+    DESIGN.md §8), ``bound``
     (the plan's ``farfield_bound``), ``fp_slack`` (see
     :data:`FP_SLACK_ULPS`), and ``within_bound`` — ``max_rel_err <= bound
     + fp_slack``, the predicate the error-budget tests
-    (``tests/engine/test_farfield.py``, ``tests/engine/test_quadtree.py``)
-    enforce.
+    (``tests/engine/test_quadtree.py``) enforce.
     """
     import numpy as np
 

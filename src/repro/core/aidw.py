@@ -208,9 +208,8 @@ def brute_r_obs(dx, dy, qx, qy, k: int, *, q_chunk: int = 1024, d_chunk: int = 4
     """Phase 1, brute force: chunked running-k-best scan over ALL m data
     points -> mean k-nearest distance per query, shape ``(n,)``.
 
-    The single implementation behind ``aidw_interpolate(knn="brute")`` and
-    the benchmark baseline (``benchmarks/run.py``), and the pure-jnp twin of
-    the grid path's ``grid_r_obs``."""
+    The single implementation behind ``aidw_interpolate(knn="brute")``, and
+    the pure-jnp twin of the grid path's ``grid_r_obs``."""
     n = qx.shape[0]
     dtype = qx.dtype
     big = jnp.asarray(jnp.finfo(dtype).max / 4, dtype)

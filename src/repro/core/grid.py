@@ -479,43 +479,24 @@ def seam_layout(seg_sorted, n_segments: int, block_q: int, n_slots: int):
 
 
 class CellAggregates(NamedTuple):
-    """Per-cell far-field aggregates over a grid's point set (plan-time).
+    """Per-cell aggregates over a grid's point set (plan-time): level 0 of
+    the quadtree (:func:`quadtree_aggregates`).
 
-    One entry per real cell (``n_cells``): the point count, the z-sum, the
-    centroid of the cell's points, and the cell's integer grid coordinates.
-    ``e_max`` is the grid-wide maximum distance from any point to its cell's
-    centroid — the dispersion radius the far-field error model is built on
-    (``engine.plan._choose_farfield_radius``): every point of a far cell
-    lies within ``e_max`` of the centroid its aggregate term stands in for.
-
-    ``z_dev_max`` (max within-cell deviation from the cell's z mean) and
-    ``z_abs_max`` complete the error model's plan-time inputs: the far
-    z-sum term pays a first-order (in dispersion) error proportional to how
-    much z varies *inside* a cell, while the count term is second-order.
-
-    Empty cells get their *geometric* centre as centroid (count and z-sum
-    are 0, so the value never matters — but a finite coordinate keeps the
-    far kernel's weight finite instead of manufacturing inf·0).
+    One entry per real cell (``n_cells``): the point count, the z-sum and
+    the centroid of the cell's points.  Empty cells get their *geometric*
+    centre as centroid (count and z-sum are 0, so the value never matters —
+    but a finite coordinate keeps the far kernel's weight finite instead of
+    manufacturing inf·0).
     """
 
     cent_x: jnp.ndarray  # (n_cells,) centroid x (cell centre when empty)
     cent_y: jnp.ndarray  # (n_cells,)
     count: jnp.ndarray   # (n_cells,) point count, data dtype (kernel operand)
     z_sum: jnp.ndarray   # (n_cells,) sum of z over the cell's points
-    ix: jnp.ndarray      # (n_cells,) int32 cell x index
-    iy: jnp.ndarray      # (n_cells,) int32 cell y index
-    e_max: float         # max point-to-centroid distance over all cells
-    z_dev_max: float     # max |z_j - cell z mean| over all cells
-    z_abs_max: float     # max |z_j| over all points
 
 
 def cell_aggregates(grid: UniformGrid) -> CellAggregates:
-    """Compute :class:`CellAggregates` from the padded cell layout.
-
-    Eager-only by convention (plan time, like :func:`build_grid`): ``e_max``
-    is returned as a concrete float because the far-field radius choice
-    needs it as a Python number.
-    """
+    """Compute :class:`CellAggregates` from the padded cell layout."""
     nc = grid.n_cells
     dtype = grid.pt_x.dtype
     big = coord_sentinel(dtype)
@@ -533,18 +514,7 @@ def cell_aggregates(grid: UniformGrid) -> CellAggregates:
     cent_x = jnp.where(cnt > 0, sum_x / denom, centre_x)
     cent_y = jnp.where(cnt > 0, sum_y / denom, centre_y)
     z_sum = jnp.sum(grid.cell_z[:nc], axis=1)  # pad slots hold 0
-    dev2 = jnp.where(
-        mask,
-        (cx_cells - cent_x[:, None]) ** 2 + (cy_cells - cent_y[:, None]) ** 2,
-        0.0,
-    )
-    e_max = float(jnp.sqrt(jnp.max(dev2)))
-    z_mean = z_sum / denom
-    z_dev = jnp.where(mask, jnp.abs(grid.cell_z[:nc] - z_mean[:, None]), 0.0)
-    z_dev_max = float(jnp.max(z_dev))
-    z_abs_max = float(jnp.max(jnp.where(mask, jnp.abs(grid.cell_z[:nc]), 0.0)))
-    return CellAggregates(cent_x, cent_y, cnt, z_sum, ix, iy, e_max,
-                          z_dev_max, z_abs_max)
+    return CellAggregates(cent_x, cent_y, cnt, z_sum)
 
 
 class QuadtreeLevel(NamedTuple):
@@ -616,7 +586,7 @@ def _pad_even(a, ny, nx, fill=0.0):
 def quadtree_aggregates(grid: UniformGrid) -> tuple[QuadtreeLevel, ...]:
     """Bottom-up quadtree of far-field aggregates over the grid's points.
 
-    Eager-only by convention (plan time, like :func:`cell_aggregates`):
+    Eager-only by convention (plan time, like :func:`build_grid`):
     the per-level ``e_max`` / ``zd_max`` are concrete floats for the plan's
     level-selection table.  Level 0 is computed exactly from the padded
     cell layout; each coarser level combines 2x2 children with the exact

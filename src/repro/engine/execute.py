@@ -11,12 +11,10 @@ seam-split block layout, per-query safe radii from the plan's
 ``required_radius`` table (closed form — no while-loop), the
 Phase 1 reading each block's CSR row runs in place (or, for
 ``pipeline="dense"``, over candidate rows gathered to the static capacity)
-and the full-data Phase 2 — or, for
-``build_plan(phase2="farfield")`` plans, the near/far split Phase 2 with a
-plan-proved error bound (DESIGN.md §7), or, for ``phase2="quadtree"``
-plans, the multi-level Barnes–Hut far field whose per-node opening
-criterion and dipole correction make the bound second-order (DESIGN.md
-§8).  Exactness is unconditional and
+and the full-data Phase 2 — or, for ``build_plan(phase2="quadtree")``
+plans, an exact near field plus the multi-level Barnes–Hut far field
+whose per-node opening criterion and dipole correction give a plan-proved,
+second-order error bound (DESIGN.md §8).  Exactness is unconditional and
 now *per block*: the kernel result is kept wherever a block's candidates
 fit the plan's capacity, and queries in overflowing blocks (far out-of-bbox
 queries, query distributions unlike the data) get their alpha from the
@@ -51,7 +49,6 @@ from repro.kernels.aidw_grid import (
     gather_candidates_csr,
     phase1_alpha_from_candidates,
     phase1_alpha_row_runs,
-    phase2_far_aggregates,
     phase2_far_nodes,
     phase2_near_row_runs,
     phase2_near_weights,
@@ -77,7 +74,7 @@ def _seam_split_layout(plan: InterpolationPlan, qx_s, qy_s, cx_s, cy_s):
     (sorted index -> slot: maps per-slot results back); both ``None`` when
     splitting is off — the view IS the sorted layout.  The exact Phase 2
     never sees the split layout (alpha is gathered back through ``dest``,
-    so its full-data sweep cost is untouched); the far-field Phase 2 runs
+    so its full-data sweep cost is untouched); the quadtree Phase 2 runs
     in the view, whose per-block rectangles it shares with Phase 1.
     """
     n_tot = qx_s.shape[0]
@@ -102,50 +99,6 @@ def _tile_table(need, capacity: int, block_d: int, pipeline: str):
         covered = jnp.minimum(need, capacity)
         return (covered + block_d - 1) // block_d
     return jnp.full(need.shape, capacity // block_d, jnp.int32)
-
-
-def _phase2_farfield(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
-                     cx_v=None, cy_v=None):
-    """Far-field Phase 2 over a blocked query view (DESIGN.md §7).
-
-    ``qx_v/qy_v`` is any Morton-blocked layout whose length is a multiple of
-    ``plan.block_q`` (the engine passes the seam-split Phase-1 view, the
-    benchmark the plain sorted batch); ``alpha_v (n_tot, 1)`` the matching
-    per-slot alpha; ``cx_v/cy_v`` the view's clamped home cells if the
-    caller already holds them.  Per block: the near rectangle is the
-    home-cell bbox expanded by the plan's near-field radius; its points are
-    swept exactly (CSR gather at the static ``p2_capacity``, tile-table
-    skip), every cell outside it contributes one aggregate term.  Returns
-    ``(z (n_tot, 1), need (nb,), rect_cells (nb,))`` — ``need >
-    p2_capacity`` flags blocks whose near gather was truncated; the caller
-    must route those queries to the exact sweep (the error bound assumes a
-    complete near field).
-    """
-    grid = plan.grid
-    if cx_v is None or cy_v is None:
-        cx_v, cy_v = cell_of(grid, qx_v, qy_v)
-    r_near = jnp.full(cx_v.shape, plan.farfield_radius, jnp.int32)
-    xlo, xhi, ylo, yhi = block_rectangles(grid, cx_v, cy_v, r_near, plan.block_q)
-    cand_x, cand_y, cand_z, need = gather_candidates_csr(
-        grid, xlo, xhi, ylo, yhi, plan.p2_capacity, with_z=True
-    )
-    num_tiles = _tile_table(need, plan.p2_capacity, plan.p2_block_d,
-                            plan.pipeline)
-    ah = alpha_v * 0.5
-    sw_n, swz_n, md_n, hz_n = phase2_near_weights(
-        qx_v, qy_v, ah, cand_x, cand_y, cand_z, num_tiles,
-        block_q=plan.block_q, block_d=plan.p2_block_d, interpret=plan.interpret,
-    )
-    rects = jnp.stack([xlo, xhi, ylo, yhi], axis=1)
-    sw_f, swz_f = phase2_far_aggregates(
-        qx_v, qy_v, ah, rects, plan.far,
-        block_q=plan.block_q, block_d=plan.p2_far_block_d,
-        interpret=plan.interpret,
-    )
-    z = jnp.where(md_n <= plan.params.exact_hit_eps, hz_n,
-                  (swz_n + swz_f) / (sw_n + sw_f))
-    rect_cells = (xhi - xlo + 1) * (yhi - ylo + 1)
-    return z, need, rect_cells
 
 
 def _quadtree_walk(plan: InterpolationPlan, hxlo, hxhi, hylo, hyhi):
@@ -237,8 +190,8 @@ def _phase2_quadtree(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
     the sentinel node, accumulating into the same
     ``(sum_w, sum_wz)`` the near sweep produced.  ``real_b (nb,)``, where
     given, flags the blocks holding a real query: the others (seam pad
-    blocks) walk nothing.  Returns ``(z, need, overflow, rect_cells,
-    closed_counts, opened_tot, proc_tot)`` — ``overflow (nb,)`` flags
+    blocks) walk nothing.  Returns ``(z, need, overflow, closed_counts,
+    opened_tot, proc_tot)`` — ``overflow (nb,)`` flags
     blocks whose near field was truncated (their queries must take the
     exact sweep; the bound assumes completeness), ``closed_counts`` the
     per-level ``(nb,)`` closed node counts for the stats dict.
@@ -307,8 +260,7 @@ def _phase2_quadtree(plan: InterpolationPlan, qx_v, qy_v, alpha_v,
             opened_tot = opened_tot + n_opened
             proc_tot = proc_tot + n_proc
     z = jnp.where(md_n <= plan.params.exact_hit_eps, hz_n, swz / sw)
-    rect_cells = (xhi - xlo + 1) * (yhi - ylo + 1)
-    return z, need, overflow, rect_cells, closed_counts, opened_tot, proc_tot
+    return z, need, overflow, closed_counts, opened_tot, proc_tot
 
 
 def _real_blocks(plan: InterpolationPlan, nb: int, dest):
@@ -443,28 +395,19 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
         alpha = jnp.where(over_q[:, None], alpha_exact, alpha_fast)
 
     dxp, dyp, dzp = plan.data
-    qt_diag = None
     with jax.named_scope("aidw.phase2"):
-        if plan.phase2 in ("farfield", "quadtree"):
-            # approximated Phase 2 runs in the seam-split view (its rectangles
+        if plan.phase2 == "quadtree":
+            # the quadtree Phase 2 runs in the seam-split view (its rectangles
             # must not straddle Morton seams either); alpha maps in through
             # src, the per-slot z maps back through dest.  Blocks whose near
-            # field overflows p2_capacity — or, for the quadtree, whose
-            # closed-node table overflows its level capacity — would violate
-            # the error bound (truncated sweep), so their queries take the
-            # per-block masked exact sweep instead: one overflowing block
-            # costs O(block_q * m), a clean batch costs nothing.
+            # field overflows p2_capacity would violate the error bound
+            # (truncated sweep), so their queries take the per-block masked
+            # exact sweep instead: one overflowing block costs
+            # O(block_q * m), a clean batch costs nothing.
             alpha_v = alpha[src] if src is not None else alpha
-            if plan.phase2 == "quadtree":
-                (z_v, need2, over2_b, rect_cells, closed_counts, opened_tot,
-                 proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v,
-                                              _real_blocks(plan, need.shape[0], dest))
-                qt_diag = (closed_counts, opened_tot, proc_tot)
-            else:
-                with jax.named_scope("aidw.phase2.farfield"):
-                    z_v, need2, rect_cells = _phase2_farfield(plan, qx_v, qy_v,
-                                                              alpha_v, cx_v, cy_v)
-                over2_b = need2 > plan.p2_capacity
+            (z_v, need2, over2_b, closed_counts, opened_tot,
+             proc_tot) = _phase2_quadtree(plan, qx_v, qy_v, alpha_v, cx_v, cy_v,
+                                          _real_blocks(plan, need.shape[0], dest))
             over2_v = jnp.repeat(over2_b, plan.block_q)
             if dest is not None:
                 z_near = z_v[dest]
@@ -504,32 +447,23 @@ def _execute_grid(plan: InterpolationPlan, qx, qy):
             / jnp.maximum(jnp.sum(jnp.where(real_b, run_tiles, 0).astype(jnp.float32))
                           * plan.row_tile, 1.0),
         }
-        if plan.phase2 in ("farfield", "quadtree"):
+        if plan.phase2 == "quadtree":
             n_real_b = jnp.maximum(jnp.sum(real_b.astype(jnp.int32)), 1).astype(jnp.float32)
-            if plan.phase2 == "quadtree":
-                # far work per block is the number of CLOSED nodes summed over
-                # levels — the quantity the O(log m) sweep benchmark tracks
-                closed_counts, opened_tot, proc_tot = qt_diag
-                closed_stack = jnp.stack(closed_counts)           # (n_levels, nb)
-                far_terms = jnp.sum(closed_stack, axis=0)
-                far_mean = jnp.sum(
-                    jnp.where(real_b, far_terms, 0)).astype(jnp.float32) / n_real_b
-                stats.update({
-                    "cells_per_level": jnp.sum(
-                        jnp.where(real_b[None, :], closed_stack, 0), axis=1
-                    ).astype(jnp.float32) / n_real_b,
-                    "opened_fraction": jnp.sum(
-                        jnp.where(real_b, opened_tot, 0)).astype(jnp.float32)
-                    / jnp.maximum(jnp.sum(jnp.where(real_b, proc_tot, 0)), 1
-                                  ).astype(jnp.float32),
-                    "quadtree_rtol_bound": plan.farfield_bound,
-                })
-            else:
-                far_mean = jnp.sum(
-                    jnp.where(real_b, grid.n_cells - rect_cells, 0)
-                ).astype(jnp.float32) / n_real_b
-                stats["farfield_rtol_bound"] = plan.farfield_bound
+            # far work per block is the number of CLOSED nodes summed over
+            # levels — the ~O(log m) quantity
+            closed_stack = jnp.stack(closed_counts)           # (n_levels, nb)
+            far_terms = jnp.sum(closed_stack, axis=0)
+            far_mean = jnp.sum(
+                jnp.where(real_b, far_terms, 0)).astype(jnp.float32) / n_real_b
             stats.update({
+                "cells_per_level": jnp.sum(
+                    jnp.where(real_b[None, :], closed_stack, 0), axis=1
+                ).astype(jnp.float32) / n_real_b,
+                "opened_fraction": jnp.sum(
+                    jnp.where(real_b, opened_tot, 0)).astype(jnp.float32)
+                / jnp.maximum(jnp.sum(jnp.where(real_b, proc_tot, 0)), 1
+                              ).astype(jnp.float32),
+                "quadtree_rtol_bound": plan.farfield_bound,
                 "near_points_mean": jnp.sum(
                     jnp.where(real_b, need2, 0)).astype(jnp.float32) / n_real_b,
                 "far_cells_mean": far_mean,
@@ -726,21 +660,19 @@ def execute_with_stats(plan: InterpolationPlan, qx, qy):
     now persisted for ``PERSISTENT_OVERFLOW_BATCHES`` consecutive diagnostic
     batches against this plan object; a RuntimeWarning suggesting a re-plan
     fires when the streak is first reached).  ``grid`` with
-    ``phase2="farfield"`` additionally reports ``near_points_mean`` /
-    ``far_cells_mean`` (per real query block), the plan's proved
-    ``farfield_rtol_bound``, ``p2_overflow_queries`` (queries routed to
-    the exact Phase-2 sweep because their block's near field overflowed),
+    ``phase2="quadtree"`` additionally reports ``near_points_mean`` (per
+    real query block), ``p2_overflow_queries`` (queries routed to the
+    exact Phase-2 sweep because their block's near field overflowed),
     ``p2_overflow_query_mask`` (bool ``(n,)``, caller order — which
-    queries the masked exact sweep answered) and ``p2_need_max`` (the
-    largest near-field point count of a real block — what a re-plan sizes
-    ``p2_capacity`` from).
-    ``grid`` with ``phase2="quadtree"`` reports the same near/overflow keys
-    plus ``far_cells_mean`` (mean CLOSED nodes per real block, summed over
-    levels — the ~O(log m) quantity), ``cells_per_level`` (its per-level
-    split, shape ``(n_levels,)``), ``opened_fraction`` (opened / processed
-    nonempty nodes — how much of the tree the walk descends) and the
-    plan's proved ``quadtree_rtol_bound``; the dict structure is static per
-    plan (the level count is a plan static).
+    queries the masked exact sweep answered), ``p2_need_max`` (the largest
+    near-field point count of a real block — what a re-plan sizes
+    ``p2_capacity`` from), ``far_cells_mean`` (mean CLOSED nodes per real
+    block, summed over levels — the ~O(log m) quantity),
+    ``cells_per_level`` (its per-level split, shape ``(n_levels,)``),
+    ``opened_fraction`` (opened / processed nonempty nodes — how much of
+    the tree the walk descends) and the plan's proved
+    ``quadtree_rtol_bound``; the dict structure is static per plan (the
+    level count is a plan static).
     ``tiled_v2``: the measured ``merge_fraction``.
     The computation is jitted with a static dict structure per plan (no
     retrace across same-shape batches); only the streak bookkeeping runs on
@@ -768,8 +700,7 @@ execute_with_stats._cache_size = _execute_with_stats_jit._cache_size
 def exact_arm_mask(stats):
     """``(n,)`` bool, caller order: the queries of one ``execute_with_stats``
     call that an exact fallback arm answered — the ring search (Phase-1
-    overflow) or, on far-field and quadtree plans, the masked exact
-    Phase-2 sweep."""
+    overflow) or, on quadtree plans, the masked exact Phase-2 sweep."""
     mask = stats["overflow_query_mask"]
     if "p2_overflow_query_mask" in stats:
         mask = mask | stats["p2_overflow_query_mask"]

@@ -37,7 +37,6 @@ from repro.core.grid import (
     DEFAULT_OCCUPANCY,
     UniformGrid,
     build_grid,
-    cell_aggregates,
     quadtree_aggregates,
     required_radius_table,
     static_cell_radius,
@@ -70,8 +69,9 @@ _FALLBACK_BOUND_CEIL = 0.5
 # pairs/s at 4,096 points, 2.50-2.74e11 at 2,048, 1.18e11 at 512; PERF.md §6).
 _SWEEP_TILE = 4096
 
-# Per-tile element budget for the Phase-2 near/far sweeps: block_q * tile_d
-# capped so the in-kernel (block_q, tile_d) f32 distance tile stays ~1 MiB.
+# Per-tile element budget for the quadtree's gathered near field and level
+# sweeps: block_q * tile_d capped so the in-kernel (block_q, tile_d) f32
+# distance tile stays ~1 MiB.
 _P2_TILE_ELEMS = 64 * 4096
 
 # Row-run Phase 1 (DESIGN.md §6): the CSR tile widths it may walk, and a
@@ -133,14 +133,13 @@ class InterpolationPlan:
     grid_rebuilds: int        # grid: coarsening rebuilds during planning
     seam_level: int           # grid: Morton quadrant split depth (0 = off)
     pipeline: str             # grid Phase 1: "prefetch" (row runs) | "dense"
-    phase2: str               # grid Phase 2: "exact" (full sweep) | "farfield"
-    farfield_rtol: float      # farfield: user-requested relative error target
-    farfield_radius: int      # far field/quadtree: near-field radius (cells)
-    farfield_bound: float     # far field/quadtree: proved worst-case rel error
-    p2_capacity: int          # farfield: static near-field candidate width
-    p2_block_d: int           # farfield/quadtree: near-field sweep tile (the
-                              # quadtree's row-run tile on "prefetch")
-    p2_far_block_d: int       # farfield: far cell-aggregate sweep tile
+    phase2: str               # grid Phase 2: "exact" (full sweep) | "quadtree"
+    farfield_rtol: float      # quadtree: user-requested relative error target
+    farfield_radius: int      # quadtree: near-field radius (cells)
+    farfield_bound: float     # quadtree: proved worst-case rel error
+    p2_capacity: int          # quadtree: static near-field point capacity
+    p2_block_d: int           # quadtree: near-field sweep tile (the row-run
+                              # tile on "prefetch")
     qt_tau: float             # quadtree: effective opening ratio tau_eff
     qt_levels: tuple          # quadtree: per-level (nx, ny, step, n_pad, tile)
     row_tile: int             # grid Phase 1: CSR tile of the row-run walk
@@ -148,8 +147,7 @@ class InterpolationPlan:
     data: tuple               # impl-specific padded arrays
     grid: UniformGrid | None
     r_need: jnp.ndarray | None  # (gy, gx) int32 per-cell required_radius
-    far: tuple                # farfield: padded (1, ncp) cell-aggregate arrays
-                              # quadtree: per-level node-aggregate tuples
+    far: tuple                # quadtree: per-level node-aggregate tuples
     row_cells: jnp.ndarray | None  # grid "prefetch": packed cell per CSR point
 
     def tree_flatten(self):
@@ -159,7 +157,7 @@ class InterpolationPlan:
                self.cand_capacity, self.cand_block_d, self.grid_rebuilds,
                self.seam_level, self.pipeline, self.phase2,
                self.farfield_rtol, self.farfield_radius, self.farfield_bound,
-               self.p2_capacity, self.p2_block_d, self.p2_far_block_d,
+               self.p2_capacity, self.p2_block_d,
                self.qt_tau, self.qt_levels, self.row_tile)
         return (self.data, self.grid, self.r_need, self.far, self.row_cells), aux
 
@@ -186,7 +184,7 @@ def _choose_candidate_capacity(grid: UniformGrid, r_need, block_q: int, m: int,
     ring-search fallback instead of losing neighbours.
 
     Returns ``(capacity, r_static, window, side)`` — all concrete ints
-    (``side`` is reused to size the farfield near-field capacity with the
+    (``side`` is reused to size the quadtree near-field capacity with the
     same block-bbox model).
     """
     r_cell = static_cell_radius(grid, r_need)
@@ -222,71 +220,26 @@ def _densest_window_count(grid: UniformGrid, window: int) -> int:
     return max(int(jnp.max(sums)), 1)
 
 
-def _farfield_bound_model(radius: int, cell_min: float, a_max: float,
-                          e_max: float, z_dev_max: float, z_abs_max: float):
-    """Worst-case relative error of the far-field Phase 2 at a given
-    near-field radius — the provable half of the error budget (DESIGN.md §7).
+def _bound_from_tau(tau: float, a_max: float):
+    """Worst-case relative error of the quadtree far field at opening ratio
+    ``tau`` (DESIGN.md §8), for ``A = a_max = max(alpha_levels)``.
 
-    Geometry (the ring-search invariant, which survives out-of-bbox queries):
-    every far cell — Chebyshev cell-distance ``>= radius + 1`` from the
-    query's clamped home cell — has all its points, and therefore its
-    centroid, at Euclidean distance ``d_c >= radius * cell_min``, with every
-    point within ``e_max`` of the centroid.  Let ``tau = e_max / (radius *
-    cell_min)`` (>= the per-cell dispersion ratio of every far cell) and
-    ``A = max(alpha_levels)`` (every per-weight term below increases with
-    alpha).
-
-    Because the centroid zeroes the first moment of the cell's points, the
-    count term ``n_c * w(d_c)`` matches ``sum_j w_j`` to SECOND order in the
-    dispersion: Taylor with the Lagrange Hessian of ``w(p) = |q - p|^-a``
-    (largest eigenvalue ``a*(a+1)*d^-a-2``, evaluated no closer than
-    ``d_c - e``) gives
-
-        |n w(d_c) - sum w_j| <= eps2 * n * w(d_c),
-        eps2 = 0.5 * A * (A+1) * tau^2 * (1 - tau)^-(A+2).
-
-    The z-sum term ``w(d_c) * S_c`` additionally pays a FIRST-order price
-    for z varying inside the cell: splitting ``z_j = zbar_c + dz_j``,
-
-        |w(d_c) S_c - sum w_j z_j| <= (|zbar_c| eps2 + eta * z_dev_max) * n * w(d_c),
-        eta = (1 - tau)^-A - 1   (per-point weight spread at dispersion tau).
-
-    With ``sum_cell w_j >= n w(d_c) (1+tau)^-A``, the exact interpolant a
-    convex combination of data z (``|z| <= s = z_abs_max``), and the
-    perturbed denominator ``>= (1 - eps2h) * D``:
-
-        |z_ff - z| / s <= (2*eps2 + eta * z_dev_max/s) * (1+tau)^A / (1 - eps2h),
-        eps2h = eps2 * (1+tau)^A.
-
-    Returns ``inf`` when ``tau >= 1`` or ``eps2h >= 1`` (radius too small for
-    any guarantee).  ``z_dev_max = 0`` (constant z per cell — e.g. one point
-    per cell) collapses the model to the pure second-order term, and
-    ``e_max = 0`` to exactly 0.
-    """
-    if radius <= 0:
-        return math.inf
-    tau = e_max / (radius * cell_min) if cell_min > 0 else math.inf
-    g = z_dev_max / z_abs_max if z_abs_max > 0 else 0.0
-    return _bound_from_tau(tau, a_max, g)
-
-
-def _bound_from_tau(tau: float, a_max: float, g: float = 0.0,
-                    dipole: bool = False):
-    """The (tau, alpha) -> worst-case-relative-error core of the far-field
-    models, shared by the single-level model above and the quadtree model
-    (DESIGN.md §7-8).
-
-    ``dipole=False`` is the PR-5 single-level budget: second-order count term
-    plus the FIRST-order ``eta * g`` z-spread term (``g = z_dev_max /
-    z_abs_max``).  ``dipole=True`` is the quadtree budget: the kernel adds
-    the stored first z-moment term ``grad w(cent) . M``, which cancels the
-    z budget's first-order piece exactly (the count term's first order
-    already cancels because the centroid zeroes the first position moment),
-    so BOTH terms are second-order in tau:
+    A closed node of ``n`` points within ``e`` of their centroid, at
+    distance ``d >= e / tau`` from the query, stands in for their weights
+    with ``n * w(d)``.  The centroid zeroes the first position moment, so
+    Taylor with the Lagrange Hessian of ``w(p) = |q - p|^-A`` (largest
+    eigenvalue ``A*(A+1)*d^-A-2``, evaluated no closer than ``d - e``)
+    leaves a second-order error, ``eps2 = 0.5 * A * (A+1) * tau^2 *
+    (1 - tau)^-(A+2)`` of ``n * w(d)``.  The kernel adds the stored first
+    z-moment term ``grad w(cent) . M``, which cancels the z-sum term's
+    first-order piece in the same way, so both sums are second-order:
 
         |N_hat - N| <= eps2 * n * w(d) * z_abs_max,
         |D_hat - D| <= eps2 * n * w(d),
-        bound = 2 * eps2 * (1+tau)^A / (1 - eps2 * (1+tau)^A).
+        bound = 2 * eps2 * (1+tau)^A / (1 - eps2 * (1+tau)^A),
+
+    with ``sum_node w_j >= n w(d) (1+tau)^-A`` and the exact interpolant a
+    convex combination of data z.
 
     Monotone non-increasing as tau shrinks (the property the hypothesis
     test pins); ``inf`` when no guarantee exists at this tau.
@@ -298,97 +251,22 @@ def _bound_from_tau(tau: float, a_max: float, g: float = 0.0,
     eps2h = eps2 * grow
     if eps2h >= 1.0:
         return math.inf
-    if dipole:
-        return 2.0 * eps2 * grow / (1.0 - eps2h)
-    eta = (1.0 - tau) ** (-a_max) - 1.0
-    return (2.0 * eps2 + eta * g) * grow / (1.0 - eps2h)
-
-
-def _bound_at_radius(grid: UniformGrid, params: AIDWParams, agg, radius: int):
-    """Proved worst-case bound at a given near radius — the ONE source of
-    truth shared by the auto chooser and the ``farfield_radius=`` override.
-    A radius >= max(gx, gy) makes every near rectangle span the whole grid
-    (the far set is empty), so the bound is exactly 0 there."""
-    if radius >= max(grid.gx, grid.gy):
-        return 0.0
-    cell_min = float(jnp.minimum(grid.cell_size[0], grid.cell_size[1]))
-    return _farfield_bound_model(radius, cell_min, float(max(params.alpha_levels)),
-                                 agg.e_max, agg.z_dev_max, agg.z_abs_max)
-
-
-def _choose_farfield_radius(grid: UniformGrid, params: AIDWParams,
-                            farfield_rtol: float, agg, *, side: int, m: int):
-    """Near-field radius from the worst-case error model + a cost cap.
-
-    Returns ``(radius, bound)`` — concrete int/float.  Picks the smallest
-    radius whose :func:`_farfield_bound_model` value meets ``farfield_rtol``,
-    subject to a profitability cap: the modeled Phase-2 work (near window
-    occupancy + one term per cell) must stay under ``m / 4``, else the
-    far-field split would not beat the exact m-point sweep it replaces.  If
-    the target is not provable under the cap — the common case for tight
-    rtols, since a single-level aggregate's worst-case bound is second-order
-    in (cell dispersion / near distance) and the worst query sits right at
-    the near boundary — the cap radius is used and a warning reports the
-    honest bound; measured error (``core.accuracy.farfield_error_report``)
-    is typically orders of magnitude below it.  A radius beyond
-    ``max(gx, gy)`` would make every near rectangle span the whole grid
-    (the far set is empty and the "approximation" is the exact sweep with
-    gather overhead), so radii are also clamped there, with bound 0.
-    """
-    cover = max(grid.gx, grid.gy)
-    occ_mean = max(m / max(grid.n_cells, 1), 1.0)
-
-    def modeled_cost(radius):
-        window = min(side + 2 * radius + 1, cover)
-        return window * window * occ_mean + grid.n_cells
-
-    def bound_at(radius):
-        return _bound_at_radius(grid, params, agg, radius)
-
-    r_cap = 1
-    while r_cap + 1 < cover and modeled_cost(r_cap + 1) <= m / 4:
-        r_cap += 1
-    for radius in range(1, r_cap + 1):
-        bound = bound_at(radius)
-        if bound <= farfield_rtol:
-            return radius, bound
-    # Not provable under the cap.  Fall back to the CHEAPEST radius whose
-    # bound is at least non-vacuous (a relative-error promise above ~0.5 of
-    # the data scale guarantees nothing useful, and larger radii buy only
-    # marginally tighter worst cases at near-linear extra cost); r_cap if
-    # even that is out of reach.
-    radius = r_cap
-    for r in range(1, r_cap + 1):
-        if bound_at(r) <= _FALLBACK_BOUND_CEIL:
-            radius = r
-            break
-    bound = bound_at(radius)
-    warnings.warn(
-        f"farfield_rtol={farfield_rtol:g} is not provable within the "
-        f"profitable near-field budget (radius <= {r_cap} of a "
-        f"{grid.gx}x{grid.gy} grid); using radius {radius} with worst-case "
-        f"bound {bound:.3g}. Measured error is typically far below the "
-        "bound — check farfield_error_report, or pass farfield_radius= / a "
-        "coarser grid to trade speed for guarantee.",
-        UnprovableRtolWarning,
-        stacklevel=4,
-    )
-    return radius, bound
+    return 2.0 * eps2 * grow / (1.0 - eps2h)
 
 
 def _quadtree_tau_required(a_max: float, rtol: float) -> float:
     """Largest opening ratio tau whose dipole bound still proves ``rtol`` —
     bisection on the monotone :func:`_bound_from_tau` (60 steps ~ 1 ulp).
     To leading order ``tau_req ~ sqrt(rtol / (2 * a * (a+1)))``; at a = 4,
-    rtol = 1e-3 that is ~7e-3 — an opening angle coarse data can actually
-    meet, unlike the first-order single-level budget."""
+    rtol = 1e-3 that is ~7e-3 — an opening angle sub-cell-clustered data
+    can actually meet."""
     hi = 0.5
-    if _bound_from_tau(hi, a_max, dipole=True) <= rtol:
+    if _bound_from_tau(hi, a_max) <= rtol:
         return hi
     lo = 0.0
     for _ in range(60):
         mid = 0.5 * (lo + hi)
-        if _bound_from_tau(mid, a_max, dipole=True) <= rtol:
+        if _bound_from_tau(mid, a_max) <= rtol:
             lo = mid
         else:
             hi = mid
@@ -397,23 +275,31 @@ def _quadtree_tau_required(a_max: float, rtol: float) -> float:
 
 def _choose_quadtree_radius(grid: UniformGrid, params: AIDWParams,
                             farfield_rtol: float, e0_max: float, *,
-                            side: int, m: int):
+                            side: int, m: int, radius: int | None = None):
     """Near-field radius + effective opening ratio for the quadtree arm.
 
-    Returns ``(radius, tau_eff, bound)``.  The walk closes a node only when
-    its own dispersion ``e`` fits ``tau_eff * (gap-1) * cell_min`` — EXCEPT
-    level-0 cells, which cannot be opened further and are force-closed
+    Returns ``(radius, tau_eff, bound)``; a given ``radius`` (the user's
+    ``farfield_radius=``, clamped to ``[1, max(gx, gy)]``) is kept as it is
+    and only its ``tau_eff`` and bound are computed.
+
+    The walk closes a node only when its own dispersion ``e`` fits
+    ``tau_eff * (gap-1) * cell_min`` — EXCEPT level-0 cells, which cannot
+    be opened further and are force-closed
     whenever their gap clears ``radius + 1``.  ``tau_eff`` therefore must
     also cover the worst level-0 cell at the near boundary:
 
         tau_eff = max(tau_req, e0_max / (radius * cell_min)).
 
-    The smallest radius under the Phase-2 profitability cap whose
-    ``_bound_from_tau(tau_eff, dipole=True)`` meets ``farfield_rtol`` wins;
-    when even the cap radius cannot prove the target (cell dispersion too
-    coarse — e.g. uniform data, where e0 ~ 0.7 * cell) the fallback mirrors
-    :func:`_choose_farfield_radius`: cheapest non-vacuous radius + a warning
-    with the honest bound.
+    The smallest radius under the Phase-2 profitability cap (the modeled
+    near-field work, window occupancy, stays under ``m / 4``, else the split
+    would not beat the exact m-point sweep it replaces) whose
+    ``_bound_from_tau(tau_eff)`` meets ``farfield_rtol`` wins.  When even
+    the cap radius cannot prove the target (cell dispersion too coarse —
+    e.g. uniform data, where e0 ~ 0.7 * cell) the plan takes the cheapest
+    radius whose bound is at most ``_FALLBACK_BOUND_CEIL`` (the cap radius
+    if none is) and warns with that honest bound.  A radius ``>= max(gx,
+    gy)`` makes every near rectangle span the whole grid: the far set is
+    empty and the bound 0.
     """
     a_max = float(max(params.alpha_levels))
     cell_min = float(jnp.minimum(grid.cell_size[0], grid.cell_size[1]))
@@ -427,7 +313,11 @@ def _choose_quadtree_radius(grid: UniformGrid, params: AIDWParams,
         if cell_min <= 0:
             return math.inf, math.inf
         tau_eff = max(tau_req, e0_max / (radius * cell_min))
-        return tau_eff, _bound_from_tau(tau_eff, a_max, dipole=True)
+        return tau_eff, _bound_from_tau(tau_eff, a_max)
+
+    if radius is not None:
+        radius = max(1, min(int(radius), cover))
+        return (radius, *at_radius(radius))
 
     def modeled_cost(radius):
         window = min(side + 2 * radius + 1, cover)
@@ -556,7 +446,7 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
         seam_level = _choose_seam_level(grid, window)
 
     # Phase-2 full-data sweep: sentinel-pad to its own tile multiple (kept on
-    # farfield plans too — it is the exact arm of the overflow fallback)
+    # quadtree plans too — it is the exact arm of the overflow fallback)
     bd2 = min(_SWEEP_TILE, max(128, _round_up(m, 128)))
     big = coord_sentinel(dtype)
     data = (
@@ -566,30 +456,19 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
     )
 
     ff = dict(farfield_radius=0, farfield_bound=0.0, p2_capacity=0,
-              p2_block_d=0, p2_far_block_d=0, qt_tau=0.0, qt_levels=(),
-              far=())
+              p2_block_d=0, qt_tau=0.0, qt_levels=(), far=())
     if phase2 == "quadtree":
         qt = quadtree_aggregates(grid)
-        cell_min = float(jnp.minimum(grid.cell_size[0], grid.cell_size[1]))
-        a_max = float(max(params.alpha_levels))
-        if farfield_radius is not None:  # user override: radius as given
-            radius = max(1, min(int(farfield_radius), max(grid.gx, grid.gy)))
-            tau_req = _quadtree_tau_required(a_max, farfield_rtol)
-            if radius >= max(grid.gx, grid.gy):
-                tau_eff, bound = tau_req, 0.0
-            elif cell_min > 0:
-                tau_eff = max(tau_req, qt[0].e_max / (radius * cell_min))
-                bound = _bound_from_tau(tau_eff, a_max, dipole=True)
-            else:
-                tau_eff, bound = math.inf, math.inf
-        else:
-            radius, tau_eff, bound = _choose_quadtree_radius(
-                grid, params, farfield_rtol, qt[0].e_max, side=side, m=m
-            )
-        # near-field capacity: the single-level arm's densest-window model.
+        radius, tau_eff, bound = _choose_quadtree_radius(
+            grid, params, farfield_rtol, qt[0].e_max, side=side, m=m,
+            radius=farfield_radius,
+        )
+        # near-field capacity: Phase 1's densest-window model, with the
+        # block's home bbox expanded by the near radius instead of r_safe.
         # The row-run near field walks CSR tiles of the row-run rule's
         # width for its own (near-rectangle-wide) runs; the dense pipeline
-        # keeps the single-level arm's gathered-tile autotune
+        # gathers the near field and sweeps it in the widest tile that keeps
+        # the (block_q x tile) distance tile within _P2_TILE_ELEMS
         window2 = min(side + 2 * radius + 1, max(grid.gx, grid.gy))
         cap2 = min(_densest_window_count(grid, window2), m)
         if min_p2_capacity is not None:
@@ -619,44 +498,8 @@ def _plan_grid(dx, dy, dz, *, params, block_q, block_d, grid, target_occupancy,
         )
         ff = dict(farfield_radius=radius, farfield_bound=float(bound),
                   p2_capacity=p2_capacity, p2_block_d=p2_block_d,
-                  p2_far_block_d=0, qt_tau=float(tau_eff),
+                  qt_tau=float(tau_eff),
                   qt_levels=qt_levels, far=far)
-    if phase2 == "farfield":
-        agg = cell_aggregates(grid)
-        if farfield_radius is not None:  # user override: radius as given
-            radius = max(1, min(int(farfield_radius), max(grid.gx, grid.gy)))
-            bound = _bound_at_radius(grid, params, agg, radius)
-        else:
-            radius, bound = _choose_farfield_radius(
-                grid, params, farfield_rtol, agg, side=side, m=m
-            )
-        # near-field capacity: same densest-window model as Phase 1, with the
-        # block's home bbox expanded by the near radius instead of r_safe
-        window2 = min(side + 2 * radius + 1, max(grid.gx, grid.gy))
-        cap2 = min(_densest_window_count(grid, window2), m)
-        if min_p2_capacity is not None:
-            cap2 = min(max(cap2, int(min_p2_capacity)), m)
-        # Phase-2 tiles are autotuned independently of block_d: the near row
-        # is narrow (<= capacity, vs m for the full sweep), so the widest
-        # tile that keeps the (block_q x tile) distance/weight tile within a
-        # ~1 MiB VMEM budget covers it in the fewest grid steps — per-step
-        # overhead, not FLOPs, dominates both interpret mode and short grids
-        tile_cap = max(512, _round_up(_P2_TILE_ELEMS // block_q, 128))
-        p2_block_d = min(tile_cap, max(128, _round_up(cap2, 128)))
-        p2_capacity = _round_up(cap2, p2_block_d)
-        far_bd = min(tile_cap, _round_up(grid.n_cells, 128))
-        zero = jnp.zeros((), dtype)
-        far = (
-            pad_to(agg.cent_x, far_bd, big)[None, :],
-            pad_to(agg.cent_y, far_bd, big)[None, :],
-            pad_to(agg.count, far_bd, zero)[None, :],
-            pad_to(agg.z_sum, far_bd, zero)[None, :],
-            pad_to(agg.ix, far_bd, jnp.asarray(-1, jnp.int32))[None, :],
-            pad_to(agg.iy, far_bd, jnp.asarray(-1, jnp.int32))[None, :],
-        )
-        ff = dict(farfield_radius=radius, farfield_bound=float(bound),
-                  p2_capacity=p2_capacity, p2_block_d=p2_block_d,
-                  p2_far_block_d=far_bd, far=far)
 
     return dict(block_d=bd2, cand_capacity=cand_capacity, cand_block_d=cand_block_d,
                 grid_rebuilds=rebuilds, seam_level=int(seam_level),
@@ -716,30 +559,25 @@ def build_plan(
     gathered to the static capacity and every block walks all of it; the
     oracle the default is tested against, bit-identical results).
     ``phase2`` (grid impl) selects the Phase-2 sweep: "exact" (default; the
-    full m-point weighted sweep, bit-identical to every prior release),
-    "farfield" (exact per-point weights only inside a plan-chosen near-field
-    radius, one aggregate term per far cell beyond it — the first
-    *approximating* path; its worst-case relative error, proved by the
-    model in :func:`_choose_farfield_radius` and enforced by
-    ``tests/engine/test_farfield.py``, is reported as
-    ``plan.farfield_bound``.  The bound meets ``farfield_rtol`` when that
-    is provable at a profitable radius; otherwise the plan WARNS and
-    ``farfield_bound`` is the honest, larger worst case — always check it
-    rather than assuming the request was met), or "quadtree" (DESIGN.md §8:
-    the far field is walked as a Barnes–Hut quadtree of cell aggregates,
-    coarse levels closed wherever the per-node opening criterion holds and
-    a dipole z-moment term added per closed node, making BOTH error terms
-    second-order in the opening ratio — per-query far work drops to
-    ~O(log m) and rtol=1e-3 becomes provable wherever data clusters below
-    the cell scale; same near-field machinery, same ``farfield_bound``
-    reporting contract as "farfield").
+    full m-point weighted sweep, bit-identical to every prior release) or
+    "quadtree" (DESIGN.md §8, the approximating arm: exact per-point
+    weights inside a plan-chosen near-field radius, and beyond it a
+    Barnes–Hut walk over a quadtree of cell aggregates, coarse levels
+    closed wherever the per-node opening criterion holds and a dipole
+    z-moment term added per closed node, making BOTH error terms
+    second-order in the opening ratio — per-query far work is ~O(log m)
+    and rtol=1e-3 is provable wherever data clusters below the cell
+    scale).  Its worst-case relative error, proved by the model in
+    :func:`_choose_quadtree_radius`, is reported as
+    ``plan.farfield_bound``.
     ``farfield_rtol`` is the requested relative-error ceiling, measured
     against ``max|z_data|`` (see ``core.accuracy.farfield_error_report``);
-    when it is not provable at a profitable radius the plan warns and
-    reports the honest (larger) bound.  ``farfield_radius`` overrides the
-    model's radius choice directly (the bound is still computed and
-    reported for the chosen radius — possibly ``inf`` for radii too small
-    to prove anything).
+    when it is not provable at a profitable radius the plan WARNS and
+    ``farfield_bound`` is the honest, larger worst case — always check it
+    rather than assuming the request was met.  ``farfield_radius``
+    overrides the model's radius choice directly (the bound is still
+    computed and reported for the chosen radius — possibly ``inf`` for
+    radii too small to prove anything).
     ``min_cand_capacity`` / ``min_p2_capacity`` (grid impl) floor the
     occupancy-model capacities, clamped to ``m`` — the capacity
     re-estimator's re-plan knob (see :func:`replan_with_capacity`).
@@ -765,10 +603,9 @@ def build_plan(
         raise ValueError(f"pipeline must be 'prefetch' or 'dense', got {pipeline!r}")
     if seam_level is not None and not (0 <= int(seam_level) <= 8):
         raise ValueError(f"seam_level must be in [0, 8], got {seam_level!r}")
-    if phase2 not in ("exact", "farfield", "quadtree"):
-        raise ValueError(f"phase2 must be 'exact', 'farfield' or 'quadtree', "
-                         f"got {phase2!r}")
-    if phase2 in ("farfield", "quadtree") and impl != "grid":
+    if phase2 not in ("exact", "quadtree"):
+        raise ValueError(f"phase2 must be 'exact' or 'quadtree', got {phase2!r}")
+    if phase2 == "quadtree" and impl != "grid":
         raise ValueError(f"phase2={phase2!r} requires impl='grid' (the cell "
                          "aggregates live on the grid snapshot)")
     if not float(farfield_rtol) > 0.0:
@@ -817,7 +654,7 @@ def build_plan(
         seam_level=0, pipeline=pipeline,
         phase2=phase2, farfield_rtol=float(farfield_rtol),
         farfield_radius=0, farfield_bound=0.0,
-        p2_capacity=0, p2_block_d=0, p2_far_block_d=0,
+        p2_capacity=0, p2_block_d=0,
         qt_tau=0.0, qt_levels=(), row_tile=0,
         data=(), grid=None, r_need=None, far=(), row_cells=None,
     )
@@ -865,8 +702,9 @@ def replan_with_capacity(
     data arrays are recovered from the plan's padded copies, the grid
     snapshot is REUSED (no rebuild — the data didn't change, the capacity
     model did), and the statics (params/area/blocks/seam/pipeline/phase2
-    and the far-field knobs, including an explicit-radius carry-over so the
-    radius cannot drift between old and new plan) are passed through.  The
+    and the quadtree's ``farfield_*`` knobs, including an explicit-radius
+    carry-over so the radius cannot drift between old and new plan) are
+    passed through.  The
     result serves the same queries with the same exactness contract; only
     the static candidate widths (and their derived tile sizes) grow.
     """

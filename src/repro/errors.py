@@ -11,9 +11,10 @@ assertions against the stdlib categories keep working.
 Hierarchy::
 
     ReproWarning
-    ├── UnprovableRtolWarning      (UserWarning)     plan: requested farfield_rtol
-    │                                                not provable at a profitable
-    │                                                radius; honest bound reported
+    ├── UnprovableRtolWarning      (UserWarning)     plan: a quadtree plan's
+    │                                                farfield_rtol not provable at
+    │                                                a profitable radius; honest
+    │                                                bound reported
     ├── PathologicalGridWarning    (UserWarning)     plan: grid resolution leaves
     │                                                candidate rows near a full sweep
     ├── CapacityOverflowWarning    (RuntimeWarning)  execute: overflow_queries > 0
@@ -35,8 +36,9 @@ class ReproWarning(Warning):
 
 
 class UnprovableRtolWarning(ReproWarning, UserWarning):
-    """The requested ``farfield_rtol`` is not provable at a profitable
-    near-field radius; the plan ships the honest (larger) worst-case bound."""
+    """The requested ``farfield_rtol`` of a ``phase2="quadtree"`` plan is not
+    provable at a profitable near-field radius; the plan ships the honest
+    (larger) worst-case bound."""
 
 
 class PathologicalGridWarning(ReproWarning, UserWarning):

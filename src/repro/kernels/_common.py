@@ -51,7 +51,7 @@ def pow_weight(d2, alpha_half):
     """The AIDW weight ``(d^2)^(-alpha/2) = d^(-alpha)`` from a squared
     distance, with the dtype-dependent tiny clamp (exact hits are handled by
     the callers' min-d² guard; sentinel distances overflow to +inf and yield
-    weight 0).  The ONE kernel-side definition — the far-field aggregate arm
+    weight 0).  The ONE kernel-side definition — the quadtree's far-node sweep
     must weigh centroids exactly as the near/full sweeps weigh points, or
     the proved error budget silently breaks."""
     dtype = d2.dtype

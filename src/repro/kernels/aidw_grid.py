@@ -36,33 +36,25 @@ of ``(snapshot arrays, queries, static capacity)`` and runs under ``jax.jit``:
 * :func:`phase2_weights_full` — exact Phase 2 (the default): AIDW weights
   ALL m data points, so the full-data sweep (``_weight_kernel_soa``) is
   reused verbatim.
-* :func:`phase2_near_weights` + :func:`phase2_far_aggregates` — the
-  far-field approximated Phase 2 (``build_plan(phase2="farfield")``,
-  DESIGN.md §7).  The near kernel sweeps exact per-point weights over the
-  block's near-rectangle candidate rows (the materialised CSR gather,
-  with a scalar-prefetched per-block tile count — sparse blocks skip their
-  all-sentinel tail tiles) and returns the four partial accumulators
-  ``(sum_w, sum_wz, min_d2, hit_z)`` instead of a finished z.  The far
-  kernel sweeps the plan's per-cell aggregates (count, z-sum, centroid)
-  once per cell, masking cells inside the block's scalar-prefetched near
-  rectangle (those are covered exactly), and folds ``count*w(centroid)`` /
-  ``z_sum*w(centroid)`` into ``(sum_w, sum_wz)``.  The engine combines the
-  two and applies the exact-hit guard; the worst-case relative error is
-  bounded at plan time (``engine.plan._choose_farfield_radius``).
-* :func:`phase2_near_row_runs` — the quadtree arm's near field on the
-  default ``pipeline="prefetch"`` (DESIGN.md §8): the same accumulators as
-  :func:`phase2_near_weights`, read from the CSR row runs of each block's
-  near rectangle in place, by the Phase-1 row-run walk (the near
-  rectangle is far too wide to gather).
-* :func:`phase2_far_nodes` — the multi-level quadtree far field
-  (``build_plan(phase2="quadtree")``, DESIGN.md §8): the near field above
-  (or, on ``pipeline="dense"``, the gathered near kernel), while the far
+* :func:`phase2_near_row_runs` — the quadtree arm's near field
+  (``build_plan(phase2="quadtree")``, DESIGN.md §8) on the default
+  ``pipeline="prefetch"``: exact per-point weights over the CSR row runs
+  of each block's near rectangle, read in place by the Phase-1 row-run
+  walk (the near rectangle is far too wide to gather), returned as the
+  four partial accumulators ``(sum_w, sum_wz, min_d2, hit_z)`` instead of
+  a finished z.  :func:`phase2_near_weights` is the ``pipeline="dense"``
+  oracle: the same accumulators over the materialised CSR gather, with a
+  scalar-prefetched per-block tile count (sparse blocks skip their
+  all-sentinel tail tiles).
+* :func:`phase2_far_nodes` — the multi-level quadtree far field: the
   sweep runs once per quadtree LEVEL over the level's nodes, those a
   block does not close (by the engine's Barnes–Hut walk) masked to the
-  sentinel node, each closed node
-  contributing its aggregate term plus a dipole z-moment correction — the
-  piece that cancels the z budget's first-order error and makes the plan's
-  bound second-order in the opening ratio.
+  sentinel node, each closed node contributing its aggregate term plus a
+  dipole z-moment correction — the piece that cancels the z budget's
+  first-order error and makes the plan's bound second-order in the
+  opening ratio.  The engine adds the levels to the near accumulators and
+  applies the exact-hit guard; the worst-case relative error is bounded at
+  plan time (``engine.plan._choose_quadtree_radius``).
 
 Morton sorting, seam splitting, padding, the per-block overflow blend, the
 quadtree level walk and the unsort live in ``repro.engine.execute``; this
@@ -133,7 +125,7 @@ def gather_candidates_csr(grid: UniformGrid, xlo, xhi, ylo, yhi, capacity: int,
     this gather is incomplete and the caller must use the exact fallback.
     ``with_z=True`` additionally gathers the attribute rows (sentinel slot
     z = 0, i.e. weightless) and returns ``(cand_x, cand_y, cand_z, need)`` —
-    the far-field Phase 2 needs the z values of its near field.
+    the quadtree's dense near field needs the z values of its points.
     """
     nb = xlo.shape[0]
     gx, gy = grid.gx, grid.gy
@@ -260,10 +252,6 @@ def _row_tiles(rows):
 
 def _row_spec(block_d: int, index_map):
     return pl.BlockSpec((None, 1, block_d), index_map)
-
-
-def _pf_shared_tile_map(i, j, _scalar):
-    return (0, j)
 
 
 def phase1_alpha_from_candidates(
@@ -440,12 +428,12 @@ def phase1_alpha_row_runs(
 
 def _near_weight_kernel(nt_ref, qx_ref, qy_ref, ah_ref, dx_ref, dy_ref, dz_ref,
                         sw_ref, swz_ref, md_ref, hz_ref, *acc):
-    """Near-field half of the far-field Phase 2: ``_weight_kernel_soa``'s
-    sweep step over per-block candidate rows, with the Phase-1 tile-table
-    skip (steps past ``nt_ref[i]`` are clamped revisits, the accumulation
-    is predicated off) — and the four reduced accumulators written out
-    instead of a finished z, so the engine can fold in the far-cell terms
-    before dividing."""
+    """Near-field half of the quadtree Phase 2 on ``pipeline="dense"``:
+    ``_weight_kernel_soa``'s sweep step over per-block candidate rows, with
+    the Phase-1 tile-table skip (steps past ``nt_ref[i]`` are clamped
+    revisits, the accumulation is predicated off) — and the four reduced
+    accumulators written out instead of a finished z, so the engine can
+    fold in the far-node terms before dividing."""
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -583,86 +571,14 @@ def phase2_near_row_runs(
     return tuple(outs)
 
 
-def _far_cell_kernel(rect_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
-                     fix_ref, fiy_ref, fcnt_ref, fzs_ref,
-                     sw_ref, swz_ref, acc_w, acc_wz):
-    """Far-field half: one aggregate term per cell OUTSIDE the block's near
-    rectangle (scalar-prefetched flat, ``rect_ref[4i : 4i+4] = (xlo, xhi,
-    ylo, yhi)``: a 2-D SMEM table pads every row to 128 words).
-
-    Each far cell contributes ``count * w(d_centroid)`` to Σw and
-    ``z_sum * w(d_centroid)`` to Σw·z.  Cells inside the rectangle are
-    masked to 0 — their points were swept exactly by the near kernel — and
-    pad cells carry sentinel centroids (w = 0) AND count = z_sum = 0.
-    """
-    i, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_w[...] = jnp.zeros(acc_w.shape, acc_w.dtype)
-        acc_wz[...] = jnp.zeros(acc_wz.shape, acc_wz.dtype)
-
-    d2 = sq_dist_tile(qx_ref[...], qy_ref[...], fx_ref[...], fy_ref[...])
-    w = pow_weight(d2, ah_ref[...])
-    xlo, xhi = rect_ref[4 * i], rect_ref[4 * i + 1]
-    ylo, yhi = rect_ref[4 * i + 2], rect_ref[4 * i + 3]
-    inside = ((fix_ref[...] >= xlo) & (fix_ref[...] <= xhi)
-              & (fiy_ref[...] >= ylo) & (fiy_ref[...] <= yhi))
-    w = jnp.where(inside, jnp.zeros((), d2.dtype), w)
-    acc_w[...] += jnp.sum(w * fcnt_ref[...], axis=1, keepdims=True)
-    acc_wz[...] += jnp.sum(w * fzs_ref[...], axis=1, keepdims=True)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        sw_ref[...] = acc_w[...]
-        swz_ref[...] = acc_wz[...]
-
-
-def phase2_far_aggregates(
-    qx_s, qy_s, alpha_half, rects, far, *,
-    block_q: int, block_d: int, interpret: bool,
-):
-    """Far-field aggregate sweep: every cell of the grid, one term each.
-
-    rects: (nb, 4) int32 per-block near rectangles (inclusive cell bounds,
-    masked out of the far sum); far: the plan's padded ``(1, ncp)`` arrays
-    ``(cent_x, cent_y, count, z_sum, ix, iy)``, ``ncp % block_d == 0``.
-
-    Returns ``(sum_w_far, sum_wz_far)``, each ``(n_tot, 1)``.
-    """
-    n_tot = qx_s.shape[0]
-    nb = rects.shape[0]
-    dtype = qx_s.dtype
-    fx, fy, fcnt, fzs, fix, fiy = far
-    ncp = fx.shape[1]
-    qx2, qy2 = qx_s[:, None], qy_s[:, None]
-    q_spec = pl.BlockSpec((block_q, 1), _pf_query_map)
-    c_spec = pl.BlockSpec((1, block_d), _pf_shared_tile_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, ncp // block_d),
-        in_specs=[q_spec, q_spec, q_spec] + [c_spec] * 6,
-        out_specs=[q_spec] * 2,
-        scratch_shapes=[pltpu.VMEM((block_q, 1), dtype) for _ in range(2)],
-    )
-    return pl.pallas_call(
-        _far_cell_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_tot, 1), dtype)] * 2,
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-        name="_far_cell_kernel",
-    )(rects.astype(jnp.int32).reshape(-1), qx2, qy2, alpha_half, fx, fy, fix, fiy, fcnt, fzs)
-
-
 def _far_node_kernel(nt_ref, qx_ref, qy_ref, ah_ref, fx_ref, fy_ref,
                      fcnt_ref, fzs_ref, fmx_ref, fmy_ref,
                      sw_ref, swz_ref, acc_w, acc_wz):
     """Quadtree far-field level sweep: one aggregate + DIPOLE term per
     closed node of the block's row of the level (DESIGN.md §8).
 
-    The monopole terms are the far-cell kernel's (``count * w`` / ``z_sum *
-    w`` at the centroid distance); the dipole adds ``grad w(cent) . M`` with
+    The monopole terms are ``count * w`` / ``z_sum * w`` at the centroid
+    distance; the dipole adds ``grad w(cent) . M`` with
     ``M = (mx, my)`` the node's stored first z-moment about its centroid:
     for ``w(p) = |q - p|^-a``, ``grad_p w = a |q - p|^(-a-2) (q - p)``, so
     the term is ``a * w / d2 * ((qx-cx) mx + (qy-cy) my)`` — it cancels the

@@ -53,8 +53,8 @@ def _knn_kernel_soa(qx_ref, qy_ref, dx_ref, dy_ref, alpha_ref, best, *, m_real, 
         # tile to nbins contiguous bin-minima (1 op/pair) before the k-pass
         # merge — cuts merge cost ~bm/nbins-fold; mildly approximate (drops a
         # true neighbour only when two of a query's top-k land in the SAME
-        # bin of the SAME tile; r_obs feeds a smooth map, error measured in
-        # tests/benchmarks).
+        # bin of the SAME tile; r_obs feeds a smooth map, error bounded in
+        # tests/kernels).
         bm = d2.shape[1]
         sub = bm // nbins
         cands = jnp.concatenate(

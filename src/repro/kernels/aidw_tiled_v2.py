@@ -12,7 +12,7 @@ the TPU can do cheaply, unlike the CUDA per-thread early-out which diverges).
 
 Expected kNN-pass cost: 7 + 1 + p_merge * 3k ~ 9 flop/pair vs 37 baseline.
 The kernel also emits a per-block merge counter so interpret-mode runs can
-MEASURE p_merge (reported in §Perf, benchmarks/fig_speedups path).
+MEASURE p_merge (``engine.execute_with_stats``'s ``merge_fraction``).
 """
 
 from __future__ import annotations

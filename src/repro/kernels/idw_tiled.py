@@ -1,7 +1,7 @@
 """Standard-IDW tiled Pallas kernel — the paper's §5.3.1 comparison baseline.
 
 One distance sweep (constant alpha, no kNN pass): half the data traffic and
-roughly half the FLOPs of AIDW, quantified in benchmarks/fig_speedups.py.
+roughly half the FLOPs of AIDW.
 """
 
 from __future__ import annotations

@@ -88,8 +88,8 @@ def aidw(
     merge-fraction diagnostic).
     ``layout``: "soa" | "aoas" — layout of the streamed data-point array.
     ``phase2``/``farfield_rtol``/``farfield_radius`` (impl="grid" only)
-    select the far-field approximated Phase 2 with its plan-time error
-    budget — see :func:`repro.engine.build_plan`.
+    select the quadtree Phase 2 with its plan-time error budget — see
+    :func:`repro.engine.build_plan`.
 
     Repeat calls with the *same* ``dx/dy/dz`` array objects reuse a memoized
     plan (keyed on array identity, not contents): don't mutate data arrays

@@ -13,7 +13,7 @@ this module ships the response:
     quadtree plans the near field's ``p2_need_max`` too.
 ``replanning``
     The streak — consecutive batches in which Phase 1 overflowed (ring
-    search) or, on far-field and quadtree plans, Phase 2 did (masked exact
+    search) or, on quadtree plans, Phase 2 did (masked exact
     sweep) — reached ``PERSISTENT_OVERFLOW_BATCHES``: a background thread
     rebuilds the plan via ``engine.plan.replan_with_capacity`` with a
     geometrically bumped floor on the capacity of each phase that

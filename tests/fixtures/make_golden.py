@@ -8,16 +8,17 @@ these values within dtype-appropriate tolerance, pinning the whole impl
 family to one absolute reference across PRs (pairwise parity tests cannot
 see a drift that moves two impls together).
 
-Beyond the exact-impl batches, the fixture pins the two approximating
-Phase-2 arms:
+Beyond the exact-impl batches, the fixture pins the approximating
+quadtree Phase 2 on a tight-cluster batch where its dipole bound PROVES
+rtol=1e-3:
 
-* ``ffpin_*`` — the ``phase2="farfield"`` plan's committed OUTPUT on the
-  uniform batch (gx=12, radius=2): a semantic-regression gate that the
-  single-level arm is unchanged across PRs;
-* ``qtree_*`` — a tight-cluster batch where the quadtree dipole bound
-  PROVES rtol=1e-3, with its Kahan reference and the proved bound recorded
-  at generation time; ``test_golden.py`` asserts the live plan reproduces
-  the bound and stays within it against the committed reference.
+* ``qtree_*`` — the batch, its Kahan reference and the proved bound
+  recorded at generation time; ``test_golden.py`` asserts the live plan
+  reproduces the bound and stays within it against the committed
+  reference;
+* ``qtpin_*`` — the served quadtree plan's committed OUTPUT (z and alpha)
+  on that batch: a semantic-regression gate that the arm is unchanged
+  across PRs.
 
 Run from the repo root (only when the reference semantics intentionally
 change — note it in the PR):
@@ -27,7 +28,6 @@ change — note it in the PR):
 
 import os
 import sys
-import warnings
 
 import numpy as np
 import jax.numpy as jnp
@@ -48,7 +48,8 @@ OUT = os.path.join(os.path.dirname(__file__), "aidw_golden.npz")
 def _quadtree_batch(seed=303):
     """Per-cell clusters far below the cell scale (sub-cell dispersion) with
     z noise INSIDE each cluster — the configuration where the quadtree
-    dipole bound proves rtol=1e-3 and the single-level model cannot."""
+    dipole bound proves rtol=1e-3 (a monopole-only budget would pay the
+    in-cluster z noise to first order)."""
     rng = np.random.default_rng(seed)
     centers = (np.stack(np.meshgrid(np.arange(QT_GX), np.arange(QT_GX)), -1)
                .reshape(-1, 2) + 0.5) / QT_GX
@@ -76,23 +77,9 @@ def main():
             f"{name}_z": np.asarray(z_ref), f"{name}_alpha": np.asarray(a_ref),
         })
 
-    # farfield pin: committed output of the single-level arm on the uniform
-    # batch — any semantic drift across PRs trips the golden gate.
-    dx, dy, dz, qx, qy = (blobs[f"uniform_{n}"]
-                          for n in ("dx", "dy", "dz", "qx", "qy"))
-    g = build_grid(jnp.asarray(dx), jnp.asarray(dy), jnp.asarray(dz),
-                   gx=12, gy=12)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")  # honest-bound warning at this radius
-        plan = build_plan(dx, dy, dz, params=params, area=1.0, impl="grid",
-                          grid=g, phase2="farfield", farfield_radius=2,
-                          block_q=64)
-    z, a = execute(plan, jnp.asarray(qx), jnp.asarray(qy))
-    blobs.update({"ffpin_z": np.asarray(z), "ffpin_alpha": np.asarray(a),
-                  "ffpin_radius": np.int32(2), "ffpin_gx": np.int32(12)})
-
-    # quadtree pin: Kahan reference + the proved dipole bound on the
-    # provable batch.
+    # quadtree pins: Kahan reference + the proved dipole bound on the
+    # provable batch, and the served plan's output on it — any semantic
+    # drift across PRs trips the golden gate.
     dx, dy, dz, qx, qy = _quadtree_batch()
     z_ref, a_ref = aidw_interpolate_kahan(dx, dy, dz, qx, qy, params,
                                           area=1.0, q_chunk=64, d_chunk=128)
@@ -101,12 +88,14 @@ def main():
     plan = build_plan(dx, dy, dz, params=params, area=1.0, impl="grid",
                       grid=g, phase2="quadtree", block_q=64)
     assert plan.farfield_bound <= 1e-3, "qtree batch must be provable"
+    z, a = execute(plan, jnp.asarray(qx), jnp.asarray(qy))
     blobs.update({
         "qtree_dx": dx, "qtree_dy": dy, "qtree_dz": dz,
         "qtree_qx": qx, "qtree_qy": qy,
         "qtree_z": np.asarray(z_ref), "qtree_alpha": np.asarray(a_ref),
         "qtree_bound": np.float64(plan.farfield_bound),
         "qtree_gx": np.int32(QT_GX),
+        "qtpin_z": np.asarray(z), "qtpin_alpha": np.asarray(a),
     })
     np.savez_compressed(OUT, **blobs)
     print(f"wrote {OUT} ({os.path.getsize(OUT)} bytes)")

@@ -26,7 +26,6 @@ from repro.engine.plan import _ROW_TILES, _SWEEP_TILE
 from repro.kernels.aidw_grid import (
     phase1_alpha_from_candidates,
     phase1_alpha_row_runs,
-    phase2_far_aggregates,
     phase2_far_nodes,
     phase2_near_row_runs,
     phase2_near_weights,
@@ -40,7 +39,6 @@ N = M = 1 << 20
 BLOCK_Q, BLOCK_D = 256, 512
 CAPACITY = 4096
 NB = N // BLOCK_Q
-N_CELLS = 1 << 16          # ~16 points per cell at m = 2^20 (grid default)
 PARAMS = AIDWParams(k=10, area=1.0)
 F32 = jnp.float32
 
@@ -179,16 +177,6 @@ def test_near_weight_kernel_rows(compile_for_chip, tile, shape):
                      ((nb, row_run_max_tiles(capacity, tile, gy)), jnp.int32),
                      ((nb,), jnp.int32), ((nb, 4), jnp.int32), pts, pts, pts,
                      ((m,), jnp.int32))
-
-
-def test_far_cell_kernel(compile_for_chip):
-    def fn(qx, qy, ah, rects, fx, fy, fcnt, fzs, fix, fiy):
-        return phase2_far_aggregates(qx, qy, ah, rects, (fx, fy, fcnt, fzs, fix, fiy),
-                                     block_q=BLOCK_Q, block_d=BLOCK_D, interpret=False)
-
-    q, cells, ids = ((N,), F32), ((1, N_CELLS), F32), ((1, N_CELLS), jnp.int32)
-    compile_for_chip(fn, q, q, ((N, 1), F32), ((NB, 4), jnp.int32),
-                     cells, cells, cells, cells, ids, ids)
 
 
 def test_far_node_kernel(compile_for_chip):

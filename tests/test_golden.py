@@ -7,12 +7,12 @@ impls to a freshly-computed oracle, so a change that shifts the oracle and
 an impl together passes them silently; this gate pins everyone to one
 absolute committed reference.  The approximating ``binned`` prefilter is
 deliberately excluded — its contract is error-bounded, not golden-equal.
-The two approximating Phase-2 arms get their own pins: ``ffpin_*`` commits
-the farfield plan's OUTPUT (semantic-drift gate, near-bitwise tolerance)
-and ``qtree_*`` commits a Kahan reference plus the proved dipole bound the
-quadtree arm must reproduce and stay within (see tests/engine/
-test_farfield.py and tests/engine/test_quadtree.py for the live-oracle
-versions of these contracts).
+The approximating quadtree Phase 2 gets two pins on its tight-cluster
+batch: ``qtpin_*`` commits the served quadtree plan's OUTPUT (semantic-drift
+gate, near-bitwise tolerance) and ``qtree_*`` commits a Kahan reference
+plus the proved dipole bound the plan must reproduce and stay within (see
+tests/engine/test_quadtree.py for the live-oracle version of this
+contract).
 
 Regenerate (only for an intentional semantic change, noted in the PR):
 ``PYTHONPATH=src python tests/fixtures/make_golden.py``.
@@ -59,41 +59,9 @@ def test_exact_impl_reproduces_golden(golden, impl, batch):
                                rtol=RTOL, atol=ATOL, err_msg=f"{impl} z drift")
 
 
-def test_farfield_output_pinned(golden):
-    """``phase2="farfield"`` output is pinned to the committed fixture: this
-    PR family's contract is that the single-level arm is UNCHANGED while the
-    quadtree arm evolves.  Tolerance covers cross-backend codegen jitter
-    only — a semantic change moves values far beyond it and must come with
-    a deliberate regeneration noted in the PR."""
-    import warnings
-
-    from repro.core.grid import build_grid
-
-    p = AIDWParams(k=int(golden["k"]), area=float(golden["area"]))
-    dx, dy, dz, qx, qy = (golden[f"uniform_{n}"]
-                          for n in ("dx", "dy", "dz", "qx", "qy"))
-    gx = int(golden["ffpin_gx"])
-    g = build_grid(jnp.asarray(dx), jnp.asarray(dy), jnp.asarray(dz),
-                   gx=gx, gy=gx)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        plan = build_plan(dx, dy, dz, params=p, area=float(golden["area"]),
-                          impl="grid", grid=g, phase2="farfield",
-                          farfield_radius=int(golden["ffpin_radius"]),
-                          block_q=64)
-    z, a = execute(plan, jnp.asarray(qx), jnp.asarray(qy))
-    np.testing.assert_allclose(np.asarray(a), golden["ffpin_alpha"],
-                               rtol=0, atol=1e-6, err_msg="farfield alpha drift")
-    np.testing.assert_allclose(np.asarray(z), golden["ffpin_z"],
-                               rtol=2e-6, atol=2e-6, err_msg="farfield z drift")
-
-
-def test_quadtree_pinned_within_proved_bound(golden):
-    """``phase2="quadtree"`` against the committed Kahan reference on the
-    provable tight-cluster batch: the live plan must reproduce the committed
-    proved bound (<= 1e-3) and its output must stay within that bound of
-    the committed reference."""
-    from repro.core.accuracy import FP_SLACK_ULPS
+def _quadtree_plan(golden):
+    """The served quadtree plan (default pipeline and rtol) on the fixture's
+    provable tight-cluster batch, and that batch's queries."""
     from repro.core.grid import build_grid
 
     p = AIDWParams(k=int(golden["k"]), area=float(golden["area"]))
@@ -104,14 +72,39 @@ def test_quadtree_pinned_within_proved_bound(golden):
                    gx=gx, gy=gx)
     plan = build_plan(dx, dy, dz, params=p, area=float(golden["area"]),
                       impl="grid", grid=g, phase2="quadtree", block_q=64)
+    return plan, jnp.asarray(qx), jnp.asarray(qy)
+
+
+def test_quadtree_output_pinned(golden):
+    """The served ``phase2="quadtree"`` plan's output is pinned to the
+    committed fixture: a change that leaves the arm's semantics alone must
+    leave its z and alpha here.  Tolerance covers cross-backend codegen
+    jitter only — a semantic change moves values far beyond it and must
+    come with a deliberate regeneration noted in the PR."""
+    plan, qx, qy = _quadtree_plan(golden)
+    z, a = execute(plan, qx, qy)
+    np.testing.assert_allclose(np.asarray(a), golden["qtpin_alpha"],
+                               rtol=0, atol=1e-6, err_msg="quadtree alpha drift")
+    np.testing.assert_allclose(np.asarray(z), golden["qtpin_z"],
+                               rtol=2e-6, atol=2e-6, err_msg="quadtree z drift")
+
+
+def test_quadtree_pinned_within_proved_bound(golden):
+    """``phase2="quadtree"`` against the committed Kahan reference on the
+    provable tight-cluster batch: the live plan must reproduce the committed
+    proved bound (<= 1e-3) and its output must stay within that bound of
+    the committed reference."""
+    from repro.core.accuracy import FP_SLACK_ULPS
+
+    plan, qx, qy = _quadtree_plan(golden)
     bound = float(golden["qtree_bound"])
     assert bound <= 1e-3
     np.testing.assert_allclose(plan.farfield_bound, bound, rtol=1e-9,
                                err_msg="dipole bound model drift")
-    z, a = execute(plan, jnp.asarray(qx), jnp.asarray(qy))
+    z, a = execute(plan, qx, qy)
     scale = float(np.max(np.abs(golden["qtree_dz"])))
     fp_slack = (FP_SLACK_ULPS * float(np.finfo(np.float32).eps)
-                * float(np.sqrt(dx.shape[0])))
+                * float(np.sqrt(golden["qtree_dx"].shape[0])))
     rel = float(np.max(np.abs(np.asarray(z, np.float64)
                               - golden["qtree_z"].astype(np.float64))) / scale)
     assert rel <= bound + fp_slack, (rel, bound, fp_slack)
@@ -132,3 +125,9 @@ def test_fixture_is_self_consistent(golden):
         a = golden[f"{batch}_alpha"]
         levels = AIDWParams().alpha_levels
         assert (a >= min(levels) - 1e-6).all() and (a <= max(levels) + 1e-6).all()
+    # the quadtree output pin: the served plan's answers on the qtree batch
+    for name in ("z", "alpha"):
+        pin = golden[f"qtpin_{name}"]
+        assert pin.dtype == np.float32 and np.isfinite(pin).all()
+        assert pin.shape == golden["qtree_qx"].shape
+    assert not any(k.startswith("ffpin_") for k in golden)

@@ -139,7 +139,6 @@ def _compiled_text(fn, plan, n=256):
 
 @pytest.mark.parametrize("phase2, extra", [
     ("exact", ()),
-    ("farfield", ("aidw.phase2.farfield", "aidw.phase2.masked_exact")),
     ("quadtree", ("aidw.phase2.quadtree_walk", "aidw.phase2.masked_exact")),
 ])
 def test_grid_program_names_every_stage(phase2, extra):
@@ -178,7 +177,7 @@ def _pallas_calls():
 KERNEL_FUNCTIONS = {
     "_fused_kernel", "_knn_kernel_soa", "_knn_kernel_aoas", "_knn_kernel_skip",
     "_knn_kernel_v2", "_weight_kernel_soa", "_weight_kernel_aoas", "_near_weight_kernel",
-    "_near_weight_kernel_rows", "_far_cell_kernel", "_far_node_kernel",
+    "_near_weight_kernel_rows", "_far_node_kernel",
     "_naive_kernel_soa", "_naive_kernel_aoas", "_idw_kernel",
 }
 
